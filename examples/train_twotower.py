@@ -174,7 +174,7 @@ def main():
                 loss_core, argnums=(0, 1))(u_tab, i_tab, u, i, r, B)
             return u_tab - lr * gu, i_tab - lr * gi, loss
     else:
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         ax = emb_u.axis_name
 
@@ -194,7 +194,7 @@ def main():
             in_specs=(emb_u.table_spec, emb_i.table_spec,
                       P(ax), P(ax), P(ax)),
             out_specs=(emb_u.table_spec, emb_i.table_spec, P()),
-            check_rep=False)
+            check_vma=False)
     step_fn = jax.jit(step_fn, donate_argnums=(0, 1))
 
     # host-held sparse-DDP exchange plan for telemetry: what a

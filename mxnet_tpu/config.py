@@ -205,28 +205,6 @@ register_flag("mirror_policy", "MXNET_MIRROR_POLICY", str,
               "everything — max memory savings), dots_saveable (keep "
               "matmul outputs), dots_with_no_batch_dims_saveable "
               "(transformer-style).")
-register_flag("compile_cache_dir", "MXNET_COMPILE_CACHE_DIR", str,
-              (os.path.expanduser("~/.cache/mxnet_tpu/xla")
-               if not os.path.expanduser("~").startswith("~") else ""),
-              "Persistent XLA compilation-cache directory; empty "
-              "disables. The default engages only when an accelerator "
-              "platform is explicitly selected (jax_platforms leads with "
-              "a non-cpu entry): XLA:CPU AOT artifacts can fail feature "
-              "verification on reload (SIGILL), and CPU compiles are "
-              "cheap. Setting MXNET_COMPILE_CACHE_DIR explicitly forces "
-              "the cache on for any backend; empty turns it off. "
-              "The XLA-era replacement for the reference's "
-              "operator_tune startup autotuning "
-              "(src/operator/operator_tune.h:67-225): instead of "
-              "re-measuring ops every process, compiled programs are "
-              "reused across processes, so a big fused train step's "
-              "multi-minute first compile is paid once per program, not "
-              "once per run.")
-register_flag("compile_cache_min_compile_secs",
-              "MXNET_COMPILE_CACHE_MIN_COMPILE_SECS", float, 1.0,
-              "Only persist programs whose compile took at least this "
-              "many seconds (tiny eager ops are cheap to recompile and "
-              "would bloat the cache).")
 register_flag("profiler_autostart", "MXNET_PROFILER_AUTOSTART",
               _parse_bool, False,
               "Start the profiler when mxnet_tpu.profiler is first "
@@ -269,8 +247,8 @@ register_flag("kernel_tier", "MXNET_KERNEL_TIER", str, "off",
               "always fall back to pure JAX. See docs/tuning.md.")
 register_flag("kernel_interpret", "MXNET_KERNEL_INTERPRET", str, "auto",
               "Pallas execution mode for the kernel tier. 'auto' "
-              "(default): interpreter off-TPU (CPU tests), Mosaic on the "
-              "chip — the pallas_flash idiom. '0'/'compiled': force "
+              "(default): Mosaic on the 'tpu' backend, interpreter on "
+              "'cpu' (tests), an error on any other. '0'/'compiled': force "
               "Mosaic lowering even on a CPU host (used to EXPORT "
               "TPU-platform HLO chip-free; such a program cannot "
               "execute on the host). '1'/'interpret': force interpreter "

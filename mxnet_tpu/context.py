@@ -9,9 +9,12 @@ instead of per-op stream selection.
 """
 from __future__ import annotations
 
+import logging
 import threading
 
 import jax
+
+from .base import MXNetError
 
 __all__ = ["Context", "cpu", "gpu", "tpu", "current_context", "num_gpus", "num_tpus"]
 
@@ -99,20 +102,45 @@ def _accel_devices():
     return devs
 
 
+def cpu_pinned():
+    """True when this process asked for the CPU platform by name
+    (``JAX_PLATFORMS=cpu`` or the same through ``jax.config``)."""
+    return (jax.config.jax_platforms or "").split(",")[0] == "cpu"
+
+
+_warned_test_mode = False
+
+
 def _resolve_device(device_type, device_id):
     if device_type == "cpu":
         cpus = _platform_devices("cpu")
         if cpus:
+            # cpu ids are nominal, as in the reference: one host, any id
             return cpus[device_id % len(cpus)]
         # No CPU PJRT client exposed (accelerator-only runtime): fall back to
         # default device; host staging still happens via numpy.
         return jax.local_devices()[0]
-    accels = _accel_devices()
-    if accels:
-        return accels[device_id % len(accels)]
-    # tpu requested but only CPU available (test mode): map onto cpu devices
-    devs = jax.local_devices()
-    return devs[device_id % len(devs)]
+    devs = _accel_devices()
+    if not devs:
+        # Test mode: a CPU-pinned process runs accelerator contexts on its
+        # (virtual) CPU devices. Anywhere else a missing chip is an error.
+        if not cpu_pinned():
+            raise MXNetError(
+                "tpu(%d) requested but JAX found no accelerator (devices: "
+                "%s). Set JAX_PLATFORMS=cpu to run accelerator contexts on "
+                "CPU devices." % (device_id, jax.local_devices()))
+        devs = jax.local_devices()
+        global _warned_test_mode
+        if not _warned_test_mode:
+            _warned_test_mode = True
+            logging.getLogger("mxnet_tpu").warning(
+                "JAX_PLATFORMS=cpu: accelerator contexts (mx.tpu(i)) run "
+                "on the %d CPU device(s) of this process", len(devs))
+    if not 0 <= device_id < len(devs):
+        raise MXNetError(
+            "tpu(%d) requested but this process has %d such device(s)"
+            % (device_id, len(devs)))
+    return devs[device_id]
 
 
 def cpu(device_id=0):

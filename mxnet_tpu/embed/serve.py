@@ -248,11 +248,7 @@ class RecommendEngine:
         queue's billing rate (load_s = pending gathers x this)."""
         from .. import perfmodel
         if device_kind is None:
-            try:
-                import jax
-                device_kind = jax.devices()[0].device_kind
-            except Exception:
-                device_kind = perfmodel.DEFAULT_DEVICE_KIND
+            device_kind = perfmodel.modelled_device_kind()
         base = perfmodel.recommend_request_seconds(
             1, self.dim, self.items,
             dtype_bytes=self.cache.dtype.itemsize,
@@ -262,11 +258,7 @@ class RecommendEngine:
     def estimate_request_s(self, gathers, device_kind=None):
         from .. import perfmodel
         if device_kind is None:
-            try:
-                import jax
-                device_kind = jax.devices()[0].device_kind
-            except Exception:
-                device_kind = perfmodel.DEFAULT_DEVICE_KIND
+            device_kind = perfmodel.modelled_device_kind()
         return perfmodel.recommend_request_seconds(
             gathers, self.dim, self.items,
             dtype_bytes=self.cache.dtype.itemsize,

@@ -224,12 +224,12 @@ class ShardedEmbedding:
         import jax
         if self.num_shards == 1:
             return jax.jit(self.lookup)
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         fn = shard_map(
             self.lookup, mesh=self.mesh,
             in_specs=(self.table_spec, P(self.axis_name)),
-            out_specs=P(self.axis_name), check_rep=False)
+            out_specs=P(self.axis_name), check_vma=False)
         return jax.jit(fn)
 
     def comm_bytes_per_lookup(self, batch_ids):

@@ -164,11 +164,14 @@ class Executor:
         self._ctxs = ctxs
         self._mesh = None
         self._batch_args = frozenset(batch_args or ())
-        devices = []
-        for c in ctxs:
-            d = c.jax_device
-            if d not in devices:
-                devices.append(d)
+        devices = [c.jax_device for c in ctxs]
+        if len(set(devices)) != len(devices):
+            # a mesh over fewer devices than contexts would train on a
+            # smaller machine than the one asked for, in silence
+            raise MXNetError(
+                "contexts %s resolve to %d distinct device(s) %s: each "
+                "context of a multi-device bind needs a device of its own"
+                % (ctxs, len(set(devices)), sorted(set(devices), key=str)))
         if len(devices) > 1:
             from jax.sharding import Mesh
             self._mesh = Mesh(_np.asarray(devices), ("dp",))

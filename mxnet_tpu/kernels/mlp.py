@@ -5,8 +5,9 @@ GeLU/ReLU — sits between two MXU matmuls. This kernel runs it in one
 VMEM pass over the (rows, features) view: per-feature f32 coefficients
 stream as (1, block_f) tiles while the activation tensor is tiled
 (block_r, block_f), everything computed in f32 with a single downcast
-on the way out. Exact (erf) GeLU, matching ``ops/nn.py``
-``leaky_relu(act_type='gelu')``.
+on the way out. Exact (erf) GeLU as in ``ops/nn.py``
+``leaky_relu(act_type='gelu')``, with erf from an f32 rational fit
+(:func:`_erf_f32`) because Mosaic lowers neither ``erf`` nor ``erfc``.
 
 The matmul itself stays in XLA: the executor's fusion pass rewrites
 ``FullyConnected(+bias) -> gelu`` into ``FullyConnected(no_bias)``
@@ -33,6 +34,16 @@ OP_NAME = "scale_bias_act"
 DEFAULT_CONFIG = {"block_r": 256, "block_f": 512}
 
 _ACTS = ("gelu", "relu", "identity")
+_RSQRT2 = 0.7071067811865476
+# erf(x) ~= x * P(x^2) / Q(x^2) on [-4, 4]: the f32 rational fit XLA's own
+# erf expands to (3.5e-7 max abs error against math.erf)
+_ERF_P = (-2.72614225801306e-10, 2.77068142495902e-08,
+          -2.10102402082508e-06, -5.69250639462346e-05,
+          -7.34990630326855e-04, -2.95459980854025e-03,
+          -1.60960333262415e-02)
+_ERF_Q = (-1.45660718464996e-05, -2.13374055278905e-04,
+          -1.68282697438203e-03, -7.37332916720468e-03,
+          -1.42647390514189e-02)
 
 
 class _Cfg(NamedTuple):
@@ -42,9 +53,23 @@ class _Cfg(NamedTuple):
     interpret: bool
 
 
+def _erf_f32(x):
+    """erf from mul/add/div only: the Pallas TPU lowering has neither
+    ``erf`` nor the ``erfc`` that ``jax.nn.gelu`` is built on."""
+    x = jnp.clip(x, -4.0, 4.0)
+    x2 = x * x
+    p = jnp.full_like(x, _ERF_P[0])
+    for c in _ERF_P[1:]:
+        p = p * x2 + c
+    q = jnp.full_like(x, _ERF_Q[0])
+    for c in _ERF_Q[1:]:
+        q = q * x2 + c
+    return x * p / q
+
+
 def _act_f32(y, act):
     if act == "gelu":
-        return jax.nn.gelu(y, approximate=False)
+        return 0.5 * y * (1.0 + _erf_f32(y * _RSQRT2))
     if act == "relu":
         return jnp.maximum(y, 0.0)
     return y

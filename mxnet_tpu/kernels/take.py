@@ -52,21 +52,25 @@ def _call(weight, idx_flat, block_d, interpret):
     L = idx_flat.shape[0]
     block_d = max(1, min(block_d, D))
     grid = (L, D // block_d)
+    # Rows ride a unit middle axis: Mosaic wants a block's last two dims
+    # (8, 128)-aligned or equal to the array's, and a (1, block_d) block
+    # of a (V, D) table is neither, while (1, block_d) of (V, 1, D) is.
     out = pl.pallas_call(
         _gather_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[pl.BlockSpec(
-                (1, block_d), lambda i, di, idx_ref: (idx_ref[i], di))],
+                (None, 1, block_d),
+                lambda i, di, idx_ref: (idx_ref[i], 0, di))],
             out_specs=pl.BlockSpec(
-                (1, block_d), lambda i, di, idx_ref: (i, di)),
+                (None, 1, block_d), lambda i, di, idx_ref: (i, 0, di)),
         ),
-        out_shape=jax.ShapeDtypeStruct((L, D), weight.dtype),
+        out_shape=jax.ShapeDtypeStruct((L, 1, D), weight.dtype),
         interpret=interpret,
         name="mxk_take_rows",
-    )(idx_flat, weight)
-    return out
+    )(idx_flat, weight.reshape(V, 1, D))
+    return out.reshape(L, D)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))

@@ -283,11 +283,25 @@ class Module(BaseModule):
         self._fused = None
         self._fused_ran = False
         self._detach_device_metric()
-        if not self.for_training or not _flags.module_fused_step:
-            return
-        if self.inputs_need_grad or self._monitor_installed:
-            return
         kv_type = kv.type if kv is not None else None
+
+        def eager(why):
+            # the eager path trains correctly, one dispatch per op: where
+            # the caller asked for the fused path by name, say why not
+            if kv_type == "tpu_sync":
+                self.logger.warning(
+                    "kvstore='tpu_sync' but the fused train step is not "
+                    "engaged (%s): training runs the eager per-op path",
+                    why)
+
+        if not self.for_training:
+            return
+        if not _flags.module_fused_step:
+            return eager("MXNET_MODULE_FUSED_STEP=0")
+        if self.inputs_need_grad:
+            return eager("inputs_need_grad=True")
+        if self._monitor_installed:
+            return eager("a monitor is installed")
         if self._update_on_kvstore:
             return  # optimizer runs on the (dist) kvstore server
         on_tpu = all(c.device_type == "tpu" for c in self._context)
@@ -297,14 +311,15 @@ class Module(BaseModule):
         # 'add' grad accumulation needs the eager grad buffers
         if any(self._exec._grad_req.get(n) == "add"
                for n in self._param_names):
-            return
+            return eager("grad_req='add'")
         if self._optimizer.fused_ops() is None:
-            return
+            return eager("optimizer %s has no fused update"
+                         % type(self._optimizer).__name__)
         # fp16 params need the eager multi-precision path (f32 master copy
         # per weight, optimizer.py:71-75) — fused state layout differs
         if any(self._exec.arg_dict[n].dtype != _np.float32
                for n in self._param_names):
-            return
+            return eager("non-float32 parameters")
         from .fused import FusedStep
         # multi_precision on a TPU module = bf16 compute over f32 master
         # weights (the reference's fp16 multi-precision SGD, optimizer.py
@@ -586,7 +601,7 @@ class Module(BaseModule):
             self._detach_device_metric()
             return None
         if self._ddp:
-            # under check_rep=False a replicated metric carry would
+            # under check_vma=False a replicated metric carry would
             # silently accumulate only each rank's LOCAL batches — keep
             # the host metric path (per-worker metric, reference
             # dist_sync semantics)
@@ -621,14 +636,16 @@ class Module(BaseModule):
         return proxy
 
     def _place_met_state(self, state):
-        """Commit a fresh metric carry to the mesh's replicated sharding
-        (single-device modules take the host scalars as-is; jit places
-        them)."""
-        ex = self._exec
-        if ex._mesh is None:
-            return state
+        """Commit a fresh metric carry where the step's outputs will live:
+        the mesh's replicated sharding, or the module's one device. Left
+        on the host, the fresh carry and the carry a step returns would
+        differ in placement, and XLA would compile the step once for
+        each."""
         import jax
-        return tuple(tuple(jax.device_put(x, ex._rep_sharding) for x in p)
+        ex = self._exec
+        where = ex._rep_sharding if ex._mesh is not None \
+            else ex._ctx.jax_device
+        return tuple(tuple(jax.device_put(x, where) for x in p)
                      for p in state)
 
     def _reset_device_metric(self):

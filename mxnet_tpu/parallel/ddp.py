@@ -57,14 +57,6 @@ def enabled():
     return bool(flags.ddp)
 
 
-def _device_kind():
-    try:
-        import jax
-        return jax.devices()[0].device_kind
-    except Exception:
-        return _perfmodel.DEFAULT_DEVICE_KIND
-
-
 def choose_bucket_bytes(device_kind=None):
     """Bucket size in bytes: ``MXNET_DDP_BUCKET_MB`` if set, else sized
     from the interconnect bandwidth so launch overhead amortizes to
@@ -73,7 +65,8 @@ def choose_bucket_bytes(device_kind=None):
     mb = float(flags.ddp_bucket_mb or 0.0)
     if mb > 0.0:
         return max(1, int(mb * (1 << 20)))
-    bw = _perfmodel.interconnect_bytes_per_s(device_kind or _device_kind())
+    bw = _perfmodel.interconnect_bytes_per_s(
+        device_kind or _perfmodel.modelled_device_kind())
     raw = bw * _LAUNCH_OVERHEAD_S / _LAUNCH_FRACTION
     return int(min(max(raw, _MIN_BUCKET_BYTES), _MAX_BUCKET_BYTES))
 
@@ -297,7 +290,8 @@ def estimate_overlap_ms(bucket_nbytes, axis_size, device_kind=None):
     ``ddp/overlap_ms`` gauge and the bench ``overlap_frac``."""
     if axis_size <= 1 or len(bucket_nbytes) <= 1:
         return 0.0
-    bw = _perfmodel.interconnect_bytes_per_s(device_kind or _device_kind())
+    bw = _perfmodel.interconnect_bytes_per_s(
+        device_kind or _perfmodel.modelled_device_kind())
     ring = 2.0 * (axis_size - 1) / axis_size
     return sum(ring * b / bw for b in bucket_nbytes[:-1]) * 1e3
 

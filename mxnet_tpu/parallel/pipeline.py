@@ -50,7 +50,7 @@ def make_pipeline(stage_fn, mesh, axis_name="pp", n_microbatch=None):
       stack_stage_params), sharded over ``axis_name``.
     * ``x`` — (batch, d); batch must divide into ``n_microbatch``.
     """
-    from ._compat import shard_map_no_check
+    from jax import shard_map
 
     n_stage = mesh.shape[axis_name]
     if n_microbatch is None:
@@ -66,9 +66,9 @@ def make_pipeline(stage_fn, mesh, axis_name="pp", n_microbatch=None):
 
         # replication checker off: the psum-of-banked-zeros trick
         # confuses its static analysis (the result IS replicated)
-        smap = shard_map_no_check(mesh=mesh,
-                                  in_specs=(P(axis_name), P()),
-                                  out_specs=P())
+        smap = functools.partial(shard_map, mesh=mesh,
+                                 in_specs=(P(axis_name), P()),
+                                 out_specs=P(), check_vma=False)
 
         @smap
         def run(params, micro_all):

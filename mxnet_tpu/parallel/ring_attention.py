@@ -139,8 +139,7 @@ def ring_attention(q, k, v, axis_name="sp", causal=False):
 def make_ring_attention(mesh, axis_name="sp", causal=False):
     """Build a jitted ring-attention fn over `mesh`: inputs (B,H,T,D) are
     sharded on T over `axis_name`; output sharded the same way."""
-    from ._compat import get_shard_map
-    shard_map = get_shard_map()
+    from jax import shard_map
 
     spec = P(None, None, axis_name, None)
     fn = shard_map(

@@ -230,10 +230,8 @@ class SPMDTrainStep:
             # explicit-collective mode: dp becomes a MANUAL mesh axis
             # (shard_map) so the bucketed psums in step() are real; any
             # other axes (tp) stay auto — GSPMD still places those.
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             self._build_reducer(param_shapes)
-            auto = frozenset(a for a in self.mesh.axis_names
-                             if a != self.dp_axis)
             d_spec = {k: P(self.dp_axis) for k in data_shapes}
             l_spec = {k: P(self.dp_axis) for k in label_shapes}
             p_spec = {k: P() for k in param_shapes}
@@ -242,7 +240,7 @@ class SPMDTrainStep:
                 fn, mesh=self.mesh,
                 in_specs=(p_spec, a_spec, p_spec, d_spec, l_spec, P()),
                 out_specs=(p_spec, a_spec, p_spec, P(self.dp_axis)),
-                check_rep=False, auto=auto)
+                axis_names={self.dp_axis}, check_vma=False)
         self._jitted = jax.jit(
             fn,
             in_shardings=(p_sh, a_sh, p_sh, d_sh, l_sh, key_sh),

@@ -12,11 +12,14 @@ __all__ = ["PEAK_FLOPS", "HBM_GBPS", "ICI_GBPS", "peak_flops",
            "hbm_bytes_per_s", "interconnect_bytes_per_s", "mfu",
            "roofline_seconds", "recommend_request_seconds",
            "speculation_depth",
-           "RESNET50_TRAIN_FLOPS_PER_IMG", "DEFAULT_DEVICE_KIND"]
+           "RESNET50_TRAIN_FLOPS_PER_IMG", "DEFAULT_DEVICE_KIND",
+           "modelled_device_kind"]
 
 # fwd+bwd ~= 3x fwd MACs * 2 flops/MAC (ResNet-50 @ 224: 4.089 GMACs fwd)
 RESNET50_TRAIN_FLOPS_PER_IMG = 3 * 2 * 4.089e9
 
+# The chip the repo is measured on. Estimators that must answer on the CPU
+# backend (tests, chip-free tools) model this one, and say so by name.
 DEFAULT_DEVICE_KIND = "v5e"
 
 # bf16 peak FLOP/s per chip by device-kind substring (first match wins;
@@ -43,27 +46,38 @@ ICI_GBPS = [
 ]
 
 
-def _lookup(table, device_kind, default):
+def _lookup(table, device_kind):
     kind = (device_kind or "").lower()
     for sub, val in table:
         if sub in kind:
             return val
-    return default
+    raise KeyError("no peak numbers for device kind %r (known: %s)"
+                   % (device_kind, ", ".join(sub for sub, _ in table)))
 
 
 def peak_flops(device_kind: str) -> float:
-    """bf16 peak FLOP/s for a device kind string; assumes v5e if unknown."""
-    return _lookup(PEAK_FLOPS, device_kind, 197e12)
+    """bf16 peak FLOP/s for a device kind string; KeyError if unknown."""
+    return _lookup(PEAK_FLOPS, device_kind)
 
 
 def hbm_bytes_per_s(device_kind: str) -> float:
-    """HBM bandwidth in bytes/s for a device kind; assumes v5e if unknown."""
-    return _lookup(HBM_GBPS, device_kind, 819e9)
+    """HBM bandwidth in bytes/s for a device kind; KeyError if unknown."""
+    return _lookup(HBM_GBPS, device_kind)
 
 
 def interconnect_bytes_per_s(device_kind: str) -> float:
-    """ICI bandwidth in bytes/s for a device kind; assumes v5e if unknown."""
-    return _lookup(ICI_GBPS, device_kind, 1600e9 / 8)
+    """ICI bandwidth in bytes/s for a device kind; KeyError if unknown."""
+    return _lookup(ICI_GBPS, device_kind)
+
+
+def modelled_device_kind() -> str:
+    """The device kind a cost estimate should model in this process: the
+    attached accelerator's own, or, on the CPU backend, the chip the
+    estimate is made for (:data:`DEFAULT_DEVICE_KIND`) — the CPU has no
+    row in the tables and gets none."""
+    import jax
+    dev = jax.devices()[0]
+    return DEFAULT_DEVICE_KIND if dev.platform == "cpu" else dev.device_kind
 
 
 def mfu(flops_per_step: float, step_seconds: float,

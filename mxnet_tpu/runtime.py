@@ -3,18 +3,21 @@
 The reference implements its engine/storage/io core in C++
 (src/engine/, src/storage/, src/io/ — SURVEY.md §2.1); here the same
 components live in /root/repo/src and are loaded through a flat C ABI.
-If the shared library is absent, it is built on first import when a
-toolchain exists; every consumer also has a pure-python fallback, so the
-framework works without a compiler.
+If the shared library is absent, it is built on first use when a
+toolchain exists. Every consumer also has a pure-python path, so the
+framework works without a compiler; that it is on that path is logged
+once with the reason, and :func:`load_error` returns it.
 """
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 
 _LIB = None
 _TRIED = False
+_ERROR = None
 
 
 def _lib_path():
@@ -26,30 +29,53 @@ def _src_dir():
 
 
 def _build():
+    """``make -C src``; returns None on success, else why it failed."""
     src = _src_dir()
     if not os.path.isdir(src):
-        return False
+        return "no source directory %s" % src
     try:
-        subprocess.run(["make", "-C", src], check=True,
-                       capture_output=True, timeout=300)
-        return os.path.exists(_lib_path())
-    except Exception:
-        return False
+        r = subprocess.run(["make", "-C", src], capture_output=True,
+                           text=True, timeout=300)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return "make -C %s: %s" % (src, e)
+    if r.returncode != 0:
+        return "make -C %s exited %d: %s" % (
+            src, r.returncode, (r.stderr or r.stdout).strip()[-2000:])
+    if not os.path.exists(_lib_path()):
+        return "make -C %s left no %s" % (src, _lib_path())
+    return None
+
+
+def load_error():
+    """Why :func:`get_lib` returned None (None while it has not)."""
+    return _ERROR
+
+
+def _unavailable(reason):
+    global _ERROR
+    _ERROR = reason
+    logging.getLogger("mxnet_tpu").warning(
+        "native runtime unavailable, using the pure-python paths: %s",
+        reason)
+    return None
 
 
 def get_lib():
-    """Load (building if needed) the native library, or None."""
+    """Load (building if needed) the native library, or None — then the
+    reason is logged once and kept for :func:`load_error`."""
     global _LIB, _TRIED
     if _LIB is not None or _TRIED:
         return _LIB
     _TRIED = True
     path = _lib_path()
-    if not os.path.exists(path) and not _build():
-        return None
+    if not os.path.exists(path):
+        err = _build()
+        if err is not None:
+            return _unavailable(err)
     try:
         lib = ctypes.CDLL(path)
-    except OSError:
-        return None
+    except OSError as e:
+        return _unavailable("loading %s: %s" % (path, e))
     # engine
     lib.EngineCreate.restype = ctypes.c_void_p
     lib.EngineCreate.argtypes = [ctypes.c_int]
@@ -91,6 +117,13 @@ def get_lib():
     return _LIB
 
 
+def _require_lib():
+    lib = get_lib()
+    if lib is None:
+        raise RuntimeError("native runtime unavailable: %s" % load_error())
+    return lib
+
+
 _ENGINE_FN = ctypes.CFUNCTYPE(None, ctypes.c_void_p)
 
 
@@ -102,11 +135,7 @@ class NativeEngine:
         if num_workers is None:
             from .config import flags
             num_workers = flags.cpu_worker_nthreads
-        lib = get_lib()
-        if lib is None:
-            raise RuntimeError("native runtime unavailable "
-                               "(libmxtpu.so missing and no toolchain)")
-        self._lib = lib
+        self._lib = lib = _require_lib()
         self._h = lib.EngineCreate(num_workers)
         # token -> cfn closure. A callback must NOT free its own libffi
         # closure (the worker thread still returns through it), so closures
@@ -171,10 +200,7 @@ class NativeStoragePool:
     """Pooled host allocator (reference pooled_storage_manager.h)."""
 
     def __init__(self, reserve_limit=0):
-        lib = get_lib()
-        if lib is None:
-            raise RuntimeError("native runtime unavailable")
-        self._lib = lib
+        self._lib = lib = _require_lib()
         self._h = lib.StorageCreate(reserve_limit)
 
     def alloc(self, size):
@@ -213,10 +239,7 @@ class NativeRecordReader:
     """Zero-copy indexed RecordIO scanner (reference dmlc recordio)."""
 
     def __init__(self, path):
-        lib = get_lib()
-        if lib is None:
-            raise RuntimeError("native runtime unavailable")
-        self._lib = lib
+        self._lib = lib = _require_lib()
         self._h = lib.RecordReaderCreate(path.encode())
         if not self._h:
             raise IOError("failed to open/parse RecordIO file %s" % path)

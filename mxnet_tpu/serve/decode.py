@@ -277,10 +277,7 @@ class GenerateSession:
         self._win_drafted = 0
         self._win_accepted = 0
         self._win_t0 = time.monotonic()
-        try:
-            self._device_kind = jax.devices()[0].device_kind
-        except Exception:
-            self._device_kind = perfmodel.DEFAULT_DEVICE_KIND
+        self._device_kind = perfmodel.modelled_device_kind()
         # compile before traffic by default (flag-controlled, like the
         # predict path's engine warmup) — otherwise the first request
         # pays prefill+decode+commit compiles against its own deadline

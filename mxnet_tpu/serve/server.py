@@ -610,12 +610,8 @@ class Server:
             for d in s["shape"][1:]:
                 n *= int(d)
             bytes_row += n * _np.dtype(s["dtype"]).itemsize
-        try:
-            kind = jax.devices()[0].device_kind
-        except Exception:
-            kind = perfmodel.DEFAULT_DEVICE_KIND
-        return max(perfmodel.roofline_seconds(0.0, 2.0 * bytes_row, kind),
-                   1e-7)
+        return max(perfmodel.roofline_seconds(
+            0.0, 2.0 * bytes_row, perfmodel.modelled_device_kind()), 1e-7)
 
     def load_status(self):
         """The live half of a fleet heartbeat: readiness (+reason) and

@@ -68,11 +68,8 @@ def _live_mfu(steps, window_s):
     if not flops or window_s <= 0:
         return None
     from mxnet_tpu import perfmodel
-    kind = info.get("device_kind") or perfmodel.DEFAULT_DEVICE_KIND
-    try:
-        return perfmodel.mfu(float(flops), window_s / steps, kind)
-    except Exception:
-        return None
+    kind = info.get("device_kind") or perfmodel.modelled_device_kind()
+    return perfmodel.mfu(float(flops), window_s / steps, kind)
 
 
 def _stall_attribution(steps, window_s, stall_ms):
@@ -89,12 +86,8 @@ def _stall_attribution(steps, window_s, stall_ms):
     flops = info.get("flops_per_step")
     if flops:
         from mxnet_tpu import perfmodel
-        kind = info.get("device_kind") or perfmodel.DEFAULT_DEVICE_KIND
-        try:
-            floor = steps * perfmodel.roofline_seconds(
-                float(flops), 0.0, kind)
-        except Exception:
-            floor = 0.0
+        kind = info.get("device_kind") or perfmodel.modelled_device_kind()
+        floor = steps * perfmodel.roofline_seconds(float(flops), 0.0, kind)
         slack = max(0.0, window_s - floor)
         return frac, bool(stall_s > 0.5 * slack and frac > 0.02)
     return frac, bool(frac > 0.10)

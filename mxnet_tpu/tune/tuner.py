@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import time
 
+from .. import perfmodel as _perfmodel
 from . import cost_model as _cm
 from . import space as _space
 from .cache import shape_bucket_key
@@ -127,10 +128,7 @@ def tune(op, shapes, dtype, chip_free=None, model=None,
     if chip_free is None:
         chip_free = jax.default_backend() == "cpu"
     if device_kind is None:
-        try:
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            device_kind = _cm.DEFAULT_DEVICE_KIND
+        device_kind = _perfmodel.modelled_device_kind()
     model = model or _cm.default_model()
     shapes = tuple(tuple(s) for s in shapes)
     candidates = _space.space_for(op, shapes, str(dtype))

@@ -63,25 +63,29 @@ def test_enforce_determinism_blocks_autoseed():
 
 
 def test_compile_cache_persists_programs(tmp_path):
-    """MXNET_COMPILE_CACHE_DIR: compiled XLA programs persist on disk and
-    are reused by later processes (the operator_tune-replacement flag)."""
+    """JAX_COMPILATION_CACHE_DIR places the cache from outside: the
+    package sets no directory of its own, compiled programs persist
+    there, and a later process reuses them."""
     cache = str(tmp_path / "xla_cache")
     code = (
         "import jax\n"
         "jax.config.update('jax_platforms', 'cpu')\n"
         "import mxnet_tpu as mx\n"
+        "print('CACHE_DIR', jax.config.jax_compilation_cache_dir)\n"
         "net = mx.gluon.nn.Dense(8)\n"
         "net.initialize()\n"
         "net.hybridize()\n"
         "y = net(mx.nd.ones((4, 16)))\n"
         "y.asnumpy()\n"
         "print('RAN_OK')\n")
-    env = dict(os.environ, MXNET_COMPILE_CACHE_DIR=cache,
-               MXNET_COMPILE_CACHE_MIN_COMPILE_SECS="0.0")
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=cache,
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env, timeout=180)
     assert r.returncode == 0, r.stderr
     assert "RAN_OK" in r.stdout
+    assert "CACHE_DIR %s\n" % cache in r.stdout
     entries = os.listdir(cache)
     assert entries, "no programs persisted to the compilation cache"
     # a second process must HIT the cache (jax logs a cache read at debug;
@@ -90,6 +94,28 @@ def test_compile_cache_persists_programs(tmp_path):
                         text=True, env=env, timeout=180)
     assert r2.returncode == 0, r2.stderr
     assert set(os.listdir(cache)) == set(entries)
+
+
+@pytest.mark.parametrize("platforms,expected", [
+    ("cpu", None), ("cpu,tpu", None), ("tpu,cpu", ".jax_cache"),
+    (None, ".jax_cache")])
+def test_compile_cache_default_dir(platforms, expected):
+    """With no directory given from outside, the cache is
+    <checkout>/.jax_cache (from the package's own path: never ~, a temp
+    name, a pid or a time), and off for a CPU-pinned process. Import
+    only: no backend is touched, so this runs without a chip."""
+    code = ("import jax, mxnet_tpu\n"
+            "print('CACHE_DIR', jax.config.jax_compilation_cache_dir)\n")
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR", "JAX_PLATFORMS")}
+    if platforms is not None:
+        env["JAX_PLATFORMS"] = platforms
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, timeout=120)
+    assert r.returncode == 0, r.stderr
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = None if expected is None else os.path.join(root, expected)
+    assert "CACHE_DIR %s\n" % want in r.stdout
 
 
 def test_misc_parity_modules():

@@ -75,10 +75,13 @@ def test_choose_bucket_bytes_tracks_interconnect_table():
     per device kind so a table edit shows up as a policy change here."""
     from mxnet_tpu import perfmodel
     with config.override(ddp_bucket_mb=0.0):
-        for kind in ("TPU v5p", "TPU v4", "TPU v3", "TPU v2", "weird"):
+        for kind in ("TPU v5p", "TPU v4", "TPU v3", "TPU v2"):
             bw = perfmodel.interconnect_bytes_per_s(kind)
             want = int(min(max(bw * 20e-6 / 0.05, 1 << 20), 64 << 20))
             assert ddp.choose_bucket_bytes(kind) == want
+        # a device that is not in the table is an error, not a v5e
+        with pytest.raises(KeyError, match="weird"):
+            ddp.choose_bucket_bytes("weird")
         # fast ICI saturates the 64 MiB overlap ceiling; v2/v3 land
         # mid-range where the launch-amortization formula is live
         assert ddp.choose_bucket_bytes("TPU v5p") == 64 << 20
@@ -125,7 +128,7 @@ def test_estimate_overlap_excludes_last_bucket():
 
 @needs_mesh
 def test_grad_reducer_psum_matches_sum():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     mesh = ddp.process_mesh()
     n = mesh.size
     entries = [("w", (3, 4), np.float32), ("b", (4,), np.float32)]
@@ -139,7 +142,7 @@ def test_grad_reducer_psum_matches_sum():
         return red.reduce(g)
 
     fn = shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     out = jax.jit(fn)(grads)
     np.testing.assert_allclose(np.asarray(out["w"]), grads["w"] * n)
     np.testing.assert_allclose(np.asarray(out["b"]), grads["b"] * n)
@@ -341,7 +344,7 @@ def test_module_ddp_indivisible_batch_falls_back():
 
 @needs_mesh
 def test_module_ddp_refuses_device_metric():
-    """Per-rank device metric accumulation under check_rep=False would be
+    """Per-rank device metric accumulation under check_vma=False would be
     silently wrong — the fused step must refuse it loudly."""
     _, mod = _fit_module("dist_sync", ddp_on=True)
     assert mod._fused is not None

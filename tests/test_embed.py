@@ -60,7 +60,7 @@ def test_sharded_lookup_bitwise_vs_dense_fwd_and_grad():
     forward and table gradient. rows=37 exercises stripe padding; the
     id batch includes out-of-range ids (the clip contract) and heavy
     duplication (the scatter-add fold order)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     rows, dim, batch = 37, 8, 16
@@ -95,7 +95,7 @@ def test_sharded_lookup_bitwise_vs_dense_fwd_and_grad():
         lambda t, i, y: jax.grad(local_loss)(t, i, y),
         mesh=mesh,
         in_specs=(emb.table_spec, P(emb.axis_name), P(emb.axis_name)),
-        out_specs=emb.table_spec, check_rep=False)
+        out_specs=emb.table_spec, check_vma=False)
     g_mesh = np.asarray(jax.jit(g_fn)(emb.device_put(table), ids,
                                       targets))
 
@@ -161,7 +161,7 @@ def test_sparse_ddp_bitwise_and_10x_compression():
     """The sparse bucket kind: contributions all-gathered and coalesced
     in sorted-id order reduce BITWISE-equal to the densified psum oracle
     — at >= 10x fewer exchanged bytes for a realistically tall table."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from mxnet_tpu.parallel import ddp, make_mesh
 
@@ -186,7 +186,7 @@ def test_sparse_ddp_bitwise_and_10x_compression():
 
     fn = shard_map(body, mesh=mesh,
                    in_specs=(P("dp"), P("dp"), P("dp")),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     dense_emb, dense_w = jax.jit(fn)(
         ids.reshape(ranks, per_rank), vals.reshape(ranks, per_rank, dim),
         w_grad)
@@ -216,7 +216,7 @@ def test_twotower_fleet_bitwise_across_shardings_and_capacities():
     two different capacities — with the user table's LOGICAL bytes above
     the configured host budget for (c)."""
     from functools import partial
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     U, I, D, B, steps = 96, 32, 8, 8, 6
@@ -267,7 +267,7 @@ def test_twotower_fleet_bitwise_across_shardings_and_capacities():
         in_specs=(emb_u.table_spec, emb_i.table_spec, P(ax), P(ax),
                   P(ax)),
         out_specs=(emb_u.table_spec, emb_i.table_spec),
-        check_rep=False), donate_argnums=(0, 1))
+        check_vma=False), donate_argnums=(0, 1))
     u_tab = emb_u.device_put(emb_u.init())
     i_tab = emb_i.device_put(emb_i.init())
     for s in range(steps):

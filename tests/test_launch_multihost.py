@@ -90,3 +90,33 @@ def test_missing_heartbeat_dir_warns():
         capture_output=True, text=True, timeout=60, env=env)
     assert r.returncode == 0
     assert "failure detection" in r.stderr
+
+
+def _load_launch():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location("_launch_under_test",
+                                                  LAUNCH)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_local_workers_on_a_chip_host_are_refused(monkeypatch, capsys):
+    """-n N on a host with TPU chips: every worker would open every chip
+    and all but the first would fail or hang. Refused with a message,
+    unless the workers are CPU-pinned by name."""
+    launch = _load_launch()
+    monkeypatch.setattr(launch, "_local_chips", lambda: 4)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    cmd = [sys.executable, "-c", "pass"]
+    assert launch.main(["-n", "2", "--max-restarts", "0"] + cmd) == 2
+    err = capsys.readouterr().err
+    assert "4 TPU chip(s)" in err and "JAX_PLATFORMS=cpu" in err
+    # one worker holds all chips; CPU-pinned workers touch none
+    assert launch._chip_conflict(1, []) is None
+    assert launch._chip_conflict(2, ["JAX_PLATFORMS=cpu"]) is None
+    assert launch.main(["-n", "2", "--max-restarts", "0", "--env",
+                        "JAX_PLATFORMS=cpu"] + cmd) == 0
+    # no chips, nothing to contend for
+    monkeypatch.setattr(launch, "_local_chips", lambda: 0)
+    assert launch._chip_conflict(2, []) is None

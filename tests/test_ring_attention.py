@@ -75,10 +75,7 @@ def test_ring_attention_grads():
     q, k, v = _qkv(t=32, seed=5)
 
     from functools import partial
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from mxnet_tpu.parallel.ring_attention import ring_attention
     spec = P(None, None, "sp", None)

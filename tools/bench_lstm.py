@@ -60,7 +60,7 @@ def measure(batch=32, seq_len=35, hidden=200, vocab=10000, layers=2,
     for _ in range(steps):
         loss = step()
     jax.block_until_ready(loss._data)
-    # force a real host sync (proxy backends can under-block)
+    # force a real host sync
     float(np.asarray(jax.device_get(loss._data)).ravel()[0])
     dt = time.perf_counter() - t0
     toks = batch * seq_len * steps / dt

@@ -58,8 +58,8 @@ def measure(batch=8, seq_len=512, dim=256, heads=8, layers=4,
         return loss
 
     def force(l):
-        # forced host fetch: block_until_ready can under-block on proxy
-        # backends (same guard as bench_lstm.py / bench.py)
+        # forced host fetch: cannot return before the step ran (same
+        # guard as bench_lstm.py / bench.py)
         return float(np.asarray(jax.device_get(l._data)).ravel()[0])
 
     loss = step()   # warmup + compile

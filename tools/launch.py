@@ -6,6 +6,10 @@ servers/workers over ssh/mpirun/yarn). The TPU-native runtime needs no
 scheduler or server processes — only N workers pointed at a PJRT
 coordination service — so this launcher:
 
+* refuses ``-n N`` (N > 1) on a host with TPU chips unless the workers
+  are CPU-pinned (``--env JAX_PLATFORMS=cpu``): every JAX process opens
+  all chips of its host and a chip belongs to one process, so one
+  process drives the host's chips (``Module(context=[mx.tpu(i) ...])``),
 * picks a free coordinator port on localhost,
 * spawns N copies of the command with MXNET_COORDINATOR_ADDRESS /
   MXNET_NUM_WORKERS / MXNET_WORKER_RANK set (DMLC_* aliases too, so
@@ -82,6 +86,35 @@ def _stream(proc, rank_, out):
     for line in proc.stdout:
         out.write("[worker %d] %s" % (rank_, line))
         out.flush()
+
+
+def _local_chips():
+    """TPU chips this host exposes, counted from their device nodes: the
+    launcher must not import jax (whoever opens the chips holds them)."""
+    import glob
+    return len(glob.glob("/dev/accel[0-9]*")
+               or glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _chip_conflict(num_workers, extra):
+    """Why ``-n num_workers`` cannot start on this host, or None. A JAX
+    process opens EVERY chip of its host, and a chip belongs to one
+    process: of N local workers all but the first would fail, or hang, at
+    start-up. Workers pinned to the CPU by name do not touch the chips."""
+    env = dict(os.environ)
+    env.update(kv.partition("=")[::2] for kv in extra)
+    if num_workers < 2 or env.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+        return None
+    chips = _local_chips()
+    if not chips:
+        return None
+    return ("launch.py: this host has %d TPU chip(s) and each of the %d "
+            "local workers would open all of them; a chip belongs to one "
+            "process. One process drives every chip of a host: use "
+            "Module(context=[mx.tpu(i) for i in range(%d)]) in one "
+            "worker (-n 1, or -H with one worker per host), or pass "
+            "--env JAX_PLATFORMS=cpu for CPU workers.\n"
+            % (chips, num_workers, chips))
 
 
 def _worker_env(addr, num_workers, rank_, hb_dir, extra):
@@ -234,6 +267,11 @@ def main(argv=None):
         return _multihost(args)
     if not args.num_workers:
         ap.error("-n is required in single-host mode")
+    conflict = None if args.dry_run else \
+        _chip_conflict(args.num_workers, args.env)
+    if conflict:
+        sys.stderr.write(conflict)
+        return 2
 
     import json
     import random

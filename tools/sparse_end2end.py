@@ -24,6 +24,7 @@ import argparse
 import json
 import os
 import sys
+import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -69,7 +70,8 @@ def main():
     import mxnet_tpu as mx
 
     n_examples = args.batch_size * args.num_batch
-    path = make_libsvm("/tmp/mxtpu_sparse_e2e.libsvm", n_examples,
+    path = make_libsvm(os.path.join(tempfile.gettempdir(),
+                                    "mxtpu_sparse_e2e.libsvm"), n_examples,
                        args.num_features, args.nnz)
 
     kv = mx.kv.create(args.kvstore)
