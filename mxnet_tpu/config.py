@@ -558,13 +558,6 @@ register_flag("telemetry_flight_len", "MXNET_TELEMETRY_FLIGHT_LEN", int,
               "Ring-buffer capacity of the flight recorder: how many "
               "recent step-window records survive into a postmortem "
               "dump.")
-register_flag("telemetry_mfu", "MXNET_TELEMETRY_MFU", _parse_bool, False,
-              "Let Module.fit derive flops_per_step for the live MFU "
-              "gauge by lowering the fused step for cost analysis once "
-              "at fit start (chip-free but seconds of lowering). Off "
-              "(default): the train/mfu gauge appears only when the "
-              "caller supplied flops via telemetry.set_run_info "
-              "(bench.py does).")
 register_flag("kernel_timings", "MXNET_KERNEL_TIMINGS", str, "",
               "Path of the measured kernel-timing JSONL log the on-chip "
               "tuner appends to (mxnet_tpu/tune/timings.py) and "

@@ -36,34 +36,38 @@ __all__ = ["StagedKFeed", "StagedWindow"]
 class StagedWindow:
     """One K-step window: the host batches (labels/metadata for metrics
     and callbacks), the pre-staged device feed (None on short tails —
-    those take the per-step path), the iterator cursor after these
-    batches, and the window's host-known H2D byte count."""
+    those take the per-step path) and the iterator cursor after these
+    batches."""
 
-    __slots__ = ("batches", "staged", "cursor", "h2d_bytes")
+    __slots__ = ("batches", "staged", "cursor")
 
-    def __init__(self, batches, staged=None, cursor=None, h2d_bytes=0):
+    def __init__(self, batches, staged=None, cursor=None):
         self.batches = batches
         self.staged = staged
         self.cursor = cursor
-        self.h2d_bytes = h2d_bytes
 
 
 class StagedKFeed:
     """Double-buffered window stager between a DataIter and fit's
     grouped loop.
 
-    ``stage_fn(batches)`` is the module's host→device staging hook
-    (``Module._stage_group``): it returns the opaque staged-feed payload
-    ``run_k`` accepts plus the window's H2D byte count. ``depth`` bounds
-    the staged windows in flight (2 = classic double buffering; staged
-    windows hold device memory, so keep it small).
+    ``stage_fn(batches, step=...)`` is the module's host→device staging
+    hook (``Module._stage_group``): it returns the opaque staged-feed
+    payload ``run_k`` accepts, and times and counts its own copy
+    (``mx/feed/h2d``). ``step`` is the ``global_step`` of the window's
+    first batch (``first_step`` plus K a window), so that the feeder
+    thread's spans carry the step of the dispatch they work for.
+    ``depth`` bounds the staged windows in flight (2 = classic double
+    buffering; staged windows hold device memory, so keep it small).
     """
 
-    def __init__(self, data_iter, k, stage_fn, depth=2, cursor_fn=None):
+    def __init__(self, data_iter, k, stage_fn, depth=2, cursor_fn=None,
+                 first_step=0):
         self._it = data_iter
         self._k = max(2, int(k))
         self._stage_fn = stage_fn
         self._cursor_fn = cursor_fn
+        self._step = first_step
         self._pq = PrefetchQueue(max(1, int(depth)))
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._thread.start()
@@ -83,14 +87,14 @@ class StagedKFeed:
                 if not batches:
                     break
                 cursor = self._cursor_fn() if self._cursor_fn else None
-                staged, nbytes = None, 0
+                staged = None
                 if len(batches) == self._k:
                     # full window: commit to the stacked device layout
                     # now, overlapping the in-flight dispatch. Tails ride
                     # unstaged — fit's per-step path handles them.
-                    staged, nbytes = self._stage_fn(batches)
-                if not pq.put(StagedWindow(batches, staged, cursor,
-                                           nbytes)):
+                    staged = self._stage_fn(batches, step=self._step)
+                self._step += len(batches)
+                if not pq.put(StagedWindow(batches, staged, cursor)):
                     return
                 if ended:
                     break

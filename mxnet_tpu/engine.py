@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import jax
 
+from . import profiler as _profiler
 from .base import MXNetError
 from .config import flags
 
@@ -53,22 +54,25 @@ class DepthController:
         self.depth = depth
         self._inflight = []  # deque of handle lists, oldest first
 
-    def admit(self, handles):
+    def admit(self, handles, step=None):
         """Register one dispatched step's output handles (jax arrays);
-        block on the oldest step beyond the depth bound."""
+        block on the oldest step beyond the depth bound. ``step`` names
+        the dispatch just admitted on the wait's span."""
         handles = [h for h in handles if hasattr(h, "block_until_ready")]
         self._inflight.append(handles)
-        if self.depth <= 0:
+        if self.depth <= 0 or len(self._inflight) <= self.depth:
             return
-        while len(self._inflight) > self.depth:
-            oldest = self._inflight.pop(0)
-            from . import profiler as _profiler
-            _profiler.record_host_sync("depth_wait")
-            for h in oldest:
-                try:
-                    h.block_until_ready()
-                except Exception as e:
-                    raise MXNetError(str(e)) from e
+        # the host's whole slack: how long it sits here says how far the
+        # device is behind it (near 0: the host sets the pace)
+        with _profiler.span("mx/fit/depth_wait", step=step):
+            while len(self._inflight) > self.depth:
+                oldest = self._inflight.pop(0)
+                _profiler.record_host_sync("depth_wait")
+                for h in oldest:
+                    try:
+                        h.block_until_ready()
+                    except Exception as e:
+                        raise MXNetError(str(e)) from e
 
     def quiesce(self):
         """Block until every admitted step has completed (checkpoint /
@@ -76,14 +80,14 @@ class DepthController:
         pending, self._inflight = self._inflight, []
         if not pending:
             return
-        from . import profiler as _profiler
         _profiler.record_host_sync("wait")
-        for handles in pending:
-            for h in handles:
-                try:
-                    h.block_until_ready()
-                except Exception as e:
-                    raise MXNetError(str(e)) from e
+        with _profiler.span("mx/fit/quiesce"):
+            for handles in pending:
+                for h in handles:
+                    try:
+                        h.block_until_ready()
+                    except Exception as e:
+                        raise MXNetError(str(e)) from e
 
 
 def naive_mode() -> bool:
@@ -102,9 +106,9 @@ def on_complete(array):
     """Block until one array's async computation completes (WaitForVar)."""
     try:
         if hasattr(array, "block_until_ready"):
-            from . import profiler as _profiler
             _profiler.record_host_sync("wait")
-            array.block_until_ready()
+            with _profiler.span("mx/sync/wait"):
+                array.block_until_ready()
     except Exception as e:  # surface async device errors like the reference
         raise MXNetError(str(e)) from e
 
@@ -120,20 +124,20 @@ def waitall():
     them, so there the (O(live arrays)) walk remains the only correct
     drain, matching the reference's WaitForAll (threaded_engine.cc)."""
     try:
-        from . import profiler as _profiler
         _profiler.record_host_sync("wait")
-        jax.effects_barrier()
-        # Every outstanding async execution *and* transfer surfaces as a
-        # not-yet-ready live array; is_ready() is a non-blocking poll, so
-        # the walk costs O(live arrays) python but issues a device sync
-        # only for the (few) actually-pending ones. A per-device sentinel
-        # program would miss in-flight H2D/D2H transfers, which are not
-        # enqueued on the compute queue.
-        for a in jax.live_arrays():
-            try:
-                if not a.is_ready():
+        with _profiler.span("mx/sync/wait"):
+            jax.effects_barrier()
+            # Every outstanding async execution *and* transfer surfaces
+            # as a not-yet-ready live array; is_ready() is a non-blocking
+            # poll, so the walk costs O(live arrays) python but issues a
+            # device sync only for the (few) actually-pending ones. A
+            # per-device sentinel program would miss in-flight H2D/D2H
+            # transfers, which are not enqueued on the compute queue.
+            for a in jax.live_arrays():
+                try:
+                    if not a.is_ready():
+                        a.block_until_ready()
+                except AttributeError:
                     a.block_until_ready()
-            except AttributeError:
-                a.block_until_ready()
     except Exception as e:
         raise MXNetError(str(e)) from e

@@ -27,7 +27,9 @@ import jax.numpy as jnp
 
 from .base import MXNetError
 from .context import Context, current_context
+from . import profiler as _profiler
 from . import random as _random
+from . import telemetry as _telemetry
 from . import autograd as _autograd
 from .ndarray import ndarray as _nd
 from .ndarray.ndarray import NDArray
@@ -325,16 +327,43 @@ class Executor:
         return {k: self._placed(v, self._rep_sharding)
                 for k, v in self.aux_dict.items()}
 
-    def prepare_input(self, name, v, place=True):
-        """Feed value (NDArray / numpy / nested list) cast to the bound
-        arg's dtype; with ``place`` (default), also committed where the
-        executor computes — feeds may come from a host iterator
-        (NDArrayIter on cpu()) and jit must not see mixed platforms."""
+    def _is_placed(self, val, name):
+        """Whether a feed value already lives where this executor
+        computes, so that feeding it copies nothing."""
+        if isinstance(val, NDArray):
+            val = val._data
+        if not isinstance(val, jax.Array):
+            return False
+        if self._mesh is not None:
+            return val.sharding.is_equivalent_to(
+                self._input_sharding(name), val.ndim)
+        dev = self._ctx.jax_device
+        return dev is None or val.sharding.device_set == {dev}
+
+    def _cast_input(self, name, v):
+        """Feed value (NDArray / jax array / numpy / nested list) as a jax
+        array of the bound arg's dtype, wherever it lives."""
+        dtype = self.arg_dict[name].dtype
         if isinstance(v, NDArray):
-            val = v._data.astype(self.arg_dict[name].dtype)
-        else:
-            val = jnp.asarray(_np.asarray(v), self.arg_dict[name].dtype)
-        return self._place_input(val, name) if place else val
+            v = v._data
+        if isinstance(v, jax.Array):
+            return v.astype(dtype)
+        return jnp.asarray(_np.asarray(v), dtype)
+
+    def prepare_input(self, name, v, place=True):
+        """Feed value cast to the bound arg's dtype; with ``place``
+        (default), also committed where the executor computes — feeds may
+        come from a host iterator (NDArrayIter on cpu()) and jit must not
+        see mixed platforms. A value that is not there yet is the step's
+        host-to-device copy: the cast and the ``device_put`` run under
+        ``mx/feed/h2d`` and the bytes copied go to ``data/h2d_bytes``. A
+        value already in place costs neither."""
+        if not place or self._is_placed(v, name):
+            return self._cast_input(name, v)
+        with _profiler.span("mx/feed/h2d") as sp:
+            val = self._place_input(self._cast_input(name, v), name)
+            sp.add(bytes=_telemetry.count_h2d(val.nbytes))
+        return val
 
     def set_inputs(self, **kwargs):
         """Feed input arrays (by arg name) into the bound buffers, placing
@@ -344,14 +373,9 @@ class Executor:
                 self.arg_dict[k]._rebind(self.prepare_input(k, v))
 
     def forward(self, is_train=False, **kwargs):
-        from . import profiler as _profiler
-        if _profiler.is_active("symbolic"):
-            with _profiler.op_timer(
-                    "Executor::forward%s" % ("_train" if is_train else ""),
-                    "symbolic",
-                    lambda: [o._data for o in self.outputs]):
-                return self._forward_impl(is_train, **kwargs)
-        return self._forward_impl(is_train, **kwargs)
+        with _profiler.span("mx/exec/forward_train" if is_train
+                            else "mx/exec/forward"):
+            return self._forward_impl(is_train, **kwargs)
 
     def _forward_impl(self, is_train=False, **kwargs):
         self.set_inputs(**kwargs)
@@ -395,14 +419,8 @@ class Executor:
     def backward(self, out_grads=None, is_train=True):
         if not self._req_args:
             return
-        from . import profiler as _profiler
-        if _profiler.is_active("symbolic"):
-            with _profiler.op_timer(
-                    "Executor::backward", "symbolic",
-                    lambda: [self.grad_dict[k]._data
-                             for k in self._req_args]):
-                return self._backward_impl(out_grads)
-        return self._backward_impl(out_grads)
+        with _profiler.span("mx/exec/backward"):
+            return self._backward_impl(out_grads)
 
     def _backward_impl(self, out_grads=None):
         if out_grads is not None:

@@ -184,16 +184,23 @@ class BaseModule:
                         "steps_per_dispatch > 1 is incompatible with "
                         "monitor / sparse_row_id_fn")
 
-        self.bind(data_shapes=train_data.provide_data,
-                  label_shapes=train_data.provide_label,
-                  for_training=True, force_rebind=force_rebind)
+        # every layer boundary below runs under a profiler.span (the
+        # table is in docs/observability.md "Spans"); none of them syncs
+        from .. import profiler as _profiler
+        with _profiler.span("mx/fit/bind"):
+            self.bind(data_shapes=train_data.provide_data,
+                      label_shapes=train_data.provide_label,
+                      for_training=True, force_rebind=force_rebind)
         if monitor is not None:
             self.install_monitor(monitor)
-        self.init_params(initializer=initializer, arg_params=arg_params,
-                         aux_params=aux_params, allow_missing=allow_missing,
-                         force_init=force_init)
-        self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
-                            optimizer_params=optimizer_params)
+        with _profiler.span("mx/fit/init_params"):
+            self.init_params(initializer=initializer, arg_params=arg_params,
+                             aux_params=aux_params,
+                             allow_missing=allow_missing,
+                             force_init=force_init)
+        with _profiler.span("mx/fit/init_optimizer"):
+            self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
+                                optimizer_params=optimizer_params)
         if eval_metric is None and eval_data is not None and \
                 validation_metric is None:
             raise ValueError(
@@ -276,39 +283,35 @@ class BaseModule:
         depth_ctl = DepthController()
 
         # 4. run-wide telemetry (docs/observability.md): publish step
-        #    time / throughput / live MFU / engine depth / sync census at
-        #    K-step window boundaries, using ONLY values this frame
+        #    time / throughput / engine depth / sync census / span totals
+        #    at K-step window boundaries, using ONLY values this frame
         #    already holds on the host (wall clock, batch shapes, the
         #    in-flight dispatch count) — zero extra device->host syncs,
         #    pinned by tests/test_step_sync_budget.py
         from .. import telemetry as _telemetry
-        if _flags.telemetry_mfu and \
-                "flops_per_step" not in _telemetry.run_info():
-            flops_fn = getattr(self, "_fused_step_flops", None)
-            flops = flops_fn() if flops_fn is not None else None
-            if flops:
-                _telemetry.set_run_info(flops_per_step=flops)
         _telem_t0 = time.monotonic()
         _telem_every = max(1, int(_flags.steps_per_dispatch))
         _telem_acc = [0, 0]          # per-step path: (steps, examples)
 
-        # 5. streaming-tier window stats (docs/data.md): input stall (time
-        #    the loop blocked on the iterator / staged feed), H2D bytes
-        #    and feed-queue depth — all host-held values, zero extra
-        #    device->host syncs (tests/test_step_sync_budget.py)
-        _data_acc = [0.0, 0]         # (input_stall_ms, h2d_bytes)
+        # 5. streaming-tier window stats (docs/data.md): input stall (the
+        #    mx/fit/next spans: time the loop blocked on the iterator /
+        #    staged feed) and feed-queue depth — host-held values, zero
+        #    extra device->host syncs (tests/test_step_sync_budget.py).
+        #    data/h2d_bytes is counted where the copy is made
+        #    (Executor.prepare_input, Module._stage_group).
+        def _next_ns():
+            return _profiler.span_totals().get("mx/fit/next", (0, 0, 0))[1]
+
+        _stall_mark = [_next_ns()]
         _queue_depth = [getattr(train_data, "queue_depth", None)]
         has_cursor = hasattr(train_data, "get_cursor") \
             and hasattr(train_data, "seek")
         data_cursor = [None]         # last CONSUMED batch's cursor
 
-        def _timed_next(it):
+        def _timed_next(it, step):
             # blocking time on the iterator IS the loop's input stall
-            t0 = time.monotonic()
-            try:
+            with _profiler.span("mx/fit/next", step=step):
                 return next(it)
-            finally:
-                _data_acc[0] += (time.monotonic() - t0) * 1000.0
 
         def _batch_examples(b):
             try:
@@ -316,42 +319,29 @@ class BaseModule:
             except Exception:
                 return 0
 
-        def _batch_h2d_bytes(b):
-            # host-side metadata only (shape x itemsize); never touches
-            # device buffers
-            try:
-                n = 0
-                for arrs in (b.data, b.label or []):
-                    for a in arrs:
-                        k = 1
-                        for d in getattr(a, "shape", ()):
-                            k *= int(d)
-                        n += k * (getattr(getattr(a, "dtype", None),
-                                          "itemsize", 4) or 4)
-                return n
-            except Exception:
-                return 0
-
         def _telem_window(n_steps, examples, gstep):
+            # the tracing's own cost, visible like the rest
             nonlocal _telem_t0
-            now = time.monotonic()
-            data = {"input_stall_ms": _data_acc[0],
-                    "h2d_bytes": _data_acc[1]}
-            qd_fn = _queue_depth[0]
-            if qd_fn is not None:
-                try:
-                    data["queue_depth"] = qd_fn()
-                except Exception:
-                    pass
-            _data_acc[0], _data_acc[1] = 0.0, 0
-            _telemetry.publish_window(
-                steps=n_steps, window_s=now - _telem_t0,
-                examples=examples or None,
-                engine_depth=len(depth_ctl._inflight),
-                global_step=gstep,
-                ddp=self._ddp_stats(n_steps),
-                data=data)
-            _telem_t0 = now
+            with _profiler.span("mx/fit/publish", step=gstep):
+                now = time.monotonic()
+                next_ns = _next_ns()
+                data = {"input_stall_ms":
+                        (next_ns - _stall_mark[0]) / 1e6}
+                _stall_mark[0] = next_ns
+                qd_fn = _queue_depth[0]
+                if qd_fn is not None:
+                    try:
+                        data["queue_depth"] = qd_fn()
+                    except Exception:
+                        pass
+                _telemetry.publish_window(
+                    steps=n_steps, window_s=now - _telem_t0,
+                    examples=examples or None,
+                    engine_depth=len(depth_ctl._inflight),
+                    global_step=gstep,
+                    ddp=self._ddp_stats(n_steps),
+                    data=data)
+                _telem_t0 = now
 
         def _snap_state():
             # quiesce first: a snapshot must capture a settled trajectory,
@@ -366,203 +356,235 @@ class BaseModule:
             return state
 
         for epoch in range(max(begin_epoch, resume_epoch), num_epoch):
-            tic = time.time()
-            if eval_metric is not None:
-                eval_metric.reset()
-            nbatch = 0
-            data_iter = iter(train_data)
-            if ckpt is not None and epoch == resume_epoch and resume_nbatch:
-                if resume_cursor is not None and has_cursor:
-                    # cursor seek: O(1) re-position to the exact
-                    # (epoch, shard, offset) the snapshot had consumed,
-                    # instead of the O(nbatch) batch-skip replay below
-                    train_data.seek(resume_cursor)
-                    data_iter = iter(train_data)
-                    data_cursor[0] = dict(resume_cursor)
-                else:
-                    # re-align the (deterministic, unshuffled-or-reseeded)
-                    # iterator with the checkpointed loop position: the
-                    # first resume_nbatch batches were consumed before the
-                    # snapshot
-                    for _ in range(resume_nbatch):
-                        try:
-                            next(data_iter)
-                        except StopIteration:
-                            break
-                nbatch = resume_nbatch
-            if grouped:
-                # one dispatch per K batches; callbacks fire per batch
-                # (from THIS frame, so BatchEndParam.locals matches the
-                # per-step path) but only after the group's dispatch.
-                # When the module exposes _stage_group, a StagedKFeed
-                # pre-builds each window's stacked device feed on a feeder
-                # thread (async H2D overlapped with the in-flight
-                # dispatch) — the zero-stall K-step feed, docs/data.md.
-                staged_feed = None
-                if _flags.data_staged_feed \
-                        and getattr(self, "_fused", None) is not None \
-                        and self.optimizer_initialized \
-                        and hasattr(self, "_stage_group"):
-                    from ..data.feed import StagedKFeed
-                    staged_feed = StagedKFeed(
-                        data_iter, steps_per_dispatch, self._stage_group,
-                        depth=max(2, int(_flags.data_feed_depth)),
-                        cursor_fn=(train_data.get_cursor if has_cursor
-                                   else None))
-                    _queue_depth[0] = staged_feed.queue_depth
-                try:
-                    group, end_of_batch = [], False
-                    staged, win_cursor = None, None
-                    while not end_of_batch:
-                        if staged_feed is not None:
-                            t0 = time.monotonic()
+            with _profiler.span("mx/fit/epoch", epoch=epoch):
+                tic = time.time()
+                if eval_metric is not None:
+                    eval_metric.reset()
+                nbatch = 0
+                data_iter = iter(train_data)
+                if ckpt is not None and epoch == resume_epoch \
+                        and resume_nbatch:
+                    if resume_cursor is not None and has_cursor:
+                        # cursor seek: O(1) re-position to the exact
+                        # (epoch, shard, offset) the snapshot had consumed,
+                        # instead of the O(nbatch) batch-skip replay below
+                        train_data.seek(resume_cursor)
+                        data_iter = iter(train_data)
+                        data_cursor[0] = dict(resume_cursor)
+                    else:
+                        # re-align the (deterministic, unshuffled-or-reseeded)
+                        # iterator with the checkpointed loop position: the
+                        # first resume_nbatch batches were consumed before the
+                        # snapshot
+                        for _ in range(resume_nbatch):
                             try:
-                                win = staged_feed.next_window()
+                                next(data_iter)
                             except StopIteration:
-                                win = None
-                                end_of_batch = True
-                            _data_acc[0] += \
-                                (time.monotonic() - t0) * 1000.0
-                            if win is not None:
-                                group = list(win.batches)
-                                staged = win.staged
-                                win_cursor = win.cursor
-                                _data_acc[1] += win.h2d_bytes
-                                if len(group) < steps_per_dispatch:
-                                    end_of_batch = True  # tail window
-                        else:
-                            try:
-                                b = _timed_next(data_iter)
-                                group.append(b)
-                                _data_acc[1] += _batch_h2d_bytes(b)
-                            except StopIteration:
-                                end_of_batch = True
-                        if len(group) == steps_per_dispatch or \
-                                (end_of_batch and group):
-                            _fi.fire("step", step=global_step)
-                            if len(group) == steps_per_dispatch:
-                                if staged is not None:
-                                    self._fit_group(group, eval_metric,
-                                                    staged=staged)
-                                else:
-                                    self._fit_group(group, eval_metric)
-                                depth_ctl.admit(self._dispatch_handles())
+                                break
+                    nbatch = resume_nbatch
+                if grouped:
+                    # one dispatch per K batches; callbacks fire per batch
+                    # (from THIS frame, so BatchEndParam.locals matches the
+                    # per-step path) but only after the group's dispatch.
+                    # When the module exposes _stage_group, a StagedKFeed
+                    # pre-builds each window's stacked device feed on a feeder
+                    # thread (async H2D overlapped with the in-flight
+                    # dispatch) — the zero-stall K-step feed, docs/data.md.
+                    staged_feed = None
+                    if _flags.data_staged_feed \
+                            and getattr(self, "_fused", None) is not None \
+                            and self.optimizer_initialized \
+                            and hasattr(self, "_stage_group"):
+                        from ..data.feed import StagedKFeed
+                        staged_feed = StagedKFeed(
+                            data_iter, steps_per_dispatch, self._stage_group,
+                            depth=max(2, int(_flags.data_feed_depth)),
+                            cursor_fn=(train_data.get_cursor if has_cursor
+                                       else None),
+                            first_step=global_step)
+                        _queue_depth[0] = staged_feed.queue_depth
+                    try:
+                        group, end_of_batch = [], False
+                        staged, win_cursor = None, None
+                        while not end_of_batch:
+                            if staged_feed is not None:
+                                try:
+                                    with _profiler.span("mx/fit/next",
+                                                        step=global_step):
+                                        win = staged_feed.next_window()
+                                except StopIteration:
+                                    win = None
+                                    end_of_batch = True
+                                if win is not None:
+                                    group = list(win.batches)
+                                    staged = win.staged
+                                    win_cursor = win.cursor
+                                    if len(group) < steps_per_dispatch:
+                                        end_of_batch = True  # tail window
                             else:
-                                # tail: per-step path — reuses/compiles
-                                # the single-step program instead of
-                                # tracing a second scan variant for the
-                                # odd group size
-                                for b in group:
-                                    self._fit_group([b], eval_metric)
-                                    depth_ctl.admit(
-                                        self._dispatch_handles())
-                            for data_batch in group:
-                                if batch_end_callback is not None:
-                                    for cb in _as_list(batch_end_callback):
-                                        cb(BatchEndParam(
+                                try:
+                                    group.append(_timed_next(
+                                        data_iter, global_step + len(group)))
+                                except StopIteration:
+                                    end_of_batch = True
+                            if len(group) == steps_per_dispatch or \
+                                    (end_of_batch and group):
+                                _fi.fire("step", step=global_step)
+                                if len(group) == steps_per_dispatch:
+                                    with _profiler.span("mx/fit/dispatch",
+                                                        step=global_step,
+                                                        steps=len(group)):
+                                        if staged is not None:
+                                            self._fit_group(group, eval_metric,
+                                                            staged=staged)
+                                        else:
+                                            self._fit_group(group, eval_metric)
+                                    depth_ctl.admit(self._dispatch_handles(),
+                                                    step=global_step)
+                                else:
+                                    # tail: per-step path — reuses/compiles
+                                    # the single-step program instead of
+                                    # tracing a second scan variant for the
+                                    # odd group size
+                                    for i, b in enumerate(group):
+                                        with _profiler.span(
+                                                "mx/fit/dispatch",
+                                                step=global_step + i,
+                                                steps=1):
+                                            self._fit_group([b], eval_metric)
+                                        depth_ctl.admit(
+                                            self._dispatch_handles(),
+                                            step=global_step + i)
+                                for data_batch in group:
+                                    if batch_end_callback is not None:
+                                        with _profiler.span("mx/fit/callbacks",
+                                                            step=global_step):
+                                            for cb in _as_list(
+                                                    batch_end_callback):
+                                                cb(BatchEndParam(
+                                                    epoch=epoch, nbatch=nbatch,
+                                                    eval_metric=eval_metric,
+                                                    locals=locals()))
+                                    nbatch += 1
+                                global_step += len(group)
+                                if win_cursor is not None:
+                                    data_cursor[0] = win_cursor
+                                elif has_cursor and staged_feed is None:
+                                    # fit is the only consumer here, so the
+                                    # iterator cursor IS the consumed position
+                                    data_cursor[0] = train_data.get_cursor()
+                                _telem_window(len(group),
+                                              sum(_batch_examples(b)
+                                                  for b in group), global_step)
+                                if ckpt is not None:
+                                    with _profiler.span("mx/fit/checkpoint",
+                                                        step=global_step):
+                                        ckpt.maybe_save(
+                                            _snap_state, global_step,
                                             epoch=epoch, nbatch=nbatch,
-                                            eval_metric=eval_metric,
-                                            locals=locals()))
-                                nbatch += 1
-                            global_step += len(group)
-                            if win_cursor is not None:
-                                data_cursor[0] = win_cursor
-                            elif has_cursor and staged_feed is None:
-                                # fit is the only consumer here, so the
-                                # iterator cursor IS the consumed position
-                                data_cursor[0] = train_data.get_cursor()
-                            _telem_window(len(group),
-                                          sum(_batch_examples(b)
-                                              for b in group), global_step)
-                            if ckpt is not None:
+                                            meta=meta)
+                                group, staged, win_cursor = [], None, None
+                    finally:
+                        if staged_feed is not None:
+                            staged_feed.close()
+                            _queue_depth[0] = getattr(train_data,
+                                                      "queue_depth", None)
+                else:
+                    end_of_batch = False
+                    try:
+                        next_data_batch = _timed_next(data_iter, global_step)
+                    except StopIteration:
+                        # resume landed exactly on this epoch's end
+                        end_of_batch = True
+                    while not end_of_batch:
+                        data_batch = next_data_batch
+                        if monitor is not None:
+                            monitor.tic()
+                        # global_step steps have completed (and, on the save
+                        # grid, been checkpointed) — "kill@step=N" dies HERE,
+                        # so the supervised restart resumes at exactly step N
+                        _fi.fire("step", step=global_step)
+                        # Python, input placement (mx/feed/h2d inside) and
+                        # the enqueue of the step program; never a wait
+                        with _profiler.span("mx/fit/dispatch",
+                                            step=global_step, steps=1):
+                            self._fit_step(data_batch)
+                        depth_ctl.admit(self._dispatch_handles(),
+                                        step=global_step)
+                        # metric BEFORE prefetch/prepare (reference
+                        # base_module.py:528-545): prepare() may switch the
+                        # bucketing module to the NEXT batch's bucket, whose
+                        # executor has no outputs yet
+                        if eval_metric is not None:
+                            with _profiler.span("mx/fit/metric",
+                                                step=global_step):
+                                self.update_metric(eval_metric,
+                                                   data_batch.label)
+                        if has_cursor:
+                            # capture BEFORE prefetching the next batch: the
+                            # cursor must reflect batches CONSUMED, not the
+                            # loop's read-ahead
+                            data_cursor[0] = train_data.get_cursor()
+                        try:
+                            next_data_batch = _timed_next(data_iter,
+                                                          global_step + 1)
+                            self.prepare(next_data_batch,
+                                         sparse_row_id_fn=sparse_row_id_fn)
+                        except StopIteration:
+                            end_of_batch = True
+                        if monitor is not None:
+                            monitor.toc_print()
+                        if batch_end_callback is not None:
+                            with _profiler.span("mx/fit/callbacks",
+                                                step=global_step):
+                                for cb in _as_list(batch_end_callback):
+                                    cb(BatchEndParam(
+                                        epoch=epoch, nbatch=nbatch,
+                                        eval_metric=eval_metric,
+                                        locals=locals()))
+                        nbatch += 1
+                        global_step += 1
+                        _telem_acc[0] += 1
+                        _telem_acc[1] += _batch_examples(data_batch)
+                        if _telem_acc[0] >= _telem_every:
+                            _telem_window(_telem_acc[0], _telem_acc[1],
+                                          global_step)
+                            _telem_acc = [0, 0]
+                        if ckpt is not None:
+                            with _profiler.span("mx/fit/checkpoint",
+                                                step=global_step):
                                 ckpt.maybe_save(_snap_state, global_step,
                                                 epoch=epoch, nbatch=nbatch,
                                                 meta=meta)
-                            group, staged, win_cursor = [], None, None
-                finally:
-                    if staged_feed is not None:
-                        staged_feed.close()
-                        _queue_depth[0] = getattr(train_data,
-                                                  "queue_depth", None)
-            else:
-                end_of_batch = False
-                try:
-                    next_data_batch = _timed_next(data_iter)
-                except StopIteration:
-                    # resume landed exactly on this epoch's end
-                    end_of_batch = True
-                while not end_of_batch:
-                    data_batch = next_data_batch
-                    _data_acc[1] += _batch_h2d_bytes(data_batch)
-                    if monitor is not None:
-                        monitor.tic()
-                    # global_step steps have completed (and, on the save
-                    # grid, been checkpointed) — "kill@step=N" dies HERE,
-                    # so the supervised restart resumes at exactly step N
-                    _fi.fire("step", step=global_step)
-                    self._fit_step(data_batch)
-                    depth_ctl.admit(self._dispatch_handles())
-                    # metric BEFORE prefetch/prepare (reference
-                    # base_module.py:528-545): prepare() may switch the
-                    # bucketing module to the NEXT batch's bucket, whose
-                    # executor has no outputs yet
-                    if eval_metric is not None:
-                        self.update_metric(eval_metric, data_batch.label)
-                    if has_cursor:
-                        # capture BEFORE prefetching the next batch: the
-                        # cursor must reflect batches CONSUMED, not the
-                        # loop's read-ahead
-                        data_cursor[0] = train_data.get_cursor()
-                    try:
-                        next_data_batch = _timed_next(data_iter)
-                        self.prepare(next_data_batch,
-                                     sparse_row_id_fn=sparse_row_id_fn)
-                    except StopIteration:
-                        end_of_batch = True
-                    if monitor is not None:
-                        monitor.toc_print()
-                    if batch_end_callback is not None:
-                        for cb in _as_list(batch_end_callback):
-                            cb(BatchEndParam(epoch=epoch, nbatch=nbatch,
-                                             eval_metric=eval_metric,
-                                             locals=locals()))
-                    nbatch += 1
-                    global_step += 1
-                    _telem_acc[0] += 1
-                    _telem_acc[1] += _batch_examples(data_batch)
-                    if _telem_acc[0] >= _telem_every:
-                        _telem_window(_telem_acc[0], _telem_acc[1],
-                                      global_step)
-                        _telem_acc = [0, 0]
-                    if ckpt is not None:
-                        ckpt.maybe_save(_snap_state, global_step,
-                                        epoch=epoch, nbatch=nbatch,
-                                        meta=meta)
-            # epoch boundary: drain in-flight dispatches before the host
-            # reads metrics/params (one explicit wait, not one per step)
-            depth_ctl.quiesce()
-            if _telem_acc[0]:    # flush the partial per-step window
-                _telem_window(_telem_acc[0], _telem_acc[1], global_step)
-                _telem_acc = [0, 0]
-            for name, val in (eval_metric.get_name_value()
-                              if eval_metric is not None else []):
-                self.logger.info("Epoch[%d] Train-%s=%f", epoch, name, val)
-            self.logger.info("Epoch[%d] Time cost=%.3f", epoch,
-                             time.time() - tic)
-            arg_p, aux_p = self.get_params()
-            self.set_params(arg_p, aux_p)
-            if epoch_end_callback is not None:
-                for cb in _as_list(epoch_end_callback):
-                    cb(epoch, self.symbol, arg_p, aux_p)
-            if eval_data is not None:
-                res = self.score(eval_data, validation_metric,
-                                 score_end_callback=eval_end_callback,
-                                 batch_end_callback=eval_batch_end_callback,
-                                 epoch=epoch)
-                for name, val in res:
-                    self.logger.info("Epoch[%d] Validation-%s=%f",
-                                     epoch, name, val)
-            train_data.reset()
+                # epoch boundary: drain in-flight dispatches before the host
+                # reads metrics/params (one explicit wait, not one per step)
+                depth_ctl.quiesce()
+                if _telem_acc[0]:    # flush the partial per-step window
+                    _telem_window(_telem_acc[0], _telem_acc[1], global_step)
+                    _telem_acc = [0, 0]
+                for name, val in (eval_metric.get_name_value()
+                                  if eval_metric is not None else []):
+                    self.logger.info("Epoch[%d] Train-%s=%f", epoch, name, val)
+                self.logger.info("Epoch[%d] Time cost=%.3f", epoch,
+                                 time.time() - tic)
+                with _profiler.span("mx/fit/epoch_end"):
+                    arg_p, aux_p = self.get_params()
+                    self.set_params(arg_p, aux_p)
+                if epoch_end_callback is not None:
+                    with _profiler.span("mx/fit/epoch_callbacks"):
+                        for cb in _as_list(epoch_end_callback):
+                            cb(epoch, self.symbol, arg_p, aux_p)
+                if eval_data is not None:
+                    with _profiler.span("mx/fit/eval"):
+                        res = self.score(
+                            eval_data, validation_metric,
+                            score_end_callback=eval_end_callback,
+                            batch_end_callback=eval_batch_end_callback,
+                            epoch=epoch)
+                    for name, val in res:
+                        self.logger.info("Epoch[%d] Validation-%s=%f",
+                                         epoch, name, val)
+                train_data.reset()
         if ckpt is not None:
             ckpt.wait()  # join an in-flight async save; surface errors
 

@@ -343,24 +343,6 @@ class Module(BaseModule):
                                 ddp_mesh=ddp_mesh)
         self._fused_opt_state = self._fused.init_state()
 
-    def _fused_step_flops(self):
-        """Chip-free FLOPs of one fused step via XLA cost analysis, for
-        the live MFU telemetry gauge. Pays a lowering, so only the
-        MXNET_TELEMETRY_MFU=1 path in fit() calls it (bench.py supplies
-        flops via telemetry.set_run_info instead); None when no fused
-        step is bound or the backend has no cost model."""
-        if self._fused is None or self._exec is None:
-            return None
-        try:
-            ex = self._exec
-            cost = self._fused.cost_analysis(
-                ex._arg_vals(), ex._aux_vals(), self._fused_opt_state)
-            if cost and cost.get("flops", 0) > 0:
-                return float(cost["flops"])
-        except Exception:
-            pass
-        return None
-
     def _ddp_stats(self, n_steps):
         """Host-held DDP bucket/comm summary scaled to a telemetry window
         of ``n_steps`` (base_module._telem_window). Pure bookkeeping from
@@ -428,12 +410,6 @@ class Module(BaseModule):
         results commit immediately. Falls back to the eager pair when the
         fused step is not engaged."""
         if self._fused is not None and self.optimizer_initialized:
-            from .. import profiler as _profiler
-            if _profiler.is_active("symbolic"):
-                with _profiler.op_timer(
-                        "Module::fused_fit_step", "symbolic",
-                        lambda: [o._data for o in self._exec.outputs]):
-                    return self._fit_step_fused_impl(data_batch)
             return self._fit_step_fused_impl(data_batch)
         else:
             self.forward_backward(data_batch)
@@ -508,82 +484,66 @@ class Module(BaseModule):
                 self.update_metric(eval_metric, b.label)
             ex.outputs = last
 
-    def _stage_group(self, data_batches):
+    def _stage_group(self, data_batches, step=None):
         """Stage one K-step window's device feed ahead of dispatch (the
         ``stage_fn`` hook of :class:`mxnet_tpu.data.feed.StagedKFeed`).
         Runs on the feeder thread while the previous window is still in
-        flight: per-batch cast via ``prepare_input`` then the SAME
-        cast/stack/commit ``run_k`` would apply (``stack_feeds``), so the
-        staged window is bitwise-identical to the unstaged path. Returns
-        ``(payload, h2d_bytes)``; the payload carries both the stacked
-        scan feed and the pre-cast last feed for the executor rebind.
-        Only reads executor metadata (dtypes/sharding) — thread-safe
-        against the main loop, which only commits donated outputs."""
+        flight, or on the fit thread inside the dispatch when nothing
+        staged the window: per-batch cast (+ placement) then the
+        cast/stack/commit of ``stack_feeds``, the same ops in the same
+        order either way, so staged and unstaged windows are
+        bitwise-identical. Batches that are not yet where the executor
+        computes make the window's host-to-device copy: it runs under ONE
+        ``mx/feed/h2d`` span carrying the window's ``step`` and the bytes
+        copied. Returns the payload: the stacked scan feed and the
+        pre-cast last feed for the executor rebind. Only reads executor
+        metadata (dtypes/sharding) — thread-safe against the main loop,
+        which only commits donated outputs."""
+        import contextlib
+        from .. import profiler as _profiler
+        from .. import telemetry as _telemetry
         ex = self._exec
         place_each = ex._mesh is None
-        feeds = [{name: ex.prepare_input(name, arr, place=place_each)
-                  for name, arr in self._feed(b).items()}
-                 for b in data_batches]
-        nbytes = 0
-        for b in data_batches:
-            for arrs in (b.data, b.label or []):
-                for a in arrs:
-                    shape = getattr(a, "shape", ())
-                    n = 1
-                    for d in shape:
-                        n *= int(d)
-                    itemsize = getattr(
-                        getattr(a, "dtype", None), "itemsize", 4) or 4
-                    nbytes += n * itemsize
-        return {"stacked": self._fused.stack_feeds(feeds),
-                "last": feeds[-1]}, nbytes
+        items = [self._feed(b) for b in data_batches]
+        copied = {name for it in items for name, v in it.items()
+                  if not ex._is_placed(v, name)}
+        sp = _profiler.span("mx/feed/h2d", step=step) if copied else None
+        with sp or contextlib.nullcontext():
+            feeds = [{name: ex._place_input(ex._cast_input(name, v), name)
+                      if place_each else ex._cast_input(name, v)
+                      for name, v in it.items()} for it in items]
+            stacked = self._fused.stack_feeds(feeds)
+            if sp is not None:
+                # per slice on one device; under a mesh the stacked (and
+                # already down-cast) buffer is what crosses
+                sp.add(bytes=_telemetry.count_h2d(sum(
+                    sum(f[n].nbytes for f in feeds) if place_each
+                    else stacked[n].nbytes for n in copied)))
+        return {"stacked": stacked, "last": feeds[-1]}
 
     def _fit_step_k(self, data_batches, staged=None):
         """K fit steps in ONE donating XLA dispatch (`FusedStep.run_k` —
         the train-loop-under-scan TPU idiom). Caller (:meth:`_fit_group`)
         guarantees the fused step is engaged and K > 1. Returns the
         stacked per-step output values (list of ``(K, ...)`` jax arrays)
-        so the fit loop can update metrics per sub-batch."""
+        so the fit loop can update metrics per sub-batch. ``staged`` is
+        the window's feed from :meth:`_stage_group` on the feeder thread;
+        without it the window is staged here."""
         assert self._fused is not None and self.optimizer_initialized \
             and len(data_batches) > 1
-        from .. import profiler as _profiler
-        if _profiler.is_active("symbolic"):
-            with _profiler.op_timer(
-                    "Module::fused_fit_step_k", "symbolic",
-                    lambda: [o._data for o in self._exec.outputs]):
-                return self._fit_step_k_impl(data_batches, staged=staged)
-        return self._fit_step_k_impl(data_batches, staged=staged)
-
-    def _fit_step_k_impl(self, data_batches, staged=None):
         from .. import random as _random
         ex = self._exec
-        if staged is not None:
-            # pre-staged by _stage_group on the feeder thread; the stacked
-            # buffer is already cast + committed to the device layout
-            feeds = staged["stacked"]
-            last = staged["last"]
-            place_each = ex._mesh is None
-        else:
-            # each feed value gets the SAME cast (+ placement) set_inputs
-            # applies (host iterator batches are cpu-committed; stacking
-            # them raw would hand the donating jit cpu feeds next to
-            # device params). Under a mesh, run_k re-commits the STACKED
-            # array to P(None, 'dp') anyway, so per-slice placement would
-            # be paid twice — skip it.
-            place_each = ex._mesh is None
-            feeds = [{name: ex.prepare_input(name, arr, place=place_each)
-                      for name, arr in self._feed(b).items()}
-                     for b in data_batches]
-            last = feeds[-1]
+        if staged is None:
+            staged = self._stage_group(data_batches)
         # keep the executor's input bindings current (shape checks, later
-        # forward() calls) without re-casting/re-transferring the batch
-        for name, val in last.items():
-            ex.arg_dict[name]._rebind(
-                val if place_each else ex._place_input(val, name))
+        # forward() calls): the pre-cast last feed, placed like any input
+        # (under a mesh the slices were left unplaced for the stack)
+        for name, val in staged["last"].items():
+            ex.arg_dict[name]._rebind(ex.prepare_input(name, val))
         keys = [_random.next_key() for _ in data_batches]
         outs, new_params, new_aux, new_opt, new_met = self._fused.run_k(
             ex._arg_vals(), ex._aux_vals(), self._fused_opt_state,
-            feeds, keys, met_state=self._fused_met_state)
+            staged["stacked"], keys, met_state=self._fused_met_state)
         self._commit_fused([o[-1] for o in outs], new_params, new_aux,
                            new_opt, n_steps=len(data_batches),
                            new_met=new_met)

@@ -114,9 +114,11 @@ class NDArray:
     # ------------------------------------------------------------ conversion
     def asnumpy(self):
         from .. import profiler as _profiler
-        _profiler.record_host_sync("d2h", getattr(self._data, "nbytes", 0))
+        nbytes = getattr(self._data, "nbytes", 0)
+        _profiler.record_host_sync("d2h", nbytes)
         try:
-            return _np.asarray(self._data)
+            with _profiler.span("mx/sync/d2h", bytes=nbytes):
+                return _np.asarray(self._data)
         except Exception as e:
             raise MXNetError(str(e)) from e
 

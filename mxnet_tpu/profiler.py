@@ -4,25 +4,33 @@ Task/Frame/Event/Counter/Marker objects).
 
 TPU-native design: the reference hooks each engine OprBlock
 (src/engine/threaded_engine.h:80). Here the analogs are the eager invoke
-path (one event per op, measured to completion — profiling forces a sync
-like MXNET_PROFILER on a stream does), the CachedOp jitted runner and the
-symbolic Executor (one event per compiled graph execution), plus
-device-side XLA traces via ``jax.profiler`` when a trace dir is configured.
+path and the CachedOp jitted runner (one event per op, measured to
+completion — profiling forces a sync like MXNET_PROFILER on a stream
+does), and on the training path (``Module.fit``, the feed, the engine,
+the symbolic Executor) the never-syncing :func:`span`, which is also in
+the device-side XLA trace via ``jax.profiler`` whenever one is taken
+(``trace_dir``, or any other ``jax.profiler`` session).
 """
 from __future__ import annotations
 
+import collections
 import json
 import os
 import threading
 import time
 
+import jax
+
 __all__ = ["set_config", "profiler_set_config", "set_state",
            "profiler_set_state", "dump", "dumps", "pause", "resume",
            "Task", "Frame", "Event", "Counter", "Marker",
            "record_host_sync", "sync_counters", "reset_sync_counters",
-           "set_sync_trace", "record_counter"]
+           "set_sync_trace", "record_counter",
+           "span", "spans", "span_totals", "open_self_ns", "reset_spans",
+           "Span", "SPAN_RING_LEN"]
 
 _lock = threading.Lock()
+CHROME_EVENTS_MAX = 1 << 20
 
 
 class _ProfilerState:
@@ -35,7 +43,9 @@ class _ProfilerState:
         self.profile_memory = False
         self.profile_api = False
         self.trace_dir = None       # jax.profiler XLA trace output
-        self.events = []            # chrome trace events
+        # chrome trace events since the last dump; bounded, so a profiler
+        # left on for a day drops its oldest events, not the process
+        self.events = collections.deque(maxlen=CHROME_EVENTS_MAX)
         self.agg = {}               # name -> [count, total_us, min, max]
         # Two clocks, captured together: durations are differences of the
         # MONOTONIC clock (immune to NTP steps/slew mid-span), while event
@@ -104,10 +114,8 @@ def set_state(state="stop"):
     assert state in ("run", "stop")
     run = state == "run"
     if run and not _state.running and _state.trace_dir:
-        import jax
         jax.profiler.start_trace(_state.trace_dir)
     if not run and _state.running and _state.trace_dir:
-        import jax
         jax.profiler.stop_trace()
     _state.running = run
     _active = run
@@ -230,7 +238,10 @@ def record_counter(name, value):
 
 
 class _OpTimer:
-    """Context manager used by the invoke/CachedOp hooks."""
+    """Context manager used by the CachedOp hook: like the eager per-op
+    path it measures to completion, so it blocks on the outputs. The
+    training path (``Module.fit``, ``Executor``) uses :func:`span`, which
+    never blocks."""
 
     __slots__ = ("name", "cat", "arrays", "t0")
 
@@ -254,6 +265,181 @@ class _OpTimer:
         record_event(self.name, self.cat, self.t0, _now_us() - self.t0)
 
 
+# ---------------------------------------------------------------------------
+# Spans: the one primitive every layer boundary of the training path uses
+# (docs/observability.md "Spans"). Always on, like the sync census: a span
+# costs microseconds next to the work it brackets, the benchmark's command
+# line can turn nothing on, and an operator wants the last minute after a
+# stall. A span NEVER blocks on the device: with the profiler on, ``fit``
+# runs the same program as with it off.
+#
+# Three sinks, one stamp:
+#   * a bounded ring of finished spans and per-name totals, stamped with
+#     ``time.time_ns()`` (``spans()``, ``span_totals()``);
+#   * ``jax.profiler.TraceAnnotation`` of the same name whenever a device
+#     trace is being taken, so the span is in the xplane beside the ops;
+#   * the chrome JSON while ``set_state("run")`` is on.
+# ---------------------------------------------------------------------------
+
+SPAN_RING_LEN = 65536
+
+Span = collections.namedtuple(
+    "Span", "name start_ns end_ns parent step tid counts")
+
+_ring = collections.deque(maxlen=SPAN_RING_LEN)
+_span_totals = {}           # name -> [count, total_ns, self_ns]
+_span_tls = threading.local()
+_trace_enabled = jax.profiler.TraceAnnotation.is_enabled
+
+
+def _span_stack():
+    try:
+        return _span_tls.stack
+    except AttributeError:
+        _span_tls.stack = []
+        return _span_tls.stack
+
+
+def _finish_span(name, t0, t1, parent, step, tid, counts, child_ns):
+    _ring.append((name, t0, t1, parent, step, tid, counts))
+    with _lock:
+        ent = _span_totals.get(name)
+        if ent is None:
+            ent = _span_totals[name] = [0, 0, 0]
+        ent[0] += 1
+        ent[1] += t1 - t0
+        ent[2] += t1 - t0 - child_ns
+    if _active:
+        # relative to the wall anchor, so that ``ts`` is the ring's stamp
+        record_event(name, "span", t0 / 1e3 - _state.epoch_wall_us,
+                     (t1 - t0) / 1e3, tid=tid)
+
+
+class span:
+    """``with profiler.span("mx/fit/dispatch", step=n, steps=1):`` — one
+    timed region of the training path.
+
+    ``step`` is the ``global_step`` of the dispatch the work belongs to,
+    the identifier the spans of one step share (the feeder thread's
+    included); left out, it is the enclosing span's. ``counts`` are what
+    the region moved (``bytes``, ``steps``); :meth:`add` sets one that is
+    only known inside. The parent is the enclosing span of the same
+    thread, by name: spans of one thread nest, so the intervals say the
+    rest."""
+
+    __slots__ = ("name", "step", "counts", "_t0", "_child_ns", "_ann",
+                 "_stack")
+
+    def __init__(self, name, step=None, **counts):
+        self.name = name
+        self.step = step
+        self.counts = counts
+
+    def add(self, **counts):
+        self.counts.update(counts)
+
+    def __enter__(self):
+        stack = self._stack = _span_stack()
+        if self.step is None and stack:
+            self.step = stack[-1].step
+        stack.append(self)
+        self._child_ns = 0
+        self._ann = None
+        if _trace_enabled():
+            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
+        self._t0 = time.time_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.time_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        stack = self._stack
+        stack.pop()
+        parent = None
+        if stack:
+            parent = stack[-1].name
+            stack[-1]._child_ns += t1 - self._t0
+        _finish_span(self.name, self._t0, t1, parent, self.step,
+                     threading.get_ident(), self.counts or None,
+                     self._child_ns)
+
+
+def spans(since_ns=None):
+    """The ring's content, oldest first: :class:`Span` tuples ``(name,
+    start_ns, end_ns, parent, step, tid, counts)`` on the clock of
+    ``time.time_ns()``; with ``since_ns`` only those that ended later."""
+    out = [Span._make(e) for e in list(_ring)]
+    if since_ns is not None:
+        out = [e for e in out if e.end_ns > since_ns]
+    return out
+
+
+def span_totals():
+    """name -> (count, total_ns, self_ns) over every span finished since
+    the process started (or :func:`reset_spans`), whatever the ring has
+    dropped. Self time is what no child span covers."""
+    with _lock:
+        return {k: tuple(v) for k, v in _span_totals.items()}
+
+
+def open_self_ns(name):
+    """Self time so far of the calling thread's innermost OPEN span
+    ``name``: for a long-lived span (``mx/fit/epoch``) whose total is
+    only booked at its end. 0 where the thread has none open."""
+    now = time.time_ns()
+    stack = _span_stack()
+    for i in range(len(stack) - 1, -1, -1):
+        if stack[i].name == name:
+            inner = now - stack[i + 1]._t0 if i + 1 < len(stack) else 0
+            return now - stack[i]._t0 - stack[i]._child_ns - inner
+    return 0
+
+
+def reset_spans():
+    _ring.clear()
+    with _lock:
+        _span_totals.clear()
+
+
+_COMPILE_EVENTS = "/jax/core/compile/"
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_jax_duration(event, seconds, **_kwargs):
+    """``jax.monitoring`` listener: every phase of a compilation (trace,
+    lowering, backend compile or cache read) becomes an ``mx/compile``
+    span that ends now, under the span and the ``step`` that caused it,
+    so an operator sees WHICH step recompiled. ``compile/count`` counts
+    backend compiles, ``compile/seconds`` every phase."""
+    if not event.startswith(_COMPILE_EVENTS):
+        return
+    t1 = time.time_ns()
+    dur = int(seconds * 1e9)
+    stack = _span_stack()
+    parent = step = None
+    if stack:
+        # not booked as the parent's child time: the phases of one
+        # compilation nest (a trace inside a trace) and would count twice
+        parent, step = stack[-1].name, stack[-1].step
+    _finish_span("mx/compile", t1 - dur, t1, parent, step,
+                 threading.get_ident(),
+                 {"event": event[len(_COMPILE_EVENTS):],
+                  "seconds": seconds}, 0)
+    from . import telemetry
+    if event == _BACKEND_COMPILE:
+        telemetry.counter("compile/count",
+                          "XLA backend compilations (or compile-cache "
+                          "reads) since the process started").inc()
+    telemetry.counter("compile/seconds",
+                      "seconds spent tracing, lowering and compiling "
+                      "jitted programs").inc(seconds)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
 def is_active(kind="imperative"):
     if not _active:
         return False
@@ -274,13 +460,14 @@ def dump(finished=True, profile_process="worker"):
         trace = {
             "traceEvents": [
                 {"name": "process_name", "ph": "M", "pid": 0,
-                 "args": {"name": "mxnet_tpu worker"}}] + _state.events,
+                 "args": {"name": "mxnet_tpu worker"}}]
+            + list(_state.events),
             "displayTimeUnit": "ms",
         }
         with open(_state.filename, "w") as f:
             json.dump(trace, f)
         if finished:
-            _state.events = []
+            _state.events.clear()
     return _state.filename
 
 

@@ -37,7 +37,8 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "FlightRecorder",
     "counter", "gauge", "histogram", "snapshot", "default_registry",
     "set_run_info", "run_info", "flight_recorder", "prometheus_text",
-    "parse_exposition", "publish_window", "exporters", "federate", "prom",
+    "parse_exposition", "publish_window", "count_h2d", "exporters",
+    "federate", "prom",
     "recorder",
 ]
 
@@ -61,8 +62,8 @@ def _ensure_exporters():
 
 def _live_mfu(steps, window_s):
     """Host-side live MFU from run-scoped flops — no device traffic.
-    Returns None until someone (bench.py, or fit's flag-gated lazy
-    cost_analysis) has called ``set_run_info(flops_per_step=...)``."""
+    Returns None until the caller (bench.py does) has supplied
+    ``set_run_info(flops_per_step=...)``."""
     info = run_info()
     flops = info.get("flops_per_step")
     if not flops or window_s <= 0:
@@ -93,6 +94,17 @@ def _stall_attribution(steps, window_s, stall_ms):
     return frac, bool(frac > 0.10)
 
 
+def count_h2d(nbytes):
+    """Book ``nbytes`` of input copied to the device, at the place where
+    the copy is made (``Executor.prepare_input``, ``Module._stage_group``):
+    the ``data/h2d_bytes`` counter. Returns ``nbytes``."""
+    counter("data/h2d_bytes",
+            "host->device input bytes copied for the step loop, counted "
+            "where the copy is made (an input already on the executor's "
+            "device counts nothing)").inc(nbytes)
+    return int(nbytes)
+
+
 def publish_window(*, steps, window_s, examples=None, engine_depth=None,
                    global_step=None, source="train", ddp=None,
                    embed=None, data=None):
@@ -120,12 +132,19 @@ def publish_window(*, steps, window_s, examples=None, engine_depth=None,
     extra device traffic.
 
     ``data`` (optional) is fit's host-held input-pipeline summary for
-    the window — ``{"input_stall_ms", "h2d_bytes", "queue_depth"}``
-    (stall = wall-clock the loop spent blocked on the iterator / staged
-    feed; h2d_bytes from batch shape metadata; queue_depth from the
-    feeder's bounded queue). Publishes ``data/*`` gauges plus the
-    perfmodel-backed input-bound/compute-bound attribution
-    (``data/stall_frac``, ``data/input_bound`` — docs/data.md).
+    the window — ``{"input_stall_ms", "queue_depth"}`` (stall = the
+    window's ``mx/fit/next`` spans: wall-clock the loop spent blocked on
+    the iterator / staged feed; queue_depth from the feeder's bounded
+    queue). Publishes ``data/*`` gauges plus the perfmodel-backed
+    input-bound/compute-bound attribution (``data/stall_frac``,
+    ``data/input_bound`` — docs/data.md). ``data/h2d_bytes`` is counted
+    where the copy is made (:func:`count_h2d`); a caller that copies
+    elsewhere may still add its own under ``"h2d_bytes"``.
+
+    Also republishes ``profiler.span_totals()`` as cumulative
+    ``host_span/<name>_ms`` gauges (the ``mx/`` prefix dropped) beside
+    ``host_sync/*``, and ``host_span/fit/unspanned_ms``: the time of
+    ``mx/fit/epoch`` under no child span, what the spans do not cover.
     """
     from mxnet_tpu import profiler
 
@@ -201,10 +220,8 @@ def publish_window(*, steps, window_s, examples=None, engine_depth=None,
                   "prefetch/staged-feed queue occupancy at window end "
                   "(0 with stalls = producer-bound)").set(
                       data.get("queue_depth", 0))
-        counter("data/h2d_bytes",
-                "host->device input bytes fed to the step loop "
-                "(batch shape metadata, not a device read)").inc(
-                    data.get("h2d_bytes", 0))
+        if data.get("h2d_bytes"):
+            count_h2d(data["h2d_bytes"])
         frac, input_bound = _stall_attribution(steps, window_s, stall_ms)
         gauge("data/stall_frac",
               "fraction of the window spent input-stalled").set(frac)
@@ -218,6 +235,17 @@ def publish_window(*, steps, window_s, examples=None, engine_depth=None,
         if key in sync:
             gauge("host_sync/%s" % key,
                   "cumulative host-sync census (profiler)").set(sync[key])
+
+    totals = profiler.span_totals()
+    for name, (_count, total_ns, _self_ns) in totals.items():
+        gauge("host_span/%s_ms" % name.removeprefix("mx/"),
+              "cumulative host ms inside the profiler span of that name "
+              "(docs/observability.md, Spans)").set(total_ns / 1e6)
+    gauge("host_span/fit/unspanned_ms",
+          "cumulative host ms of mx/fit/epoch under no child span: what "
+          "the spans inside fit do not cover").set(
+              (totals.get("mx/fit/epoch", (0, 0, 0))[2]
+               + profiler.open_self_ns("mx/fit/epoch")) / 1e6)
 
     record = {"source": source, "global_step": global_step,
               "steps": steps, "window_s": window_s, "step_ms": step_ms,

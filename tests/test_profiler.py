@@ -148,8 +148,8 @@ def test_executor_events_profiled(tmp_path):
     profiler.dump()
     events = json.load(open(f))["traceEvents"]
     names = {e.get("name") for e in events}
-    assert "Executor::forward_train" in names
-    assert "Executor::backward" in names
+    assert "mx/exec/forward_train" in names
+    assert "mx/exec/backward" in names
 
 
 def test_group2ctx_raises_loudly():
@@ -166,8 +166,8 @@ def test_group2ctx_raises_loudly():
 
 def test_fused_fit_step_is_profiled():
     """The atomic donating fit step must appear in the profile like the
-    eager Executor::forward does (observability parity for the path the
-    bench measures)."""
+    eager Executor.forward does (observability parity for the path the
+    bench measures): its span is fit's ``mx/fit/dispatch``."""
     sym = mx.sym.SoftmaxOutput(
         mx.sym.FullyConnected(mx.sym.Variable("data"), num_hidden=4),
         name="softmax")
@@ -184,4 +184,4 @@ def test_fused_fit_step_is_profiled():
     finally:
         profiler.set_state("stop")
     d = profiler.dumps(reset=True)
-    assert "Module::fused_fit_step" in d
+    assert "mx/fit/dispatch" in d

@@ -315,6 +315,8 @@ def test_streaming_fit_same_budget_and_bitwise_vs_in_memory(tmp_path):
 
         assert flags.data_staged_feed  # default-on staged K-step feed
         m_stream = mx.metric.create("acc")
+        h2d = telemetry.default_registry().get("data/h2d_bytes")
+        h2d_before = h2d.value() if h2d is not None else 0
         profiler.reset_sync_counters()
         _fit(mod, it, m_stream)
         counters = profiler.sync_counters()
@@ -330,8 +332,10 @@ def test_streaming_fit_same_budget_and_bitwise_vs_in_memory(tmp_path):
     # the window telemetry actually reported the data plane (host-held)
     reg = telemetry.default_registry()
     assert reg.get("data/input_stall_ms").value() >= 0
-    assert reg.get("data/h2d_bytes").value() \
-        >= X.nbytes + Y.nbytes
+    # data/h2d_bytes counts what is copied, where it is copied: in a CPU
+    # process the stream's batches already live on the executor's device
+    h2d = reg.get("data/h2d_bytes")
+    assert (h2d.value() if h2d is not None else 0) == h2d_before
     assert reg.get("data/examples_per_s").value() > 0
 
     # ---- in-memory baseline: same rows, same order, same init ----
