@@ -167,7 +167,7 @@ def _forward_logits(sym, arg_params, aux_params, x, ctx):
 
 
 def phase_train(ctx=None, num_layers=50, classes=1000, side=224, batch=128,
-                k=4, step_epochs=2, scan_epochs=3, ref_batch=8):
+                k=4, step_epochs=2, scan_epochs=3, ref_batch=8, lr=0.05):
     """ResNet-50, batch 128, bf16 compute over f32 masters, through
     ``Module.fit(kvstore="tpu_sync")``: ``step_epochs`` epochs of ``k``
     single-step dispatches, then ``scan_epochs`` epochs of one ``k``-step
@@ -189,12 +189,12 @@ def phase_train(ctx=None, num_layers=50, classes=1000, side=224, batch=128,
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
         t0 = time.perf_counter()
-        _fit_epochs(mod, it, 0, step_epochs, 1, losses, stamps)
+        _fit_epochs(mod, it, 0, step_epochs, 1, losses, stamps, lr=lr)
         check(mod._fused is not None, "the fused step did not engage")
         ran_step = mod._fused._jitted_donate._cache_size()
         t1 = time.perf_counter()
         _fit_epochs(mod, it, step_epochs, step_epochs + scan_epochs, k,
-                    losses, stamps)
+                    losses, stamps, lr=lr)
         ran_scan = mod._fused._jitted_k._cache_size()
     bad = [str(w.message) for w in caught
            if "donated buffers were not usable" in str(w.message)]

@@ -601,9 +601,8 @@ class HybridBlock(Block):
         # session dtype policy (config.compute_dtype): cast f32 params and
         # inputs to the compute dtype INSIDE the traced program, so the
         # hybridized path gets the same mixed-precision semantics as the
-        # fused Module step. Params flagged _keep_f32 (BN affine/stats) are
-        # exempt; the grouped downcast keeps the lowered program at one
-        # convert for all params instead of one per param.
+        # fused Module step: each param cast at its own shape. Params
+        # flagged _keep_f32 (BN affine/stats) are exempt.
         from .. import config as _config
         cdt = _config.compute_dtype(default=None)
         keep_idx = frozenset(i for i, p in enumerate(params)
@@ -611,16 +610,10 @@ class HybridBlock(Block):
 
         def traced(param_arrays, in_arrays, key):
             if cdt is not None:
-                from ..module.fused import _downcast_group
-                cast_i = [i for i, a in enumerate(param_arrays)
-                          if a.dtype == jnp.float32 and i not in keep_idx
-                          and a.size > 0]
-                if cast_i:
-                    low = _downcast_group(
-                        [param_arrays[i] for i in cast_i], cdt)
-                    param_arrays = list(param_arrays)
-                    for i, v in zip(cast_i, low):
-                        param_arrays[i] = v
+                param_arrays = [
+                    a.astype(cdt) if a.dtype == jnp.float32
+                    and i not in keep_idx and a.size > 0 else a
+                    for i, a in enumerate(param_arrays)]
                 in_arrays = [a.astype(cdt) if a.dtype == jnp.float32 else a
                              for a in in_arrays]
             tctx = _TraceCtx(dict(zip(param_names, param_arrays)), training)

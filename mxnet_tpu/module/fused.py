@@ -27,45 +27,6 @@ import jax
 import jax.numpy as jnp
 
 
-from functools import partial as _partial
-
-
-@_partial(jax.custom_vjp, nondiff_argnums=(1,))
-def _downcast_group(leaves, cdt):
-    """Cast a list of f32 arrays to ``cdt`` with ONE convert op: flatten,
-    concatenate, convert, split. A naive per-leaf ``astype`` emits one
-    f32->cdt convert per parameter in the lowered program (and one
-    cdt->f32 per gradient on the way back); grouping keeps the convert
-    count O(1) in the number of parameters, which the chip-free HLO
-    budget test (tests/test_step_hlo_budget.py) relies on."""
-    flat = jnp.concatenate([l.reshape(-1) for l in leaves])
-    h = flat.astype(cdt)
-    out, off = [], 0
-    for l in leaves:
-        out.append(h[off:off + l.size].reshape(l.shape))
-        off += l.size
-    return out
-
-
-def _downcast_group_fwd(leaves, cdt):
-    return _downcast_group(leaves, cdt), None
-
-
-def _downcast_group_bwd(cdt, _res, cots):
-    # mirror of the forward: group the cdt->f32 gradient upcasts into one
-    # convert (the cotangents carry the shapes, so no residuals needed)
-    flat = jnp.concatenate([c.reshape(-1) for c in cots])
-    f = flat.astype(jnp.float32)
-    out, off = [], 0
-    for c in cots:
-        out.append(f[off:off + c.size].reshape(c.shape))
-        off += c.size
-    return (out,)
-
-
-_downcast_group.defvjp(_downcast_group_fwd, _downcast_group_bwd)
-
-
 def _flatten_state(state):
     """Eager create_state result -> fused state tuple (see the contract in
     Optimizer.fused_ops)."""
@@ -158,13 +119,13 @@ class FusedStep:
 
             def f(d):
                 if cdt is not None:
-                    cast = [k for k, v in d.items()
-                            if v.dtype == jnp.float32 and k not in keepf
-                            and v.size > 0]
-                    if cast:
-                        low = _downcast_group([d[k] for k in cast], cdt)
-                        d = dict(d)
-                        d.update(zip(cast, low))
+                    # each master at its own shape: XLA fuses the convert
+                    # into its consumer, and the transpose of `convert`
+                    # hands the optimizer an f32 gradient of the same shape
+                    d = {k: (v.astype(cdt)
+                             if v.dtype == jnp.float32 and k not in keepf
+                             and v.size > 0 else v)
+                         for k, v in d.items()}
                 return eval_fn({**rest, **d}, aux_vals, key, True)
 
             from ..executor import mirror_wrap

@@ -59,8 +59,11 @@ def test_bench_refuses_without_a_chip():
 
 @pytest.fixture(scope="module")
 def trained():
+    # the rate goes down with the batch: at batch 8 the real size's 0.05
+    # spikes on one init seed in three, whatever the tree
     return chip_smoke.phase_train(ctx=mx.tpu(), num_layers=18, classes=10,
-                                  side=32, batch=8, k=2, ref_batch=4)
+                                  side=32, batch=8, k=2, ref_batch=4,
+                                  lr=0.005)
 
 
 def test_train_phase_tiny(trained):

@@ -488,7 +488,7 @@ def test_hybrid_forward_contrib_namespace():
 
 def test_hybridize_compute_dtype_policy_bf16():
     """Session dtype policy (MXNET_COMPUTE_DTYPE=bfloat16) on the CachedOp
-    path: compute runs bf16 off a single grouped downcast, BatchNorm
+    path: compute runs bf16 off a cast of each parameter, BatchNorm
     params/stats are excluded (stay f32), and outputs track the f32 run."""
     from mxnet_tpu import config
 

@@ -721,3 +721,117 @@ def test_fused_module_bf16_policy_trains_and_matches_f32():
     assert rm.dtype == np.float32 and not np.allclose(rm, 0)
     rv = aux_bf["bn1_moving_var"].asnumpy()
     assert _rel_err(rv, aux_32["bn1_moving_var"].asnumpy()) < 0.05
+
+
+# ------------------------------------------- the cast is per parameter
+def _policy_fused_module():
+    """Fused Module step of fc-bn-fc plus a zero-size weight (a non-float32
+    parameter cannot get here: Module keeps those on the eager path)."""
+    net = _make_net().get_internals()["fc2_output"]
+    net = mx.sym.concat(net, mx.sym.Variable("empty_weight", shape=(0, 4)),
+                        dim=0)
+    sym = mx.sym.SoftmaxOutput(net, name="softmax")
+    X, Y = _data(16)
+    it = mx.io.NDArrayIter(X, Y, batch_size=16, label_name="softmax_label")
+    mod = mx.mod.Module(sym)
+    mod.fit(it, num_epoch=1, kvstore="tpu_sync", optimizer="sgd",
+            optimizer_params={"learning_rate": 0.05, "momentum": 0.9})
+    fused, ex = mod._fused, mod._exec
+    assert fused is not None and fused._compute_dtype is not None
+    text = fused.lower(ex._arg_vals(), ex._aux_vals(),
+                       mod._fused_opt_state).as_text()
+    masters = {k: ex.arg_dict[k] for k in fused.param_names}
+    # the step's leading entry arguments are the params dict, keys sorted
+    names = sorted(masters)
+    handed = [(k, v.asnumpy()) for k, v in mod.get_params()[0].items()]
+    handed += [(k, np.asarray(s)) for k, st in mod._fused_opt_state.items()
+               for s in st]
+    return (text, names, {"fc1_weight", "fc1_bias", "fc2_weight", "fc2_bias"},
+            {k: (np.dtype(v.dtype), v.shape) for k, v in masters.items()},
+            handed)
+
+
+def _policy_cachedop(monkeypatch):
+    """Hybridized dense-bn-dense plus a float16 parameter (a zero-size one
+    cannot get here: gluon reads a 0 in a shape as unknown)."""
+    import jax
+    from mxnet_tpu import gluon, autograd
+    from mxnet_tpu.gluon import nn
+
+    class Net(gluon.HybridBlock):
+        def __init__(self, **kw):
+            super().__init__(**kw)
+            with self.name_scope():
+                self.fc1, self.bn, self.fc2 = \
+                    nn.Dense(8), nn.BatchNorm(), nn.Dense(3)
+                self.half_bias = self.params.get(
+                    "half_bias", shape=(1, 3), dtype="float16")
+
+        def hybrid_forward(self, F, x, half_bias):
+            y = self.fc2(F.relu(self.bn(self.fc1(x))))
+            return F.broadcast_add(y, F.cast(half_bias, dtype="float32"))
+
+    # the CachedOp keeps its jitted program to itself: catch it as built
+    lowered = []
+    real_jit = jax.jit
+
+    def spy(fn, *a, **kw):
+        jitted = real_jit(fn, *a, **kw)
+        if getattr(fn, "__name__", "") != "traced":
+            return jitted
+
+        def call(*args):
+            lowered.append(jitted.lower(*args))
+            return jitted(*args)
+        return call
+
+    net = Net()
+    net.initialize()
+    net.hybridize()
+    x = mx.nd.array(np.random.RandomState(0).randn(4, 5).astype("f4"))
+    with monkeypatch.context() as patched:
+        patched.setattr(jax, "jit", spy)
+        with autograd.record():
+            loss = (net(x) ** 2).sum()
+    loss.backward()
+    params = list(net.collect_params().values())
+    # entry arguments: the param arrays in collect_params() order
+    names = [p.name for p in params]
+    cast = {p.name for p in params
+            if p.name.endswith(("dense0_weight", "dense0_bias",
+                                "dense1_weight", "dense1_bias"))}
+    handed = [(p.name, p.grad().asnumpy()) for p in params
+              if p.grad_req != "null"]
+    return (lowered[0].as_text(), names, cast,
+            {p.name: (np.dtype(p.dtype), p.shape) for p in params}, handed)
+
+
+@pytest.mark.parametrize("caller", ["fused_module", "gluon_cachedop"])
+def test_compute_dtype_cast_is_per_parameter(caller, monkeypatch):
+    """Under the bf16 policy both callers cast each castable master by
+    itself, at its own shape: no flat buffer of the parameters in the
+    lowered text (on the chip a reshape between such a buffer and a tiled
+    weight is a relayout, PERF.md section 6, PR 26), BatchNorm's keep_f32
+    parameters, a zero-size and a non-float32 one reach their ops as they
+    are, and what the optimizer is handed (gradients; for the Module also
+    the updated parameters and the momentum) is float32 at the masters'
+    shapes."""
+    import re
+    from mxnet_tpu import config
+    with config.override(compute_dtype="bfloat16"):
+        text, names, cast, masters, handed = (
+            _policy_fused_module() if caller == "fused_module"
+            else _policy_cachedop(monkeypatch))
+    to_bf16 = {names[int(i)] for i in re.findall(
+        r"stablehlo\.convert %arg(\d+) : \(tensor<[^>]*>\) -> "
+        r"tensor<[0-9x]*bf16>", text) if int(i) < len(names)}
+    assert to_bf16 == cast, (to_bf16, cast)
+    # the grouped cast was a rank-1 concatenate of every castable master
+    total = sum(int(np.prod(masters[k][1])) for k in cast)
+    flat = [int(n) for n in re.findall(
+        r"stablehlo\.concatenate [^\n]* -> tensor<(\d+)x[a-z0-9]+>", text)]
+    assert all(n < total for n in flat), (flat, total)
+    assert handed
+    for k, a in handed:
+        assert (a.dtype, a.shape) == masters[k], (k, a.dtype, a.shape)
+        assert np.isfinite(a.astype("f4")).all(), k

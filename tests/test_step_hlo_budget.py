@@ -2,21 +2,33 @@
 
 The round-5 diagnosis found 766 bf16<->f32 converts (~2.75 Gelem per
 direction) in the lowered train step — one f32 round-trip of every BN
-activation, fwd and bwd. The bf16-native BatchNorm (ops/nn.py) plus the
-grouped parameter downcast (module/fused.py) eliminate them at the trace
-level, so the pre-optimization StableHLO — deterministic on CPU — is the
-regression surface: if a change reintroduces per-tensor round-trips, the
-convert count jumps by hundreds and this test fails without ever needing
-the chip.
+activation, fwd and bwd. The bf16-native BatchNorm (ops/nn.py) eliminates
+them at the trace level, so the pre-optimization StableHLO —
+deterministic on CPU — is the regression surface: if a change
+reintroduces per-tensor round-trips, the convert count jumps by hundreds
+and the traffic through them by gigaelements, and this test fails
+without ever needing the chip.
 
-Budget: <= 120 bf16<->f32 converts (measured 111 at time of writing:
-109 f32->bf16 one-per-parameter-ish small casts + 2 from the grouped
-downcast pair), versus 766 before.
+Budget: <= 230 bf16<->f32 converts (measured 219 at PR 26: 109 small
+f32->bf16 casts of BatchNorm's per-channel coefficients, plus one
+f32->bf16 in the forward and one bf16->f32 in the backward for each of
+the 55 castable parameters), versus 766 before. The count was 111 while
+the parameters were cast as ONE flat buffer (concatenate -> convert ->
+slice -> reshape); the device trace showed that form costing 34.6 ms of
+an 80 ms step in relayouts between the flat buffer and tiled weights
+(PERF.md section 6, PR 26), while a per-parameter convert fuses into its
+consumer. So the count that matters is the traffic
+(`convert_gelems_between`), and `test_no_flat_parameter_buffer` counts
+the relayout where the convert used to be counted.
 """
 import numpy as np
 import pytest
 
-BUDGET = 120
+BUDGET = 230
+
+# a parameter buffer worth a relayout: the smallest 3x3 weight of the
+# last stage is 2.4 M elements, all castable parameters 25.5 M
+FLAT_ELEMS = 1_000_000
 
 
 @pytest.fixture(scope="module")
@@ -51,3 +63,26 @@ def test_convolutions_stay_bf16(step_stats):
 def test_no_layout_transposes(step_stats):
     """NCHW stays native: no transpose blowup from the policy change."""
     assert step_stats["transpose_count"] <= 6
+
+
+def test_no_flat_parameter_buffer(resnet_step_text):
+    """No parameter (or gradient) travels through a flat buffer: no
+    `concatenate` yields a rank-1 tensor of a million elements or more,
+    and no `reshape` takes one. On the chip every reshape between such a
+    buffer and a tiled 4-D weight is a relayout through HBM (1.5 ms for
+    f32[2359296] -> f32[512,512,3,3]); the grouped parameter cast had 10
+    such concatenates and 20 such reshapes in this program."""
+    import re
+    rank1 = re.compile(r"tensor<(\d+)x[a-z]\w*>")
+    bad = []
+    for line in resnet_step_text.splitlines():
+        operands, _, result = line.rpartition("->")
+        if "stablehlo.concatenate" in line:
+            side = result
+        elif "stablehlo.reshape" in line:
+            side = operands
+        else:
+            continue
+        if any(int(n) >= FLAT_ELEMS for n in rank1.findall(side)):
+            bad.append(line.strip())
+    assert not bad, "%d flat-buffer ops, e.g. %s" % (len(bad), bad[0])
