@@ -471,6 +471,16 @@ def kernel_cases():
                 qkv,
                 lambda q, k, v, c=causal: attention.reference_attention(
                     q, k, v, causal=c), tol[dt])
+    # latent attention without positions (Kimi-Linear's MLA): 192-wide
+    # q.k beside 128-wide v, 32 heads
+    b, h, t, d, dv = 1, 32, 2048, 192, 128
+    add("pallas_flash[%dx%dx%dx%d/%d-bfloat16-causal1]" % (b, h, t, d, dv),
+        lambda q, k, v: pallas_flash.flash_attention(
+            q, k, v, 128, 128, True, False),
+        [((b, h, t, d), bf16, "normal")] * 2 + [((b, h, t, dv), bf16,
+                                                 "normal")],
+        lambda q, k, v: attention.reference_attention(q, k, v, causal=True),
+        tol[bf16])
     for b, h, t, d, dt in attn:
         qkv = [((b, h, t, d), dt, "normal")] * 3
         add("flash_attn[%dx%dx%dx%d-%s]" % (b, h, t, d, dt.__name__),

@@ -55,10 +55,59 @@ def mirror_wrap(f):
     return jax.checkpoint(f, policy=policy)
 
 
+def _mirror_stages(nodes, entries):
+    """The graph's rematerialised stages: ``[(first, last, reads, writes)]``
+    for every run of consecutive op nodes (in topological order, variables
+    aside) that carry the same ``mirror_stage`` attribute. ``first`` and
+    ``last`` index ``nodes``; ``reads`` are the values the run takes from
+    outside, ``("var", name)`` or ``("val", id(node), output)``; ``writes``
+    the ``(id(node), output)`` of its nodes that something outside reads."""
+    def stage_of(n):
+        return n.attrs.get("mirror_stage", n.attrs.get("__mirror_stage__"))
+
+    runs, cur = [], None
+    for i, n in enumerate(nodes):
+        if n.is_variable:
+            continue
+        st = stage_of(n)
+        if cur is not None and st is not None and st == cur[0]:
+            cur[2] = i
+        else:
+            cur = [st, i, i]
+            if st is not None:
+                runs.append(cur)
+    out = []
+    for _st, first, last in runs:
+        inside = {id(n) for n in nodes[first:last + 1] if not n.is_variable}
+        reads, writes = [], []
+        for n in nodes[first:last + 1]:
+            if n.is_variable:
+                continue
+            for src, oi in n.inputs:
+                key = ("var", src.name) if src.is_variable \
+                    else ("val", id(src), oi)
+                if (src.is_variable or id(src) not in inside) \
+                        and key not in reads:
+                    reads.append(key)
+        users = [(src, oi) for n in nodes if not n.is_variable
+                 and id(n) not in inside for src, oi in n.inputs]
+        for src, oi in users + list(entries):
+            if id(src) in inside and (id(src), oi) not in writes:
+                writes.append((id(src), oi))
+        out.append((first, last, reads, writes))
+    return out
+
+
 def _graph_eval_fn(symbol):
     """Build eval(arg_vals, aux_vals, key, training) -> (outputs, aux_updates).
 
-    Pure function over jax values; traced under jit.
+    Pure function over jax values; traced under jit. Nodes that carry a
+    ``mirror_stage`` attribute (``mx.AttrScope(mirror_stage=...)`` around a
+    block) are evaluated stage by stage under ``jax.checkpoint`` when
+    training: the backward pass then keeps what enters and leaves a stage
+    and recomputes its interior. A node that carries ``device_scope``
+    runs under ``jax.named_scope`` of that name, so a builder names the
+    device ops of a generic op by the layer they serve.
     """
     nodes = symbol._topo()
     entries = list(symbol._entries)
@@ -67,9 +116,15 @@ def _graph_eval_fn(symbol):
     # Empty when MXNET_KERNEL_TIER=off, which is the default.
     from .kernels import graph_fuse as _gfuse
     kplan, kdeferred = _gfuse.plan(nodes, entries)
+    stages = {first: (last, reads, writes) for first, last, reads, writes
+              in _mirror_stages(nodes, entries)}
 
-    def eval_fn(arg_vals, aux_vals, key, training):
-        values = {}
+    def run(todo, var, is_aux, values, training):
+        """Evaluate the op nodes ``todo`` in order into ``values``
+        (id(node) -> output, or (id(node), output index) -> value for what
+        came from outside a stage); ``var`` reads a variable by name and
+        ``is_aux`` says whether a name is an auxiliary state. Returns the
+        aux updates and the reader of values."""
         aux_updates = {}
 
         def route_aux(node, out):
@@ -79,7 +134,7 @@ def _graph_eval_fn(symbol):
                 for in_slot, out_slot in zip(node.op.aux_inputs,
                                              node.op.aux_outputs):
                     src, _ = node.inputs[in_slot]
-                    if src.is_variable and src.name in aux_vals:
+                    if src.is_variable and is_aux(src.name):
                         aux_updates[src.name] = outs[out_slot]
 
         def force(node):
@@ -89,18 +144,21 @@ def _graph_eval_fn(symbol):
             params = dict(node.params)
             if "_training" in node.op.param_names:
                 params["_training"] = training
-            out = node.op.fn(*ins, **params)
+            scope = node.attrs.get("device_scope")
+            if scope:   # the builder's name for the node's device ops
+                with jax.named_scope(scope):
+                    out = node.op.fn(*ins, **params)
+            else:
+                out = node.op.fn(*ins, **params)
             values[id(node)] = out
             route_aux(node, out)
             return out
 
         def read(src, oi):
             if src.is_variable:
-                if src.name in arg_vals:
-                    return arg_vals[src.name]
-                if src.name in aux_vals:
-                    return aux_vals[src.name]
-                raise MXNetError("unbound variable %r" % src.name)
+                return var(src.name)
+            if (id(src), oi) in values:
+                return values[(id(src), oi)]
             v = values.get(id(src))
             if v is None and id(src) not in values:
                 # deferred fusion interior read outside its pattern
@@ -108,18 +166,71 @@ def _graph_eval_fn(symbol):
                 v = force(src)
             return v[oi] if isinstance(v, tuple) else v
 
+        for node in todo:
+            if node.is_variable:
+                continue
+            if id(node) in kdeferred:
+                continue    # forced lazily only if a guard rejects
+            kp = kplan.get(id(node))
+            if kp is not None and _gfuse.try_eval(
+                    kp, node, read, values, route_aux, training):
+                continue
+            force(node)
+        return aux_updates, read
+
+    def eval_fn(arg_vals, aux_vals, key, training):
+        def var(name):
+            if name in arg_vals:
+                return arg_vals[name]
+            if name in aux_vals:
+                return aux_vals[name]
+            raise MXNetError("unbound variable %r" % name)
+
+        values = {}
+        aux_updates = {}
         with _random.trace_scope(key):
-            for node in nodes:
-                if node.is_variable:
+            i = 0
+            while i < len(nodes):
+                if i not in stages or not training:
+                    last = i
+                    while last + 1 < len(nodes) and (
+                            last + 1 not in stages or not training):
+                        last += 1
+                    upd, read = run(nodes[i:last + 1], var,
+                                    aux_vals.__contains__, values, training)
+                    aux_updates.update(upd)
+                    i = last + 1
                     continue
-                if id(node) in kdeferred:
-                    continue    # forced lazily only if a guard rejects
-                kp = kplan.get(id(node))
-                if kp is not None and _gfuse.try_eval(
-                        kp, node, read, values, route_aux, training):
-                    continue
-                force(node)
-        outputs = [read(n, oi) for (n, oi) in entries]
+                last, reads, writes = stages[i]
+                def stage(taken, first=i, last=last, reads=reads,
+                          writes=writes):
+                    got = dict(zip(reads, taken))
+                    inner = {(k[1], k[2]): v for k, v in got.items()
+                             if k[0] == "val"}
+                    upd, _ = run(nodes[first:last + 1],
+                                 lambda name: got[("var", name)],
+                                 aux_vals.__contains__, inner, training)
+                    outs = []
+                    for nid, oi in writes:
+                        v = inner[nid]
+                        outs.append(v[oi] if isinstance(v, tuple) else v)
+                    return outs, upd
+
+                taken = []
+                for k in reads:
+                    if k[0] == "var":
+                        taken.append(var(k[1]))
+                    else:
+                        v = values[k[1]] if k[1] in values \
+                            else values[(k[1], k[2])]
+                        taken.append(v[k[2]] if isinstance(v, tuple) else v)
+                outs, upd = jax.checkpoint(stage)(taken)
+                for (nid, oi), v in zip(writes, outs):
+                    values[(nid, oi)] = v
+                aux_updates.update(upd)
+                i = last + 1
+            _, read = run([], var, aux_vals.__contains__, values, training)
+            outputs = [read(n, oi) for (n, oi) in entries]
         return outputs, aux_updates
 
     return eval_fn
@@ -421,6 +532,18 @@ class Executor:
             return
         with _profiler.span("mx/exec/backward"):
             return self._backward_impl(out_grads)
+
+    def release_grad_buffers(self):
+        """Give back the gradient buffers' device memory (one copy of the
+        model): for a caller whose gradients never leave a compiled
+        program, as the fused train step's. The NDArrays stay, empty, and
+        keep their dtype; an eager ``backward()`` binds them to fresh
+        gradients again (``grad_req='add'`` buffers are kept: they
+        accumulate)."""
+        for k in self._req_args:
+            buf = self.grad_dict.get(k)
+            if buf is not None and self._grad_req[k] == "write":
+                buf._rebind(jnp.zeros((0,), buf.dtype))
 
     def _backward_impl(self, out_grads=None):
         if out_grads is not None:

@@ -27,6 +27,11 @@ class BaseModule:
         self.optimizer_initialized = False
         self._symbol = None
 
+    def _op_counters(self):
+        """{gauge: (value, help)} of the counters that ops carry on the
+        device; Module overrides."""
+        return {}
+
     def _ddp_stats(self, n_steps):
         """Per-window DDP telemetry payload for publish_window; Module
         overrides when the bucketed all-reduce path is engaged."""
@@ -562,6 +567,10 @@ class BaseModule:
                 if _telem_acc[0]:    # flush the partial per-step window
                     _telem_window(_telem_acc[0], _telem_acc[1], global_step)
                     _telem_acc = [0, 0]
+                # what ops count rides the step's own state on the device:
+                # read here, where the host has just waited
+                for name, (val, text) in self._op_counters().items():
+                    _telemetry.gauge(name, text).set(val)
                 for name, val in (eval_metric.get_name_value()
                                   if eval_metric is not None else []):
                     self.logger.info("Epoch[%d] Train-%s=%f", epoch, name, val)

@@ -47,8 +47,8 @@ class FusedStep:
     """
 
     def __init__(self, executor, optimizer, param_names, compute_dtype=None,
-                 data_names=(), keep_f32=(), ddp_mesh=None, ddp_axis=None,
-                 ddp_bucket_bytes=None):
+                 data_names=(), keep_f32=(), index_names=(), ddp_mesh=None,
+                 ddp_axis=None, ddp_bucket_bytes=None):
         self._exec = executor
         self._opt = optimizer
         fused = optimizer.fused_ops()
@@ -61,7 +61,9 @@ class FusedStep:
                             if executor._grad_req.get(n, "null") == "write"]
         self._name2idx = {n: i for i, n in enumerate(param_names)}
         self._compute_dtype = compute_dtype
-        self._data_names = frozenset(data_names)
+        # data inputs an op reads as indices (token ids fed the MXNet way,
+        # as float32) are never cast: bfloat16 holds no integer above 256
+        self._data_names = frozenset(data_names) - frozenset(index_names)
         # params that must NOT be downcast under mixed precision: BN
         # gamma/beta (their op consumes f32 natively — casting them would
         # just reintroduce per-layer converts at the op boundary)

@@ -340,8 +340,13 @@ class Module(BaseModule):
                                 compute_dtype=compute_dtype,
                                 data_names=self._data_names,
                                 keep_f32=self._norm_stat_params(),
+                                index_names=self._inputs_in_slots(
+                                    "index_inputs"),
                                 ddp_mesh=ddp_mesh)
         self._fused_opt_state = self._fused.init_state()
+        # the step's gradients live and die inside its program: the eager
+        # buffers would only hold a float32 copy of the model on the chip
+        self._exec.release_grad_buffers()
 
     def _ddp_stats(self, n_steps):
         """Host-held DDP bucket/comm summary scaled to a telemetry window
@@ -357,24 +362,47 @@ class Module(BaseModule):
                 "comm_bytes": s["comm_bytes"] * max(int(n_steps), 0),
                 "overlap_ms": s["overlap_ms"]}
 
+    def _op_counters(self):
+        """{gauge: (a step's mean over the nodes that count it, help)} from
+        the auxiliary states that ops declare as counters (``counters`` in
+        ops/registry.py: the state is [steps, a step's mean a gauge]).
+        One small device read a state: call it where the host has waited
+        for the device anyway."""
+        if not self.binded:
+            return {}
+        seen = {}
+        for node in self._symbol._topo():
+            for slot, gauges in (node.op.counters if node.op else ()):
+                src = node.inputs[slot][0]
+                if src.is_variable and src.name in self._exec.aux_dict:
+                    row = self._exec.aux_dict[src.name].asnumpy()
+                    for (name, text), v in zip(gauges, row[1:]):
+                        seen.setdefault((name, text), []).append(float(v))
+        return {name: (float(_np.mean(vs)), text)
+                for (name, text), vs in seen.items()}
+
+    def _inputs_in_slots(self, which):
+        """Names of the variables that feed, directly, a slot that some op
+        lists under ``which`` (``f32_inputs`` or ``index_inputs``,
+        ops/registry.py)."""
+        names = set()
+        for node in self._symbol._topo():
+            if node.op is None:
+                continue
+            for slot in getattr(node.op, which, ()):
+                if slot < len(node.inputs) and node.inputs[slot][0].is_variable:
+                    names.add(node.inputs[slot][0].name)
+        return frozenset(names)
+
     def _norm_stat_params(self):
         """Names of params that must stay f32 under a low-precision compute
-        policy: BatchNorm gamma/beta. The bf16-native BN kernel keeps its
-        statistics/scale math in f32 and consumes f32 affine params
-        directly (ops/nn.py), so downcasting them would only add converts
-        back at every BN boundary."""
-        keep = set()
-        try:
-            for node in self._symbol._topo():
-                if node.op is not None and node.op.name == "BatchNorm":
-                    for slot in (1, 2):  # gamma, beta inputs
-                        if slot < len(node.inputs):
-                            src = node.inputs[slot][0]
-                            if src.is_variable:
-                                keep.add(src.name)
-        except Exception:
-            pass
-        return frozenset(keep)
+        policy: the slots their ops read as float32 (``f32_inputs``):
+        BatchNorm and RMSNorm scales, a router's matrix, a forget gate's
+        rates. The bf16-native BN kernel keeps its statistics/scale math in
+        f32 and consumes f32 affine params directly (ops/nn.py), so
+        downcasting them would only add converts back at every BN
+        boundary; a router that rounds its matrix chooses other experts."""
+        return self._inputs_in_slots("f32_inputs")
 
     # --------------------------------------------------------------- running
     def _feed(self, data_batch):

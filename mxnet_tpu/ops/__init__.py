@@ -20,6 +20,7 @@ from . import contrib_ops   # noqa: F401
 from . import custom_op     # noqa: F401
 from . import vision_ops    # noqa: F401
 from . import pallas_flash  # noqa: F401
+from . import lm_ops        # noqa: F401
 from ..kernels import bn_act as _kernel_bn_act    # noqa: F401  (tier ops)
 from ..kernels import mlp as _kernel_mlp          # noqa: F401
 from . import linalg        # noqa: F401

@@ -1,0 +1,349 @@
+"""Ops of a language model built from a Symbol: RMSNorm, the causal
+depthwise convolution of linear-attention layers, the KDA recurrence
+(Kimi Delta Attention, arXiv:2510.26692) in chunks, and the head that
+gives every token's loss without the tokens x vocabulary array.
+
+All are pure JAX; gradients come from ``jax.vjp`` (the head's from a
+``custom_vjp`` that works through the tokens in blocks). Each of the
+layers a device trace should tell apart carries a ``jax.named_scope``
+(``mx/kda``, ``mx/lm_head``; docs/observability.md).
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .registry import register, set_op_meta
+
+_F32 = jnp.float32
+
+
+@register("RMSNorm")
+def rms_norm(data, gamma, *, eps=1e-5):
+    """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis; the
+    statistics in float32 whatever the input's dtype."""
+    x = data.astype(_F32)
+    y = x * lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps)
+    return (y * gamma.astype(_F32)).astype(data.dtype)
+
+
+@register("_contrib_CausalConv1D")
+def causal_conv1d(data, weight, *, act_type="silu"):
+    """Depthwise convolution over time that sees no later token:
+    ``y_t = sum_i w[:, i] x_{t-(K-1)+i}`` with zeros before the sequence,
+    then ``act_type`` (``silu`` or ``none``). data: (B, T, C); weight:
+    (C, K)."""
+    k = weight.shape[1]
+    t = data.shape[1]
+    xp = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0)))
+    y = sum(xp[:, i:i + t] * weight[:, i] for i in range(k))
+    return jax.nn.silu(y) if act_type == "silu" else y
+
+
+# --------------------------------------------------------------------- KDA
+def _tri_solve(a, rhs):
+    """``(I + a)^-1 rhs`` for strictly lower triangular ``a`` (..., C, C),
+    by forward substitution on blocks of 16 rows: the diagonal blocks are
+    inverted through their nilpotent series (exact after four
+    multiplications), the rest is matrix products."""
+    s = 16
+    n = a.shape[-1] // s        # a chunk is a whole number of sub-blocks
+    hi = lax.Precision.HIGHEST
+    eye = jnp.eye(s, dtype=a.dtype)
+    out = []
+    for i in range(n):
+        r = rhs[..., i * s:(i + 1) * s, :]
+        for j in range(i):
+            r = r - jnp.matmul(a[..., i * s:(i + 1) * s, j * s:(j + 1) * s],
+                               out[j], precision=hi)
+        d = a[..., i * s:(i + 1) * s, i * s:(i + 1) * s]
+        # (I + d)^-1 = (I - d)(I + d^2)(I + d^4)(I + d^8), d^16 = 0
+        inv = eye - d
+        p = jnp.matmul(d, d, precision=hi)
+        for _ in range(3):
+            inv = jnp.matmul(inv, eye + p, precision=hi)
+            p = jnp.matmul(p, p, precision=hi)
+        out.append(jnp.matmul(inv, r, precision=hi))
+    return jnp.concatenate(out, axis=-2)
+
+
+@jax.checkpoint
+def _pair_exact(a, b, g):
+    """``sum_d a_t[d] b_s[d] exp(g_t[d] - g_s[d])`` for s <= t of one
+    sub-block, channel by channel. Rematerialised: the (t, s, d) array of
+    decays is recomputed in the backward pass, never kept."""
+    n = a.shape[-2]
+    diff = g[..., :, None, :] - g[..., None, :, :]             # t, s, d
+    keep = jnp.tril(jnp.ones((n, n), bool))[..., None]
+    e = jnp.exp(jnp.where(keep, diff, -jnp.inf))
+    return jnp.sum(e * a[..., :, None, :] * b[..., None, :, :], axis=-1)
+
+
+def _pair_scores(a, b, g, sub):
+    """``sum_d a_t[d] b_s[d] exp(G_t[d] - G_s[d])`` for s <= t within a
+    chunk, 0 above the diagonal; a, b, g: (..., C, D) with ``g`` the
+    cumulative log decay (decreasing along C). No exponent is ever
+    positive: rows and columns of one sub-block of ``sub`` tokens are
+    paired exactly, channel by channel; a row meets the columns of earlier
+    sub-blocks through the decay up to its own sub-block's first token
+    (its factor and theirs both at most 1), as a matrix product."""
+    c = a.shape[-2]
+    n = c // sub
+    hi = lax.Precision.HIGHEST
+    rows = []
+    for i in range(n):
+        lo, up = i * sub, (i + 1) * sub
+        gi = g[..., lo:up, :]
+        # the log decay just before the sub-block: G of the token before
+        ref = g[..., lo - 1:lo, :] if i else jnp.zeros_like(g[..., :1, :])
+        parts = []
+        if i:
+            left = a[..., lo:up, :] * jnp.exp(gi - ref)
+            right = b[..., :lo, :] * jnp.exp(ref - g[..., :lo, :])
+            parts.append(jnp.einsum("...td,...sd->...ts", left, right,
+                                    precision=hi))
+        parts.append(_pair_exact(a[..., lo:up, :], b[..., lo:up, :], gi))
+        if up < c:
+            parts.append(jnp.zeros(a.shape[:-2] + (sub, c - up), a.dtype))
+        rows.append(jnp.concatenate(parts, axis=-1))
+    return jnp.concatenate(rows, axis=-2)
+
+
+def _kda_group(s, q, k, v, g, beta, chunk, sub):
+    """A group of whole chunks from the state ``s`` (B, H, d_k, d_v):
+    inside every chunk in matrix form, all the group's chunks at once (the
+    pseudo-values ``U = (I + A)^-1 (beta V - beta K+ S_0)``), then from
+    chunk to chunk a scan that carries the state. q, k, g: (B, T, H, d_k);
+    v: (B, T, H, d_v); beta: (B, T, H), float32, T a multiple of
+    ``chunk``. Returns (the state after the group, o (B, T, H, d_v))."""
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    n = t // chunk
+
+    def chunks(x):      # (B, T, H, D) -> (N, B, H, C, D)
+        x = x.reshape((b, n, chunk, h) + x.shape[3:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+
+    q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
+    beta = chunks(beta[..., None])                         # (N, B, H, C, 1)
+    gc = jnp.cumsum(g, axis=-2)                            # G_t, <= 0
+    hi = lax.Precision.HIGHEST
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    a = jnp.where(strict, _pair_scores(k, k, gc, sub), 0.0) * beta
+    m = _pair_scores(q, k, gc, sub)                        # q.k, s <= t
+    decay = jnp.exp(gc)                                    # within (0, 1]
+    rhs = jnp.concatenate([v * beta, k * decay * beta], -1)
+    sol = _tri_solve(a, rhs)
+    u0, w = sol[..., :dv], sol[..., dv:]
+    q_in = q * decay
+    g_end = gc[..., -1:, :]                                # (N, B, H, 1, dk)
+    k_out = k * jnp.exp(g_end - gc)                        # decay to the end
+
+    def step(s, x):
+        u0_c, w_c, m_c, q_c, k_c, ge_c = x
+        u = u0_c - jnp.matmul(w_c, s, precision=hi)
+        o = jnp.matmul(q_c, s, precision=hi) + jnp.matmul(m_c, u, precision=hi)
+        s = s * jnp.exp(jnp.swapaxes(ge_c, -1, -2)) \
+            + jnp.matmul(jnp.swapaxes(k_c, -1, -2), u, precision=hi)
+        return s, o
+
+    s, o = lax.scan(step, s, (u0, w, m, q_in, k_out, g_end))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)          # (B, N, C, H, dv)
+    return s, o.reshape(b, t, h, dv)
+
+
+def kda_chunked(xs, pre=None, *, chunk=64, sub=16, group=16):
+    """The delta rule with a per-channel forget gate,
+    ``S_t = (I - beta k k^T) Diag(exp g) S_{t-1} + beta k v^T``,
+    ``o_t = S_t^T q_t`` from a zero state, in chunks of ``chunk`` tokens
+    (:func:`_kda_group`), ``group`` chunks at a time under a
+    rematerialised scan: the backward pass keeps one state a group and
+    recomputes a group's interior (whose own scan keeps one state a
+    chunk), so what a step holds does not grow with the sequence.
+
+    ``xs`` are arrays (B, T, ...) cut along T; ``pre`` maps a group's
+    slices of them to ``(q, k, v, g, beta)`` in float32 (q, k, g: (B, t,
+    H, d_k); v: (B, t, H, d_v); beta: (B, t, H)), and without it ``xs``
+    are those five. Returns o (B, T, H, d_v) in float32."""
+    b, t = xs[0].shape[:2]
+    chunk = min(chunk, -(-t // sub) * sub)
+    group = min(group, -(-t // chunk))
+    span = group * chunk
+    pad = (-t) % span
+    n = (t + pad) // span
+
+    def grouped(x):     # zero rows after the sequence change nothing before
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        return jnp.moveaxis(x.reshape((b, n, span) + x.shape[2:]), 1, 0)
+
+    @jax.checkpoint
+    def body(s, x):
+        q, k, v, g, beta = pre(*x) if pre is not None else x
+        return _kda_group(s, q, k, v, g, beta, chunk, sub)
+
+    first = [x[:, :1] for x in xs]
+    q0, _, v0, _, _ = jax.eval_shape(pre, *first) if pre is not None \
+        else first
+    s0 = jnp.zeros((b, q0.shape[2], q0.shape[3], v0.shape[3]), _F32)
+    _, o = lax.scan(body, s0, tuple(grouped(x) for x in xs))
+    return jnp.moveaxis(o, 0, 1).reshape((b, n * span) + o.shape[3:])[:, :t]
+
+
+@register("_contrib_KDA")
+def kda(q, k, v, f, b, a_log, dt_bias, *, num_heads, chunk=64):
+    """Kimi Delta Attention's core. q, k, v: (B, T, H*d) after their
+    convolutions; f: (B, T, H*d) the forget gate's projection; b: (B, T,
+    H) the logits of beta; a_log: (H,); dt_bias: (H*d,). Per head
+    ``q = l2norm(q) d^-1/2``, ``k = l2norm(k)``, ``g = -exp(a_log)
+    softplus(f + dt_bias)``, ``beta = sigmoid(b)``, then the recurrence
+    of :func:`kda_chunked`. Gate and state are float32 whatever the
+    inputs' dtype; the output takes ``v``'s."""
+    with jax.named_scope("mx/kda"):
+        h = num_heads
+        rate = jnp.exp(a_log.astype(_F32))[:, None]
+        bias = dt_bias.astype(_F32).reshape(h, -1)
+
+        def l2norm(x):
+            return x * lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True)
+                                 + 1e-6)
+
+        def pre(q, k, v, f, b):     # a group's slices, as they arrive
+            def heads(x):
+                return x.astype(_F32).reshape(x.shape[:2] + (h, -1))
+            qh, kh = l2norm(heads(q)), l2norm(heads(k))
+            g = -rate * jax.nn.softplus(heads(f) + bias)
+            return (qh * qh.shape[-1] ** -0.5, kh, heads(v), g,
+                    jax.nn.sigmoid(b.astype(_F32)))
+
+        o = kda_chunked((q, k, v, f, b), pre, chunk=chunk)
+        return o.reshape(o.shape[:2] + (-1,)).astype(v.dtype)
+
+
+# -------------------------------------------------------------------- head
+def _head_blocks(n, block):
+    block = min(block, n)
+    return block, (-n) % block
+
+
+def _head_rows(x, w, label, block):
+    """Every token's ``logsumexp(z) - z[label]``, ``block`` tokens at a
+    time, logits in float32."""
+    n = x.shape[0]
+    block, pad = _head_blocks(n, block)
+    xb = jnp.pad(x, ((0, pad), (0, 0))).reshape(-1, block, x.shape[1])
+    lb = jnp.pad(label, (0, pad)).reshape(-1, block)
+
+    def rows(a):
+        xi, li = a
+        z = jnp.matmul(xi, w.T, preferred_element_type=_F32)
+        return jax.nn.logsumexp(z, axis=-1) \
+            - jnp.take_along_axis(z, li[:, None], axis=-1)[:, 0]
+
+    return lax.map(rows, (xb, lb)).reshape(-1)[:n]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _head_loss(x, w, label, grad_scale, block):
+    return _head_rows(x, w, label, block)
+
+
+def _head_fwd(x, w, label, grad_scale, block):
+    return _head_rows(x, w, label, block), (x, w, label)
+
+
+def _head_bwd(grad_scale, block, res, ct):
+    x, w, label = res
+    n = x.shape[0]
+    block, pad = _head_blocks(n, block)
+    xb = jnp.pad(x, ((0, pad), (0, 0))).reshape(-1, block, x.shape[1])
+    lb = jnp.pad(label, (0, pad)).reshape(-1, block)
+    cb = jnp.pad(ct.astype(_F32) * grad_scale, (0, pad)).reshape(-1, block)
+
+    def rows(dw, a):
+        xi, li, ci = a
+        z = jnp.matmul(xi, w.T, preferred_element_type=_F32)
+        dz = jax.nn.softmax(z, axis=-1) \
+            - jax.nn.one_hot(li, w.shape[0], dtype=_F32)
+        dz = (dz * ci[:, None]).astype(x.dtype)
+        dw = dw + jnp.matmul(dz.T, xi, preferred_element_type=_F32)
+        return dw, jnp.matmul(dz, w, preferred_element_type=_F32)
+
+    dw, dx = lax.scan(rows, jnp.zeros(w.shape, _F32), (xb, lb, cb))
+    dx = dx.reshape(-1, x.shape[1])[:n]
+    return dx.astype(x.dtype), dw.astype(w.dtype), None
+
+
+_head_loss.defvjp(_head_fwd, _head_bwd)
+
+
+@register("_contrib_LMHeadLoss")
+def lm_head_loss(data, weight, label, *, grad_scale=1.0,
+                 normalization="null", block=2048):
+    """Every token's cross-entropy of ``label`` under ``softmax(data
+    weight^T)``, as a loss head: data (B, T, hidden), weight (vocabulary,
+    hidden), label (B, T) of ids; the output is (B, T) of float32 losses
+    and never the B x T x vocabulary array (the logits exist ``block``
+    tokens at a time, forward and backward). Like ``SoftmaxOutput`` the
+    gradient is the sum's, ``softmax - onehot`` a token, times
+    ``grad_scale``, and with ``normalization="tokens"`` divided by T: with
+    ``Module``'s default ``rescale_grad = 1 / B`` the step then follows
+    the mean over all tokens."""
+    with jax.named_scope("mx/lm_head"):
+        b, t, hid = data.shape
+        if normalization == "tokens":
+            grad_scale = float(grad_scale) / t
+        rows = _head_loss(data.reshape(b * t, hid), weight,
+                          label.astype(jnp.int32).reshape(b * t),
+                          float(grad_scale), int(block))
+        return rows.reshape(b, t)
+
+
+# ------------------------------------------------------------ feed-forward
+@register("_contrib_SwiGLU")
+def _swiglu_op(data, gate_weight, up_weight, down_weight):
+    """``(silu(x Wg^T) * (x Wu^T)) Wd^T`` over the last axis; the
+    matrices stored (out, in) as FullyConnected's."""
+    from ..parallel.moe import swiglu
+    return swiglu(data, gate_weight, up_weight, down_weight)
+
+
+@register("_contrib_MoE", num_outputs=2)
+def _moe_op(data, router_weight, router_bias, gate_weight, up_weight,
+            down_weight, counters, *, experts_held, top_k, scale=1.0):
+    """:func:`mxnet_tpu.parallel.moe.expert_layer` over (B, T, d) as a
+    registered op. The auxiliary state ``counters`` (3,) is carried on the
+    device and never read by the host in a step: steps seen, then a step's
+    running mean of the assignments this rank held and of the largest
+    number of tokens one held expert received (float32 like every
+    auxiliary state: a mean stays as exact after a million steps as after
+    one, where a sum would pass 2**24 in two thousand)."""
+    from ..parallel.moe import expert_layer
+    b, t, d = data.shape
+    y, counts = expert_layer(
+        data.reshape(b * t, d), router_weight, router_bias, gate_weight,
+        up_weight, down_weight,
+        experts_held=tuple(int(v) for v in experts_held), top_k=int(top_k),
+        scale=float(scale))
+    steps = counters[:1] + 1
+    seen = jnp.stack([jnp.sum(counts), jnp.max(counts)]).astype(
+        counters.dtype)
+    means = counters[1:] + (seen - counters[1:]) / steps
+    return y.reshape(b, t, d), jnp.concatenate([steps, means])
+
+
+set_op_meta("RMSNorm", f32_inputs=(1,))
+set_op_meta("_contrib_KDA", f32_inputs=(5, 6))
+set_op_meta("_contrib_LMHeadLoss", index_inputs=(2,))
+set_op_meta("_contrib_MoE", aux_inputs=(6,), aux_outputs=(1,),
+            num_visible_outputs=1, f32_inputs=(1, 2),
+            counters=((6, (
+                ("moe/assignments_held",
+                 "token-to-expert assignments a step that fell to the "
+                 "experts this rank holds, mean over the expert layers"),
+                ("moe/max_expert_tokens",
+                 "tokens a step that the busiest held expert received, "
+                 "mean over the expert layers"))),),
+            shape_hook=lambda ins, p: list(ins[:6]) + [ins[6] or (3,)])

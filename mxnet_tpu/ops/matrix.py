@@ -424,6 +424,9 @@ def _param_dtype_out(in_dtypes, params):
 
 
 from .registry import set_op_meta as _set_op_meta  # noqa: E402
+for _name, _slots in (("take", (1,)), ("pick", (1,)), ("gather_nd", (1,)),
+                      ("one_hot", (0,))):
+    _set_op_meta(_name, index_inputs=_slots)
 _set_op_meta("argsort", dtype_hook=_param_dtype_out)
 _set_op_meta("topk", dtype_hook=_param_dtype_out)
 
@@ -490,5 +493,6 @@ def slice_assign_scalar(data, *, scalar=0.0, begin=(), end=(), step=None):
         jnp.asarray(scalar, data.dtype))
 
 
+_set_op_meta("batch_take", index_inputs=(1,))
 alias("_slice_assign", "_crop_assign")
 alias("_slice_assign_scalar", "_crop_assign_scalar")

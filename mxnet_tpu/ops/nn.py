@@ -1056,12 +1056,13 @@ def _bn_dtypes(in_dtypes, params):
 
 
 _set_op_meta("BatchNorm", shape_hook=_bn_shapes, dtype_hook=_bn_dtypes,
-             aux_inputs=(3, 4), aux_outputs=(3, 4),
+             aux_inputs=(3, 4), aux_outputs=(3, 4), f32_inputs=(1, 2),
              num_visible_outputs=lambda p: 3 if p.get("output_mean_var") else 1)
 _set_op_meta("LayerNorm", shape_hook=_ln_shapes)
 _set_op_meta("InstanceNorm", shape_hook=_in_shapes)
-_set_op_meta("Embedding", shape_hook=_embedding_shapes)
-_set_op_meta("_contrib_SparseEmbedding", shape_hook=_embedding_shapes)
+_set_op_meta("Embedding", shape_hook=_embedding_shapes, index_inputs=(0,))
+_set_op_meta("_contrib_SparseEmbedding", shape_hook=_embedding_shapes,
+             index_inputs=(0,))
 _set_op_meta("RNN", shape_hook=_rnn_shapes)
 _set_op_meta("LeakyReLU", shape_hook=_prelu_shapes)
 

@@ -32,7 +32,8 @@ class Operator:
     __slots__ = ("name", "fn", "num_outputs", "param_names", "is_random",
                  "doc", "shape_hook", "dtype_hook", "aux_inputs",
                  "aux_outputs", "num_visible_outputs", "input_names",
-                 "input_optional", "has_var_inputs")
+                 "input_optional", "has_var_inputs", "f32_inputs",
+                 "index_inputs", "counters")
 
     def __init__(self, name, fn, num_outputs=1, is_random=False):
         self.name = name
@@ -46,6 +47,13 @@ class Operator:
         self.aux_inputs = ()          # input slots that are auxiliary states
         self.aux_outputs = ()         # output slots holding updated aux values
         self.num_visible_outputs = None  # outputs exposed to the graph (prefix)
+        # what a low-precision compute policy must leave alone (fused.py):
+        self.f32_inputs = ()          # parameter slots the op reads as float32
+        self.index_inputs = ()        # slots read as indices (ids, labels)
+        # auxiliary states that count on the device: ((input slot, ((gauge,
+        # help), ...)), ...) for a state [steps, a step's mean of each
+        # gauge]; Module publishes them where the host waits anyway
+        self.counters = ()
         sig = inspect.signature(fn)
         self.param_names = [
             p.name for p in sig.parameters.values()
@@ -115,7 +123,8 @@ def register(name=None, num_outputs=1, is_random=False):
 
 
 def set_op_meta(name, shape_hook=None, dtype_hook=None, aux_inputs=None,
-                aux_outputs=None, num_visible_outputs=None):
+                aux_outputs=None, num_visible_outputs=None, f32_inputs=None,
+                index_inputs=None, counters=None):
     """Attach symbolic-layer metadata (parameter-shape/dtype inference
     hooks and auxiliary-state slots — the reference's FInferShape /
     FInferType / aux_states)."""
@@ -130,6 +139,12 @@ def set_op_meta(name, shape_hook=None, dtype_hook=None, aux_inputs=None,
         op.aux_outputs = tuple(aux_outputs)
     if num_visible_outputs is not None:
         op.num_visible_outputs = num_visible_outputs
+    if f32_inputs is not None:
+        op.f32_inputs = tuple(f32_inputs)
+    if index_inputs is not None:
+        op.index_inputs = tuple(index_inputs)
+    if counters is not None:
+        op.counters = tuple(counters)
     return op
 
 

@@ -9,8 +9,7 @@ insert collectives over ICI/DCN.
 from .mesh import make_mesh, data_parallel_sharding, replicated
 from .spmd import SPMDTrainStep, megatron_tp_rule
 from .pipeline import make_pipeline, stack_stage_params
-from .moe import (moe_layer, init_moe_params, shard_moe_params,
-                  aux_load_balance_loss)
+from .moe import expert_layer, route
 from .ring_attention import (blockwise_attention, ring_attention,
                              make_ring_attention, attention_reference)
 from ..ops.pallas_flash import flash_attention

@@ -1,0 +1,471 @@
+"""Kimi-Linear through ``Module.fit`` against the plain reference
+(benchmarks/references/kimi_linear.py), at a small size on the CPU: hidden
+64, 4 heads of 16, 8 experts top-2 with 2 held, the five leading layers in
+the published pattern (KDA dense, KDA, KDA, MLA, KDA), 64 tokens. Losses,
+the gradient of every leaf and the parameters after three fused Adam steps;
+the share of the experts tied to the uncut layer; the chunked recurrence
+against the token-by-token one."""
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxnet_tpu as mx
+from mxnet_tpu.io import DataBatch, DataDesc
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+from references import kimi_linear as ref      # noqa: E402
+from runners.train_lm_fit import symbol_kwargs  # noqa: E402
+
+B, T = 2, 64
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    with open(os.path.join(ROOT, "tests", "benchmarks", "configs",
+                           "kimi_linear_tiny.json")) as f:
+        return json.load(f)
+
+
+def _symbol(cfg, **over):
+    from mxnet_tpu.models.kimi_linear import kimi_linear_symbol
+    return kimi_linear_symbol(**dict(symbol_kwargs(cfg), **over))
+
+
+def _weights(cfg, seed=5):
+    """Matrices five times the stated initial scale, so that attention,
+    gates and routing all move the loss at this size."""
+    return {k: (v * 5 if k.endswith("_weight") else v)
+            for k, v in ref.init_params(cfg, seed).items()}
+
+
+def _tokens(cfg, seed=0, t=T):
+    ids = np.random.RandomState(seed).randint(0, cfg["vocab_size"],
+                                              (B, t + 1))
+    return ids[:, :-1].astype("f4"), ids[:, 1:].astype("f4")
+
+
+class _Repeat:
+    """``steps`` times the same batch, one epoch."""
+
+    def __init__(self, batch, steps):
+        self.provide_data = [DataDesc("data", batch.data[0].shape)]
+        self.provide_label = [DataDesc("softmax_label", batch.label[0].shape)]
+        self.batch_size = batch.data[0].shape[0]
+        self._batch, self._steps, self._i = batch, steps, 0
+
+    def __iter__(self):
+        return self
+
+    def reset(self):
+        self._i = 0
+
+    def __next__(self):
+        if self._i >= self._steps:
+            raise StopIteration
+        self._i += 1
+        return self._batch
+
+
+def _fit(cfg, sym, w0, data, label, steps, each_step=None):
+    mod = mx.mod.Module(sym, context=mx.cpu())
+    batch = DataBatch(data=[mx.nd.array(data)], label=[mx.nd.array(label)])
+    opt = dict(cfg["optimizer"])
+    name = opt.pop("name")
+    mod.fit(_Repeat(batch, steps), num_epoch=1, eval_metric=None,
+            kvstore="tpu_sync", optimizer=name, optimizer_params=opt,
+            arg_params={k: mx.nd.array(np.asarray(v)) for k, v in w0.items()},
+            aux_params={}, allow_missing=True,
+            batch_end_callback=(lambda _p: each_step(mod))
+            if each_step else None)
+    return mod
+
+
+def _ref_steps(cfg, w0, data, label, steps):
+    step = jax.jit(lambda p, m, v, t: ref.train_step(
+        cfg, p, m, v, t, jnp.asarray(data), jnp.asarray(label)))
+    p = w0
+    m = jax.tree.map(jnp.zeros_like, w0)
+    v = jax.tree.map(jnp.zeros_like, w0)
+    out = []
+    for t in range(1, steps + 1):
+        rows, _choices, p, m, v = step(p, m, v, t)
+        out.append((np.asarray(rows), p, m))
+    return out
+
+
+@pytest.fixture(scope="module")
+def fitted(cfg):
+    """One ``fit`` of three steps, watched: every step's losses, Adam's
+    first moment after each, the compilations each step caused; and the
+    reference's three steps from the same weights and tokens."""
+    from mxnet_tpu import telemetry
+    w0 = _weights(cfg)
+    data, label = _tokens(cfg)
+    compiles, seen = [], {"losses": [], "m": [], "compiles": []}
+
+    def listen(event, *_a, **_k):
+        if event.endswith("backend_compile_duration"):
+            compiles.append(event)
+    jax.monitoring.register_event_duration_secs_listener(listen)
+
+    def each_step(mod):
+        seen["losses"].append(mod.get_outputs()[0].asnumpy())
+        seen["m"].append({k: np.asarray(st[0])
+                          for k, st in mod._fused_opt_state.items()})
+        seen["compiles"].append(len(compiles))
+
+    mod = _fit(cfg, _symbol(cfg), w0, data, label, 3, each_step)
+    seen.update(mod=mod, w0=w0, ref=_ref_steps(cfg, w0, data, label, 3),
+                held=telemetry.gauge("moe/assignments_held").value(),
+                largest=telemetry.gauge("moe/max_expert_tokens").value())
+    return seen
+
+
+def test_fit_follows_the_reference_losses_and_three_adam_steps(cfg, fitted):
+    mod, w0 = fitted["mod"], fitted["w0"]
+    assert mod._fused is not None, "the fused step did not engage"
+    for got, (want, _p, _m) in zip(fitted["losses"], fitted["ref"]):
+        assert got.shape == (B, T)                 # every token's loss
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    args, aux = mod.get_params()
+    assert set(args) == set(w0)
+    # every leaf's change over three Adam steps. Adam divides a gradient
+    # by its own size, so where a component is round-off its step is a coin
+    # toss of size lr: the leaf is held as a whole, not element by element
+    for k in sorted(w0):
+        moved = np.asarray(fitted["ref"][-1][1][k]) - np.asarray(w0[k])
+        got = args[k].asnumpy() - np.asarray(w0[k])
+        assert np.linalg.norm(got - moved) \
+            <= 0.02 * np.linalg.norm(moved) + 1e-12, k
+    # the counters: three steps, the held experts' assignments a step
+    for name, v in aux.items():
+        steps_seen, held, largest = v.asnumpy()
+        assert steps_seen == 3 and 0 < largest <= held, name
+
+
+def test_every_leafs_gradient_is_the_references(cfg, fitted):
+    """Adam's first moment after one step is (1 - beta1) g: the gradient
+    as the optimizer got it, for every leaf (through the rematerialised
+    stages, which the symbol asks for)."""
+    m_ref = fitted["ref"][0][2]
+    b1 = cfg["optimizer"]["beta1"]
+    scale = max(float(jnp.max(jnp.abs(v))) for v in m_ref.values()) / (1 - b1)
+    for k in sorted(fitted["w0"]):
+        got = fitted["m"][0][k] / (1 - b1)
+        want = np.asarray(m_ref[k]) / (1 - b1)
+        np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-5 * scale,
+                                   err_msg=k)
+    # the selection bias only selects: no gradient reaches it
+    assert all(float(jnp.max(jnp.abs(m_ref[k]))) == 0
+               for k in fitted["w0"] if k.endswith("router_bias"))
+
+
+def test_one_program_a_step_and_no_compile_after_the_first(fitted):
+    mod = fitted["mod"]
+    first, second, third = fitted["compiles"]
+    assert first == second == third      # steps 2 and 3 reuse step 1's
+    lowered = mod._fused.lower(mod._exec._arg_vals(), mod._exec._aux_vals(),
+                               mod._fused_opt_state, donate=True)
+    assert "jit_step" in lowered.as_text()
+    # the scopes a device trace names the layers by reach the program
+    debug = lowered.as_text(debug_info=True)
+    for scope in ("mx/kda", "mx/mla", "mx/moe/route", "mx/moe/experts",
+                  "mx/lm_head"):
+        assert scope in debug, scope
+
+
+def test_moe_counters_are_published_at_the_epoch_boundary(cfg, fitted):
+    held, largest = fitted["held"], fitted["largest"]
+    lo, hi = cfg["experts_held"]
+    assert 0 < largest <= held <= B * T * cfg["num_experts_per_token"]
+    assert largest >= held / (hi - lo)
+    # a step's mean over the expert layers, from the state the step carries
+    _args, aux = fitted["mod"].get_params()
+    per_step = np.mean([v.asnumpy()[1] for v in aux.values()])
+    assert held == pytest.approx(per_step)
+    # any op's declared counters reach /metrics by the one generic hook
+    assert fitted["mod"]._op_counters()["moe/assignments_held"][0] \
+        == pytest.approx(held)
+
+
+def test_a_mirror_stage_is_one_checkpoint_from_the_symbols_attribute(cfg):
+    """Traced only: every block of the symbol is one ``jax.checkpoint``
+    when training, none in inference."""
+    from mxnet_tpu.executor import _graph_eval_fn, _mirror_stages
+
+    def jaxpr(sym, training):
+        shapes, _, aux_shapes = sym.infer_shape(data=(B, T),
+                                                softmax_label=(B, T))
+        args = {n: jax.ShapeDtypeStruct(s, jnp.float32)
+                for n, s in zip(sym.list_arguments(), shapes)}
+        aux = {n: jax.ShapeDtypeStruct(s, jnp.float32)
+               for n, s in zip(sym.list_auxiliary_states(), aux_shapes)}
+        made = jax.make_jaxpr(
+            lambda a, x: _graph_eval_fn(sym)(a, x, jax.random.PRNGKey(0),
+                                             training))(args, aux)
+        # the graph's own stages: the top level's (the ops keep theirs)
+        return sum(e.primitive.name == "remat2" for e in made.jaxpr.eqns)
+    sym = _symbol(cfg)
+    assert jaxpr(sym, True) == len(cfg["layers"])
+    assert jaxpr(sym, False) == 0
+    stages = _mirror_stages(sym._topo(), list(sym._entries))
+    assert len(stages) == len(cfg["layers"])
+    # a stage takes the residual stream and its own weights, and hands on
+    # the residual stream alone
+    for _first, _last, reads, writes in stages:
+        assert len(writes) == 1
+        assert sum(1 for r in reads if r[0] == "val") == 1
+
+
+def test_float32_token_ids_survive_bfloat16_compute():
+    """Ids fed the MXNet way, as float32, and above 256: the fused step's
+    data cast leaves alone an input that an op reads as an index (bfloat16
+    would round 1001 to 1000 and 513 to 512: other rows)."""
+    data = mx.sym.Variable("data")
+    emb = mx.sym.Embedding(data=data, input_dim=1024, output_dim=8,
+                           name="embed")
+    net = mx.sym.SoftmaxOutput(mx.sym.FullyConnected(
+        emb, num_hidden=4, name="fc"), name="softmax")
+    ids = np.array([1001, 513, 999, 258], "f4")
+    label = np.array([0, 1, 2, 3], "f4")
+    table = np.random.RandomState(0).randn(1024, 8).astype("f4")
+    mod = mx.mod.Module(net, context=mx.cpu())
+    batch = DataBatch(data=[mx.nd.array(ids)], label=[mx.nd.array(label)])
+    mod.fit(_Repeat(batch, 1), num_epoch=1, eval_metric=None,
+            kvstore="tpu_sync", optimizer="sgd",
+            optimizer_params={"learning_rate": 1.0, "multi_precision": True},
+            arg_params={"embed_weight": mx.nd.array(table)},
+            allow_missing=True, initializer=mx.init.Uniform(0.1))
+    assert mod._fused._compute_dtype == jnp.bfloat16
+    assert "data" not in mod._fused._data_names
+    moved = np.abs(mod.get_params()[0]["embed_weight"].asnumpy()
+                   - table).sum(1) > 0
+    assert sorted(np.nonzero(moved)[0]) == [258, 513, 999, 1001]
+
+
+def test_the_shares_of_the_experts_add_up_to_the_uncut_layer(cfg):
+    """Each eighth of the experts in turn as ``experts_held``, the shared
+    expert once: the sum is what the reference gives for the whole layer."""
+    from mxnet_tpu.parallel.moe import expert_layer, swiglu
+    d = ref.dims(dict(cfg, experts_held=[0, cfg["num_experts_published"]]))
+    hid, inter, n = d["hidden"], d["moe_inter"], d["router"]
+    rng = np.random.RandomState(3)
+    p = {"moe_router_weight": rng.randn(n, hid).astype("f4"),
+         "moe_router_bias": np.zeros(n, "f4"),
+         "moe_gate_weight": rng.randn(n, inter, hid).astype("f4") * .2,
+         "moe_up_weight": rng.randn(n, inter, hid).astype("f4") * .2,
+         "moe_down_weight": rng.randn(n, hid, inter).astype("f4") * .2,
+         "shared_gate_weight": rng.randn(inter, hid).astype("f4") * .2,
+         "shared_up_weight": rng.randn(inter, hid).astype("f4") * .2,
+         "shared_down_weight": rng.randn(hid, inter).astype("f4") * .2}
+    p = {k: jnp.asarray(v) for k, v in p.items()}
+    x = jnp.asarray(rng.randn(96, hid).astype("f4"))
+    whole, _ = ref.moe_layer(d, p, x, lambda a: a)
+    total = swiglu(x, p["shared_gate_weight"], p["shared_up_weight"],
+                   p["shared_down_weight"])
+    seen = 0
+    for lo in range(n):                      # every eighth: one expert
+        part, counts = expert_layer(
+            x, p["moe_router_weight"], p["moe_router_bias"],
+            p["moe_gate_weight"][lo:lo + 1], p["moe_up_weight"][lo:lo + 1],
+            p["moe_down_weight"][lo:lo + 1], experts_held=(lo, lo + 1),
+            top_k=d["top_k"], scale=d["scale"])
+        total = total + part
+        seen += int(counts.sum())
+    assert seen == x.shape[0] * d["top_k"]   # no token dropped anywhere
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("t", [40, 150])
+def test_chunked_kda_is_the_token_by_token_recurrence(t):
+    """Chunk 64 and sequences that are no multiple of it, float32, forward
+    and the gradient of every input, under gates from weak to strong."""
+    from mxnet_tpu.ops.lm_ops import kda_chunked
+    rng = np.random.RandomState(t)
+    b, h, dk, dv = 2, 3, 16, 8
+
+    def unit(x):
+        return x / np.linalg.norm(x, axis=-1, keepdims=True)
+    q = jnp.asarray(unit(rng.randn(b, t, h, dk)).astype("f4") * dk ** -.5)
+    k = jnp.asarray(unit(rng.randn(b, t, h, dk)).astype("f4"))
+    v = jnp.asarray(rng.randn(b, t, h, dv).astype("f4"))
+    # per-token log decay from -0.001 to -6 (alpha down to 0.0025): a
+    # chunk's total passes -200, what exp() of the naive form cannot hold
+    g = jnp.asarray(-np.exp(rng.uniform(np.log(1e-3), np.log(6.0),
+                                        (b, t, h, dk))).astype("f4"))
+    beta = jnp.asarray(rng.uniform(0.05, 0.95, (b, t, h)).astype("f4"))
+    cot = jnp.asarray(rng.randn(b, t, h, dv).astype("f4"))
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a) * cot)
+    with jax.default_matmul_precision("highest"):
+        want = ref.kda_recurrence(q, k, v, g, beta)
+        got = kda_chunked((q, k, v, g, beta), chunk=64, group=2)
+        g_want = jax.grad(loss(ref.kda_recurrence), range(5))(q, k, v, g,
+                                                               beta)
+        g_got = jax.grad(loss(lambda *a: kda_chunked(a, chunk=64, group=2)),
+                         range(5))(q, k, v, g, beta)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    for name, a, w in zip("qkvgb", g_got, g_want):
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), rtol=1e-3,
+                                   atol=2e-5 * scale, err_msg=name)
+
+
+@pytest.mark.parametrize("tq,tk,causal", [(96, 96, True), (70, 70, True),
+                                          (40, 104, True), (64, 64, False)])
+def test_flash_attention_with_a_value_width_of_its_own(tq, tk, causal):
+    """192-wide q.k beside 128-wide v in the model; here 24 and 16.
+    Forward (the kernel, interpreted) and backward (blockwise) against the
+    dense softmax."""
+    from mxnet_tpu.ops.pallas_flash import flash_attention
+    rng = np.random.RandomState(tq + tk)
+    b, h, d, dv = 2, 2, 24, 16
+    q = jnp.asarray(rng.randn(b, h, tq, d).astype("f4"))
+    k = jnp.asarray(rng.randn(b, h, tk, d).astype("f4"))
+    v = jnp.asarray(rng.randn(b, h, tk, dv).astype("f4"))
+    cot = jnp.asarray(rng.randn(b, h, tq, dv).astype("f4"))
+
+    def dense(q, k, v):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(d)
+        if causal:
+            mask = jnp.arange(tk)[None, :] <= jnp.arange(tq)[:, None] \
+                + (tk - tq)
+            s = jnp.where(mask, s, -jnp.inf)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+    got = flash_attention(q, k, v, 32, 32, causal)
+    assert got.shape == (b, h, tq, dv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(dense(q, k, v)),
+                               rtol=2e-4, atol=2e-5)
+    g_got = jax.grad(lambda *a: jnp.sum(
+        flash_attention(*a, 32, 32, causal) * cot), (0, 1, 2))(q, k, v)
+    g_want = jax.grad(lambda *a: jnp.sum(dense(*a) * cot), (0, 1, 2))(q, k, v)
+    for name, a, w in zip("qkv", g_got, g_want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), rtol=2e-3,
+                                   atol=2e-4, err_msg=name)
+
+
+def test_routing_however_skewed_is_one_exact_grouped_product():
+    """A router that sends every token to one held expert and none to the
+    other, then an even one: ragged groups from empty to every row, no
+    capacity to pass, the result the masked sum over the held experts."""
+    from mxnet_tpu.parallel import moe
+    rng = np.random.RandomState(0)
+    n, hid, inter, e = 256, 16, 8, 16
+    x = jnp.asarray(np.abs(rng.randn(n, hid)).astype("f4"))
+    w_r = np.zeros((e, hid), "f4")
+    w_r[3] = 1.0                              # every token's first choice
+    w_r[5] = 0.5
+    wg, wu = (jnp.asarray(rng.randn(2, inter, hid).astype("f4"))
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(2, hid, inter).astype("f4"))
+    kw = dict(experts_held=(3, 5), top_k=2, scale=1.5)
+
+    def masked(x, router):
+        ch, wt = moe.route(x, router, jnp.zeros(e), 2, 1.5)
+        return sum(moe.swiglu(x, wg[i], wu[i], wd[i]) * jnp.sum(
+            jnp.where(ch == 3 + i, wt, 0.0), -1, keepdims=True)
+            for i in (0, 1))
+    layer = jax.jit(lambda *a: moe.expert_layer(*a, **kw))
+    y, counts = layer(x, jnp.asarray(w_r), jnp.zeros(e), wg, wu, wd)
+    assert counts.tolist() == [n, 0]
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(masked(x, jnp.asarray(w_r))),
+                               rtol=2e-4, atol=2e-5)
+    x2 = jnp.asarray(rng.randn(n, hid).astype("f4"))
+    even = jnp.asarray(rng.randn(e, hid).astype("f4"))
+    y2, c2 = layer(x2, even, jnp.zeros(e), wg, wu, wd)
+    assert 0 < int(c2.min()) and int(c2.sum()) < n
+    np.testing.assert_allclose(np.asarray(y2), np.asarray(masked(x2, even)),
+                               rtol=2e-4, atol=2e-5)
+    # the layer is one program for any routing: no branch on the counts
+    eqns = jax.make_jaxpr(lambda *a: moe.expert_layer(*a, **kw))(
+        x, jnp.asarray(w_r), jnp.zeros(e), wg, wu, wd).jaxpr.eqns
+    names = [q.primitive.name for q in eqns]
+    assert "cond" not in names and names.count("ragged_dot_general") == 3
+
+
+def test_expert_layer_gradients_reach_router_and_experts():
+    from mxnet_tpu.parallel import moe
+    rng = np.random.RandomState(1)
+    n, hid, inter, e = 64, 8, 4, 8
+    x = jnp.asarray(rng.randn(n, hid).astype("f4"))
+    w_r = jnp.asarray(rng.randn(e, hid).astype("f4"))
+    wg, wu = (jnp.asarray(rng.randn(2, inter, hid).astype("f4"))
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(2, hid, inter).astype("f4"))
+    kw = dict(experts_held=(2, 4), top_k=2, scale=2.0)
+
+    def plain(x, w_r, wg, wu, wd):
+        ch, wt = moe.route(x, w_r, jnp.zeros(e), 2, 2.0)
+        return sum(moe.swiglu(x, wg[i], wu[i], wd[i]) * jnp.sum(
+            jnp.where(ch == 2 + i, wt, 0.0), -1, keepdims=True)
+            for i in (0, 1))
+    got = jax.grad(lambda *a: jnp.sum(moe.expert_layer(
+        a[0], a[1], jnp.zeros(e), *a[2:], **kw)[0] ** 2), range(5))(
+            x, w_r, wg, wu, wd)
+    want = jax.grad(lambda *a: jnp.sum(plain(*a) ** 2), range(5))(
+        x, w_r, wg, wu, wd)
+    for a, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), rtol=2e-3,
+                                   atol=2e-4)
+    assert float(jnp.max(jnp.abs(got[1]))) > 0      # the router learns
+
+
+def test_head_loss_is_cross_entropy_and_never_the_whole_logits():
+    rng = np.random.RandomState(2)
+    b, t, hid, vocab = 2, 50, 8, 37
+    x = jnp.asarray(rng.randn(b, t, hid).astype("f4"))
+    w = jnp.asarray(rng.randn(vocab, hid).astype("f4"))
+    label = jnp.asarray(rng.randint(0, vocab, (b, t)).astype("f4"))
+    op = mx.ops.get("_contrib_LMHeadLoss").fn
+
+    def plain(x, w):
+        logp = jax.nn.log_softmax(x @ w.T, -1)
+        return -jnp.take_along_axis(
+            logp, label.astype(jnp.int32)[..., None], -1)[..., 0]
+    got = op(x, w, label, block=16)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(plain(x, w)),
+                               rtol=1e-5, atol=1e-5)
+    g_got = jax.grad(lambda *a: jnp.sum(op(*a, label, block=16,
+                                           normalization="tokens")),
+                     (0, 1))(x, w)
+    g_want = jax.grad(lambda *a: jnp.sum(plain(*a)) / t, (0, 1))(x, w)
+    for a, want in zip(g_got, g_want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(want),
+                                   rtol=1e-4, atol=1e-6)
+    # blocks of 16 tokens: no array of all the tokens by the vocabulary
+    text = str(jax.make_jaxpr(lambda *a: op(*a, label, block=16))(x, w))
+    assert "f32[%d,%d]" % (b * t, vocab) not in text
+
+
+def test_rmsnorm_and_causal_conv_ops():
+    rng = np.random.RandomState(4)
+    x = rng.randn(2, 9, 6).astype("f4")
+    g = rng.rand(6).astype("f4")
+    got = mx.nd.RMSNorm(mx.nd.array(x), mx.nd.array(g), eps=1e-5).asnumpy()
+    want = x / np.sqrt((x ** 2).mean(-1, keepdims=True) + 1e-5) * g
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    w = rng.randn(6, 4).astype("f4")
+    y = mx.nd.contrib.CausalConv1D(mx.nd.array(x), mx.nd.array(w),
+                                   act_type="none").asnumpy()
+    xp = np.pad(x, ((0, 0), (3, 0), (0, 0)))
+    want = sum(xp[:, i:i + 9] * w[:, i] for i in range(4))
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-6)
+    # a later token changes nothing before it
+    x2 = x.copy()
+    x2[:, 5:] += 1.0
+    y2 = mx.nd.contrib.CausalConv1D(mx.nd.array(x2), mx.nd.array(w),
+                                    act_type="none").asnumpy()
+    np.testing.assert_allclose(y2[:, :5], y[:, :5], rtol=1e-6)

@@ -12,8 +12,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from mxnet_tpu.parallel import (make_pipeline, stack_stage_params,
-                                moe_layer, init_moe_params,
-                                shard_moe_params, make_mesh)
+                                make_mesh)
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 4, reason="needs the 8-virtual-device mesh")
@@ -75,82 +74,39 @@ def test_pipeline_gradients_match_sequential():
                                    rtol=5e-4, atol=5e-5)
 
 
-def _moe_reference(params, x, capacity_factor=2.0):
-    """Token-by-token loop over the same routing rules."""
-    import math
-    n, d = x.shape
-    e = params["gate"].shape[1]
-    c = max(1, int(math.ceil(n / e * capacity_factor)))
-    logits = np.asarray(x @ params["gate"])
-    probs = np.exp(logits - logits.max(-1, keepdims=True))
-    probs /= probs.sum(-1, keepdims=True)
-    expert = probs.argmax(-1)
-    used = np.zeros(e, int)
-    y = np.array(x, copy=True)
-    for i in range(n):
-        ex = int(expert[i])
-        if used[ex] >= c:
-            continue   # dropped: residual only
-        used[ex] += 1
-        h = np.maximum(np.asarray(x[i]) @ np.asarray(params["w1"][ex]), 0)
-        out = h @ np.asarray(params["w2"][ex])
-        y[i] = np.asarray(x[i]) + probs[i, ex] * out
-    return y
+# ---------------------------------------------------------------- experts
+# (the top-1 capacity-drop ``moe_layer`` and its six tests went with PR 27:
+# ``parallel/moe.py`` is now the one expert layer, told which experts it
+# holds; tests/test_kimi_linear.py holds its other tests)
 
-
-@pytest.mark.parametrize("ep", [1, 2, 4])
-def test_moe_matches_reference_loop(ep):
-    d, h, e, n = 8, 16, 4, 32
-    params = init_moe_params(0, d, h, e)
-    x = jnp.asarray(np.random.RandomState(5).randn(n, d).astype("f4"))
-    ref = _moe_reference(params, x)
-    if ep == 1:
-        out = jax.jit(moe_layer)(params, x)
-    else:
-        mesh = make_mesh({"ep": ep}, devices=jax.devices()[:ep])
-        sharded = shard_moe_params(params, mesh, "ep")
-        out = jax.jit(moe_layer)(sharded, x)
-    np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-4, atol=2e-5)
-
-
-def test_moe_expert_weights_actually_sharded():
-    mesh = make_mesh({"ep": 4}, devices=jax.devices()[:4])
-    params = shard_moe_params(init_moe_params(0, 8, 16, 8), mesh, "ep")
-    shard_shapes = {s.data.shape for s in params["w1"].addressable_shards}
-    assert shard_shapes == {(2, 8, 16)}   # 8 experts / 4 devices
-
-
-def test_moe_trains():
-    """ep=2 end-to-end: gradient descent reduces a regression loss."""
-    d, h, e, n = 8, 16, 4, 64
-    mesh = make_mesh({"ep": 2}, devices=jax.devices()[:2])
-    params = shard_moe_params(init_moe_params(1, d, h, e), mesh, "ep")
-    rng = np.random.RandomState(0)
+@pytest.mark.parametrize("ep", [2, 4])
+def test_expert_shares_on_their_own_devices_add_up(ep):
+    """Each of ``ep`` devices holds 8 / ep of the experts and routes over
+    all 8: the parts they compute, each on its own device, add up to the
+    layer one device computes whole. No token is dropped on the way."""
+    from mxnet_tpu.parallel import expert_layer
+    rng = np.random.RandomState(ep)
+    n, d, h, e, k = 64, 8, 16, 8, 2
     x = jnp.asarray(rng.randn(n, d).astype("f4"))
-    target = jnp.asarray(rng.randn(n, d).astype("f4") * 0.1)
-
-    @jax.jit
-    def step(p):
-        def loss(p):
-            return jnp.mean((moe_layer(p, x) - x - target) ** 2)
-        l, g = jax.value_and_grad(loss)(p)
-        return l, jax.tree.map(lambda a, b: a - 0.5 * b, p, g)
-
-    l0, params = step(params)
-    for _ in range(30):
-        l, params = step(params)
-    assert float(l) < float(l0) * 0.7, (float(l0), float(l))
-
-
-def test_aux_load_balance_loss():
-    from mxnet_tpu.parallel import aux_load_balance_loss
-    d, e = 8, 4
-    params = init_moe_params(0, d, 16, e)
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(64, d).astype("f4"))
-    l = float(aux_load_balance_loss(params, x))
-    assert l > 0
-    # a perfectly-balanced uniform router scores E^2 * E * (1/E * 1/E) = 1
-    params_uniform = dict(params, gate=jnp.zeros((d, e), jnp.float32))
-    lu = float(aux_load_balance_loss(params_uniform, x))
-    np.testing.assert_allclose(lu, 1.0, rtol=0.2)
+    w_r = jnp.asarray(rng.randn(e, d).astype("f4"))
+    bias = jnp.zeros(e)
+    wg, wu = (jnp.asarray(rng.randn(e, h, d).astype("f4") * .3)
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(e, d, h).astype("f4") * .3)
+    whole, counts = expert_layer(x, w_r, bias, wg, wu, wd,
+                                 experts_held=(0, e), top_k=k, scale=2.0)
+    assert int(counts.sum()) == n * k
+    total, held = 0, 0
+    for rank, dev in enumerate(jax.devices()[:ep]):
+        lo, hi = rank * e // ep, (rank + 1) * e // ep
+        put = lambda a: jax.device_put(a, dev)       # noqa: E731
+        part, c = jax.jit(lambda *a, lo=lo, hi=hi: expert_layer(
+            *a, experts_held=(lo, hi), top_k=k, scale=2.0))(
+                put(x), put(w_r), put(bias), put(wg[lo:hi]), put(wu[lo:hi]),
+                put(wd[lo:hi]))
+        assert part.devices() == {dev}
+        total = total + np.asarray(part)
+        held += int(c.sum())
+    assert held == n * k
+    np.testing.assert_allclose(total, np.asarray(whole), rtol=2e-4,
+                               atol=2e-5)
