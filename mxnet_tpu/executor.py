@@ -33,6 +33,7 @@ from . import telemetry as _telemetry
 from . import autograd as _autograd
 from .ndarray import ndarray as _nd
 from .ndarray.ndarray import NDArray
+from .ops.registry import STAGE_KEEP, stage_marks
 
 __all__ = ["Executor", "simple_bind"]
 
@@ -53,6 +54,16 @@ def mirror_wrap(f):
             "MXNET_MIRROR_POLICY=%r is not a jax.checkpoint_policies "
             "name" % _flags.mirror_policy)
     return jax.checkpoint(f, policy=policy)
+
+
+# what a ``mirror_stage`` keeps for its backward pass beside what enters and
+# leaves it: the values an op marked (``ops.registry.stage_keep``: an
+# attention kernel's output, a recurrence's states), and nothing else
+_STAGE_POLICY = jax.checkpoint_policies.save_only_these_names(STAGE_KEEP)
+_KEPT_VALUES = ("values that the mirror_stages of the training program "
+                "traced last keep for their backward pass because an op "
+                "marked them (0: no stage, or nothing marked)")
+_KEPT_MB = "what those values hold, from their shapes, in 1e6 bytes"
 
 
 def _mirror_stages(nodes, entries):
@@ -105,7 +116,10 @@ def _graph_eval_fn(symbol):
     ``mirror_stage`` attribute (``mx.AttrScope(mirror_stage=...)`` around a
     block) are evaluated stage by stage under ``jax.checkpoint`` when
     training: the backward pass then keeps what enters and leaves a stage
-    and recomputes its interior. A node that carries ``device_scope``
+    and what an op inside marked as dear to recompute (``_STAGE_POLICY``),
+    and recomputes the rest of its interior; the gauges
+    ``stage/kept_values`` and ``stage/kept_mb`` say what the marked values
+    of the program traced last come to. A node that carries ``device_scope``
     runs under ``jax.named_scope`` of that name, so a builder names the
     device ops of a generic op by the layer they serve.
     """
@@ -188,6 +202,7 @@ def _graph_eval_fn(symbol):
 
         values = {}
         aux_updates = {}
+        kept = []       # bytes of every value a stage of this trace keeps
         with _random.trace_scope(key):
             i = 0
             while i < len(nodes):
@@ -224,13 +239,18 @@ def _graph_eval_fn(symbol):
                         v = values[k[1]] if k[1] in values \
                             else values[(k[1], k[2])]
                         taken.append(v[k[2]] if isinstance(v, tuple) else v)
-                outs, upd = jax.checkpoint(stage)(taken)
+                with stage_marks(kept):
+                    outs, upd = jax.checkpoint(
+                        stage, policy=_STAGE_POLICY)(taken)
                 for (nid, oi), v in zip(writes, outs):
                     values[(nid, oi)] = v
                 aux_updates.update(upd)
                 i = last + 1
             _, read = run([], var, aux_vals.__contains__, values, training)
             outputs = [read(n, oi) for (n, oi) in entries]
+        if training:    # set, not added: a retrace counts the same values
+            _telemetry.gauge("stage/kept_values", _KEPT_VALUES).set(len(kept))
+            _telemetry.gauge("stage/kept_mb", _KEPT_MB).set(sum(kept) / 1e6)
         return outputs, aux_updates
 
     return eval_fn

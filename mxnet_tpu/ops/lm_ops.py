@@ -4,9 +4,11 @@ depthwise convolution of linear-attention layers, the KDA recurrence
 gives every token's loss without the tokens x vocabulary array.
 
 All are pure JAX; gradients come from ``jax.vjp`` (the head's from a
-``custom_vjp`` that works through the tokens in blocks). Each of the
-layers a device trace should tell apart carries a ``jax.named_scope``
-(``mx/kda``, ``mx/lm_head``; docs/observability.md).
+``custom_vjp`` that works through the tokens in blocks, the KDA core's from
+one that walks its groups of chunks in reverse and marks what a
+``mirror_stage`` should keep). Each of the layers a device trace should
+tell apart carries a ``jax.named_scope`` (``mx/kda`` with ``mx/kda/intra``
+and ``mx/kda/scan`` inside it, ``mx/lm_head``; docs/observability.md).
 """
 from __future__ import annotations
 
@@ -16,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .registry import register, set_op_meta
+from .registry import register, set_op_meta, stage_keep
 
 _F32 = jnp.float32
 
@@ -118,7 +120,10 @@ def _kda_group(s, q, k, v, g, beta, chunk, sub):
     pseudo-values ``U = (I + A)^-1 (beta V - beta K+ S_0)``), then from
     chunk to chunk a scan that carries the state. q, k, g: (B, T, H, d_k);
     v: (B, T, H, d_v); beta: (B, T, H), float32, T a multiple of
-    ``chunk``. Returns (the state after the group, o (B, T, H, d_v))."""
+    ``chunk``. Returns (the state after the group, o (B, T, H, d_v)).
+    The two halves run under the scopes ``mx/kda/intra`` (parallel over
+    chunks) and ``mx/kda/scan`` (sequential), for a device trace to split
+    the core by."""
     b, t, h, dk = q.shape
     dv = v.shape[-1]
     n = t // chunk
@@ -127,20 +132,21 @@ def _kda_group(s, q, k, v, g, beta, chunk, sub):
         x = x.reshape((b, n, chunk, h) + x.shape[3:])
         return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
 
-    q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
-    beta = chunks(beta[..., None])                         # (N, B, H, C, 1)
-    gc = jnp.cumsum(g, axis=-2)                            # G_t, <= 0
     hi = lax.Precision.HIGHEST
-    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
-    a = jnp.where(strict, _pair_scores(k, k, gc, sub), 0.0) * beta
-    m = _pair_scores(q, k, gc, sub)                        # q.k, s <= t
-    decay = jnp.exp(gc)                                    # within (0, 1]
-    rhs = jnp.concatenate([v * beta, k * decay * beta], -1)
-    sol = _tri_solve(a, rhs)
-    u0, w = sol[..., :dv], sol[..., dv:]
-    q_in = q * decay
-    g_end = gc[..., -1:, :]                                # (N, B, H, 1, dk)
-    k_out = k * jnp.exp(g_end - gc)                        # decay to the end
+    with jax.named_scope("mx/kda/intra"):
+        q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
+        beta = chunks(beta[..., None])                     # (N, B, H, C, 1)
+        gc = jnp.cumsum(g, axis=-2)                        # G_t, <= 0
+        strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+        a = jnp.where(strict, _pair_scores(k, k, gc, sub), 0.0) * beta
+        m = _pair_scores(q, k, gc, sub)                    # q.k, s <= t
+        decay = jnp.exp(gc)                                # within (0, 1]
+        rhs = jnp.concatenate([v * beta, k * decay * beta], -1)
+        sol = _tri_solve(a, rhs)
+        u0, w = sol[..., :dv], sol[..., dv:]
+        q_in = q * decay
+        g_end = gc[..., -1:, :]                            # (N, B, H, 1, dk)
+        k_out = k * jnp.exp(g_end - gc)                    # decay to the end
 
     def step(s, x):
         u0_c, w_c, m_c, q_c, k_c, ge_c = x
@@ -150,46 +156,120 @@ def _kda_group(s, q, k, v, g, beta, chunk, sub):
             + jnp.matmul(jnp.swapaxes(k_c, -1, -2), u, precision=hi)
         return s, o
 
-    s, o = lax.scan(step, s, (u0, w, m, q_in, k_out, g_end))
+    with jax.named_scope("mx/kda/scan"):
+        s, o = lax.scan(step, s, (u0, w, m, q_in, k_out, g_end))
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)          # (B, N, C, H, dv)
     return s, o.reshape(b, t, h, dv)
 
 
-def kda_chunked(xs, pre=None, *, chunk=64, sub=16, group=16):
-    """The delta rule with a per-channel forget gate,
-    ``S_t = (I - beta k k^T) Diag(exp g) S_{t-1} + beta k v^T``,
-    ``o_t = S_t^T q_t`` from a zero state, in chunks of ``chunk`` tokens
-    (:func:`_kda_group`), ``group`` chunks at a time under a
-    rematerialised scan: the backward pass keeps one state a group and
-    recomputes a group's interior (whose own scan keeps one state a
-    chunk), so what a step holds does not grow with the sequence.
-
-    ``xs`` are arrays (B, T, ...) cut along T; ``pre`` maps a group's
-    slices of them to ``(q, k, v, g, beta)`` in float32 (q, k, g: (B, t,
-    H, d_k); v: (B, t, H, d_v); beta: (B, t, H)), and without it ``xs``
-    are those five. Returns o (B, T, H, d_v) in float32."""
+def _kda_grouped(pre, xs, chunk, sub, group):
+    """How ``xs`` (B, T, ...) are worked through in groups of whole chunks:
+    ``(run, to_groups, from_groups)``. ``run(consts, s, x)`` is one group
+    from the state ``s`` (:func:`_kda_group` after ``pre``); ``to_groups``
+    pads an array with zero rows after the sequence (they change nothing
+    before) and puts the groups first, (N, B, span, ...); ``from_groups``
+    undoes it."""
     b, t = xs[0].shape[:2]
     chunk = min(chunk, -(-t // sub) * sub)
-    group = min(group, -(-t // chunk))
-    span = group * chunk
+    span = min(group, -(-t // chunk)) * chunk
     pad = (-t) % span
     n = (t + pad) // span
 
-    def grouped(x):     # zero rows after the sequence change nothing before
+    def run(consts, s, x):
+        return _kda_group(s, *pre(*consts, *x), chunk, sub)
+
+    def to_groups(x):
         x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
         return jnp.moveaxis(x.reshape((b, n, span) + x.shape[2:]), 1, 0)
 
-    @jax.checkpoint
+    def from_groups(x):
+        return jnp.moveaxis(x, 0, 1).reshape((b, n * span) + x.shape[3:])[:, :t]
+
+    return run, to_groups, from_groups
+
+
+def _kda_fwd_scan(pre, chunk, sub, group, dtype, consts, xs):
+    """The scan over groups from a zero state: (o (B, T, H, d_v) in
+    ``dtype``, the state on entry to every group (N, B, H, d_k, d_v))."""
+    run, to_groups, from_groups = _kda_grouped(pre, xs, chunk, sub, group)
+
     def body(s, x):
-        q, k, v, g, beta = pre(*x) if pre is not None else x
-        return _kda_group(s, q, k, v, g, beta, chunk, sub)
+        after, o = run(consts, s, x)
+        return after, (s, o)
 
     first = [x[:, :1] for x in xs]
-    q0, _, v0, _, _ = jax.eval_shape(pre, *first) if pre is not None \
-        else first
-    s0 = jnp.zeros((b, q0.shape[2], q0.shape[3], v0.shape[3]), _F32)
-    _, o = lax.scan(body, s0, tuple(grouped(x) for x in xs))
-    return jnp.moveaxis(o, 0, 1).reshape((b, n * span) + o.shape[3:])[:, :t]
+    q0, _, v0, _, _ = jax.eval_shape(pre, *consts, *first)
+    s0 = jnp.zeros((xs[0].shape[0], q0.shape[2], q0.shape[3], v0.shape[3]),
+                   _F32)
+    _, (states, o) = lax.scan(body, s0, tuple(to_groups(x) for x in xs))
+    return from_groups(o).astype(dtype), states
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
+def _kda_core(pre, chunk, sub, group, dtype, consts, xs):
+    return _kda_fwd_scan(pre, chunk, sub, group, dtype, consts, xs)[0]
+
+
+def _kda_core_fwd(pre, chunk, sub, group, dtype, consts, xs):
+    # inside a mirror_stage the output and the groups' entry states are
+    # kept: the stage's backward pass recomputes ``xs`` from the
+    # projections and never runs this scan again
+    o, states = _kda_fwd_scan(pre, chunk, sub, group, dtype, consts, xs)
+    o, states = stage_keep(o), stage_keep(states)
+    return o, (consts, xs, states)
+
+
+def _kda_core_bwd(pre, chunk, sub, group, dtype, res, ct):
+    """The groups in reverse, each recomputed from its entry state (what
+    bounds memory in T: a group's interior exists one group at a time),
+    the state's cotangent carried from group to group."""
+    consts, xs, states = res
+    run, to_groups, from_groups = _kda_grouped(pre, xs, chunk, sub, group)
+
+    def body(carry, a):
+        ds, dconsts = carry
+        s, x, do = a
+        _, pull = jax.vjp(run, consts, s, x)
+        dc, ds, dx = pull((ds, do))
+        return (ds, jax.tree.map(jnp.add, dconsts, dc)), dx
+
+    zeros = (jnp.zeros_like(states[0]), jax.tree.map(jnp.zeros_like, consts))
+    (_, dconsts), dxs = lax.scan(
+        body, zeros,
+        (states, tuple(to_groups(x) for x in xs), to_groups(ct.astype(_F32))),
+        reverse=True)
+    return dconsts, tuple(from_groups(d) for d in dxs)
+
+
+_kda_core.defvjp(_kda_core_fwd, _kda_core_bwd)
+
+
+def _as_given(*x):
+    return x
+
+
+def kda_chunked(xs, pre=_as_given, consts=(), *, chunk=64, sub=16, group=16,
+                dtype=_F32):
+    """The delta rule with a per-channel forget gate,
+    ``S_t = (I - beta k k^T) Diag(exp g) S_{t-1} + beta k v^T``,
+    ``o_t = S_t^T q_t`` from a zero state, in chunks of ``chunk`` tokens
+    (:func:`_kda_group`), ``group`` chunks at a time, with a forward and a
+    backward pass of its own (``jax.custom_vjp``): the forward is a scan
+    over groups that keeps the state on entry to each; the backward walks
+    the groups in reverse and recomputes a group's interior (whose own scan
+    keeps one state a chunk) from its entry state, so what a step holds
+    does not grow with the sequence. Inside a ``mirror_stage`` the output
+    and those states are kept (``stage_keep``): the core runs forward once
+    for the step and once a group for the backward pass.
+
+    ``xs`` are arrays (B, T, ...) cut along T; ``pre(*consts, *slices)``
+    maps a group's slices of them to ``(q, k, v, g, beta)`` in float32 (q,
+    k, g: (B, t, H, d_k); v: (B, t, H, d_v); beta: (B, t, H)), and without
+    it ``xs`` are those five. ``pre`` closes over no array: what it needs
+    beside the slices comes in ``consts``, which get their gradient too.
+    Returns o (B, T, H, d_v) in ``dtype``."""
+    return _kda_core(pre, chunk, sub, group, jnp.dtype(dtype), tuple(consts),
+                     tuple(xs))
 
 
 @register("_contrib_KDA")
@@ -210,7 +290,7 @@ def kda(q, k, v, f, b, a_log, dt_bias, *, num_heads, chunk=64):
             return x * lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True)
                                  + 1e-6)
 
-        def pre(q, k, v, f, b):     # a group's slices, as they arrive
+        def pre(rate, bias, q, k, v, f, b):    # a group's slices, as given
             def heads(x):
                 return x.astype(_F32).reshape(x.shape[:2] + (h, -1))
             qh, kh = l2norm(heads(q)), l2norm(heads(k))
@@ -218,8 +298,9 @@ def kda(q, k, v, f, b, a_log, dt_bias, *, num_heads, chunk=64):
             return (qh * qh.shape[-1] ** -0.5, kh, heads(v), g,
                     jax.nn.sigmoid(b.astype(_F32)))
 
-        o = kda_chunked((q, k, v, f, b), pre, chunk=chunk)
-        return o.reshape(o.shape[:2] + (-1,)).astype(v.dtype)
+        o = kda_chunked((q, k, v, f, b), pre, (rate, bias), chunk=chunk,
+                        dtype=v.dtype)
+        return o.reshape(o.shape[:2] + (-1,))
 
 
 # -------------------------------------------------------------------- head

@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .registry import register as _register, stage_keep
+
 _NEG_INF = -1e30
 
 
@@ -261,7 +263,10 @@ def _flash_bwd(q, k, v, o, do, causal, block_q=512, block_k=512):
 
 
 def _fwd(q, k, v, block_q, block_k, causal, interpret):
-    o = flash_attention(q, k, v, block_q, block_k, causal, interpret)
+    # inside a mirror_stage the output is kept and the kernel is not run
+    # again in the backward pass; q, k, v are recomputed like the rest
+    o = stage_keep(
+        flash_attention(q, k, v, block_q, block_k, causal, interpret))
     return o, (q, k, v, o)
 
 
@@ -274,9 +279,6 @@ flash_attention.defvjp(_fwd, _bwd)
 
 
 # eager/symbolic surface: mx.nd._contrib_FlashAttention(q, k, v, causal=...)
-from .registry import register as _register  # noqa: E402
-
-
 @_register("_contrib_FlashAttention")
 def _contrib_flash_attention(q, k, v, *, causal=False, block_q=128,
                              block_k=128):

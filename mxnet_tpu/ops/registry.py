@@ -18,12 +18,48 @@ graphs thread an explicit key input).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import inspect
+import threading
 
-__all__ = ["Operator", "register", "get", "list_ops", "alias"]
+from jax.ad_checkpoint import checkpoint_name
+
+__all__ = ["Operator", "register", "get", "list_ops", "alias",
+           "STAGE_KEEP", "stage_keep", "stage_marks"]
 
 _REGISTRY: dict[str, "Operator"] = {}
+
+# ------------------------------------------------------- what a stage keeps
+# The one name under which an op marks a value that its backward pass reads
+# and that is dear to recompute. A ``mirror_stage`` of the executor keeps
+# the values so marked and recomputes the rest of its interior; anywhere
+# else the mark is an identity.
+STAGE_KEEP = "mx_stage_keep"
+_marks = threading.local()
+
+
+def stage_keep(x):
+    """``x``, marked for the stage around it to keep. Call it in the
+    forward rule of a ``jax.custom_vjp`` on what goes into the residuals,
+    before it goes there and out, so that the kept value and the residual
+    are one array."""
+    kept = getattr(_marks, "kept", None)
+    if kept is not None:
+        kept.append(x.size * x.dtype.itemsize)
+    return checkpoint_name(x, STAGE_KEEP)
+
+
+@contextlib.contextmanager
+def stage_marks(kept):
+    """While a stage is traced and differentiated: the bytes of every
+    value marked inside it are appended to ``kept``."""
+    prev = getattr(_marks, "kept", None)
+    _marks.kept = kept
+    try:
+        yield
+    finally:
+        _marks.kept = prev
 
 
 class Operator:

@@ -51,6 +51,30 @@ def _tokens(cfg, seed=0, t=T):
     return ids[:, :-1].astype("f4"), ids[:, 1:].astype("f4")
 
 
+def _abstract(sym, **inputs):
+    """(arguments, auxiliary states) of ``sym`` as float32 shapes."""
+    shapes, _, aux_shapes = sym.infer_shape(**inputs)
+    return ({n: jax.ShapeDtypeStruct(s, jnp.float32)
+             for n, s in zip(sym.list_arguments(), shapes)},
+            {n: jax.ShapeDtypeStruct(s, jnp.float32)
+             for n, s in zip(sym.list_auxiliary_states(), aux_shapes)})
+
+
+def _gradients(sym):
+    """``(arguments, auxiliary states) -> every argument's gradient`` of
+    the training graph, differentiated as the fused step does it. A new
+    function a call: nothing traced before is reused."""
+    from mxnet_tpu.executor import _graph_eval_fn
+    eval_fn = _graph_eval_fn(sym)
+
+    def grads(a, x):
+        outs, vjp, _ = jax.vjp(
+            lambda d: eval_fn(d, x, jax.random.PRNGKey(0), True), a,
+            has_aux=True)
+        return vjp([jnp.ones(o.shape, o.dtype) for o in outs])[0]
+    return grads
+
+
 class _Repeat:
     """``steps`` times the same batch, one epoch."""
 
@@ -198,23 +222,21 @@ def test_moe_counters_are_published_at_the_epoch_boundary(cfg, fitted):
 def test_a_mirror_stage_is_one_checkpoint_from_the_symbols_attribute(cfg):
     """Traced only: every block of the symbol is one ``jax.checkpoint``
     when training, none in inference."""
-    from mxnet_tpu.executor import _graph_eval_fn, _mirror_stages
+    from mxnet_tpu.executor import _STAGE_POLICY, _graph_eval_fn, \
+        _mirror_stages
 
     def jaxpr(sym, training):
-        shapes, _, aux_shapes = sym.infer_shape(data=(B, T),
-                                                softmax_label=(B, T))
-        args = {n: jax.ShapeDtypeStruct(s, jnp.float32)
-                for n, s in zip(sym.list_arguments(), shapes)}
-        aux = {n: jax.ShapeDtypeStruct(s, jnp.float32)
-               for n, s in zip(sym.list_auxiliary_states(), aux_shapes)}
+        args, aux = _abstract(sym, data=(B, T), softmax_label=(B, T))
         made = jax.make_jaxpr(
             lambda a, x: _graph_eval_fn(sym)(a, x, jax.random.PRNGKey(0),
                                              training))(args, aux)
         # the graph's own stages: the top level's (the ops keep theirs)
-        return sum(e.primitive.name == "remat2" for e in made.jaxpr.eqns)
+        return [e.params["policy"] for e in made.jaxpr.eqns
+                if e.primitive.name == "remat2"]
     sym = _symbol(cfg)
-    assert jaxpr(sym, True) == len(cfg["layers"])
-    assert jaxpr(sym, False) == 0
+    # a stage keeps what an op marked under the one name, and no more
+    assert jaxpr(sym, True) == [_STAGE_POLICY] * len(cfg["layers"])
+    assert jaxpr(sym, False) == []
     stages = _mirror_stages(sym._topo(), list(sym._entries))
     assert len(stages) == len(cfg["layers"])
     # a stage takes the residual stream and its own weights, and hands on
@@ -284,13 +306,120 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer(cfg):
                                rtol=2e-4, atol=2e-5)
 
 
-@pytest.mark.parametrize("t", [40, 150])
-def test_chunked_kda_is_the_token_by_token_recurrence(t):
-    """Chunk 64 and sequences that are no multiple of it, float32, forward
-    and the gradient of every input, under gates from weak to strong."""
-    from mxnet_tpu.ops.lm_ops import kda_chunked
+# ---- what a stage keeps: the cores run forward once a step, not twice
+LONG_T, SMALL_CHUNK = 512, 16      # two groups of 16 chunks a KDA layer
+
+
+def _traced_gradient(sym, t=LONG_T):
+    """The training program of ``sym`` with its backward pass, traced
+    only."""
+    return jax.make_jaxpr(_gradients(sym))(
+        *_abstract(sym, data=(B, t), softmax_label=(B, t))).jaxpr
+
+
+def _every_eqn(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _every_eqn(sub)
+
+
+@pytest.fixture(scope="module")
+def traced_step(cfg):
+    return list(_every_eqn(_traced_gradient(
+        _symbol(cfg, kda_chunk=SMALL_CHUNK))))
+
+
+@pytest.mark.parametrize("core", ["kda", "attention"])
+def test_a_stage_runs_its_core_forward_once_for_the_step(cfg, traced_step,
+                                                         core):
+    """Traced only. A stage keeps the attention kernel's output, and the
+    KDA core's output and group states: its backward pass recomputes the
+    projections around them and runs neither core again. Per KDA layer the
+    scan over a group's 16 chunks runs forward twice (the step's forward,
+    the group's recompute inside the core's own backward) and backward
+    once; the attention kernel once per attention layer. Before the marks
+    the counts were three and two."""
+    kda_layers = [l for l in cfg["layers"]
+                  if l in cfg["linear_attn_config"]["kda_layers"]]
+    if core == "kda":
+        chunks = 16                # of SMALL_CHUNK tokens: no other scan's
+        scans = [e.params["reverse"] for e in traced_step
+                 if e.primitive.name == "scan"
+                 and e.params["length"] == chunks]
+        assert scans.count(False) == 2 * len(kda_layers)
+        assert scans.count(True) == len(kda_layers)
+        groups = [e.params["reverse"] for e in traced_step
+                  if e.primitive.name == "scan"
+                  and e.params["length"] == LONG_T // (SMALL_CHUNK * chunks)]
+        assert groups.count(False) == groups.count(True) == len(kda_layers)
+    else:
+        kernels = sum(e.primitive.name == "pallas_call" for e in traced_step)
+        assert kernels == len(cfg["layers"]) - len(kda_layers) == 1
+
+
+def test_the_stage_gauges_read_what_the_marked_shapes_say(cfg):
+    """``stage/kept_values`` and ``stage/kept_mb`` are set when a training
+    program is traced: the tiny symbol's nine values by their shapes, the
+    same on a second trace, 0 for a graph without stages."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.models import resnet_symbol
+    values, mb = (telemetry.gauge("stage/kept_values"),
+                  telemetry.gauge("stage/kept_mb"))
+    lin = cfg["linear_attn_config"]
+    kda_layers = [l for l in cfg["layers"] if l in lin["kda_layers"]]
+    h, d = lin["num_heads"], lin["head_dim"]
+    n_groups = LONG_T // (SMALL_CHUNK * 16)
+    per_kda = 4 * (B * LONG_T * h * d + n_groups * B * h * d * d)
+    attn = 4 * B * cfg["num_attention_heads"] * LONG_T * cfg["v_head_dim"]
+    for _ in range(2):                     # set, not added
+        _traced_gradient(_symbol(cfg, kda_chunk=SMALL_CHUNK))
+        assert values.value() == 2 * len(kda_layers) + 1 == 9
+        assert mb.value() == pytest.approx(
+            (len(kda_layers) * per_kda + attn) / 1e6, rel=1e-9)
+    net = resnet_symbol(num_classes=10, num_layers=18, image_shape="3,32,32")
+    jax.make_jaxpr(_gradients(net))(
+        *_abstract(net, data=(2, 3, 32, 32), softmax_label=(2,)))
+    assert values.value() == 0 and mb.value() == 0
+
+
+def test_kept_and_recomputed_are_the_same_numbers(cfg, monkeypatch):
+    """Two stages (a KDA layer, an attention layer, each with its expert
+    layer): every gradient with the marked values kept is bitwise the
+    gradient with each stage under a plain ``jax.checkpoint``, which
+    recomputes them. Compiled without XLA's fusion pass: fused, the CPU
+    compiler rounds the recomputed copy of the KDA core's forward as its
+    neighbours there suggest, and the two differ in the last digit (up to
+    2e-6 of a leaf's largest element; the attention layer's not at all)."""
+    from mxnet_tpu import executor
+    sym = _symbol(cfg, layers=(3, 4))
+    data, label = _tokens(cfg)
+    shapes, aux_shapes = _abstract(sym, data=(B, T), softmax_label=(B, T))
+    rng = np.random.RandomState(11)
+    args = {n: jnp.asarray(rng.randn(*s.shape).astype("f4") * 0.1)
+            for n, s in shapes.items()}
+    args.update(data=jnp.asarray(data), softmax_label=jnp.asarray(label))
+    aux = {n: jnp.zeros(s.shape, jnp.float32) for n, s in aux_shapes.items()}
+
+    def grads():
+        return jax.jit(_gradients(sym)).lower(args, aux).compile(
+            compiler_options={"xla_disable_hlo_passes": "fusion"})(args, aux)
+    kept = grads()
+    monkeypatch.setattr(executor, "_STAGE_POLICY", None)
+    plain = grads()
+    moved = 0
+    for k in sorted(kept):
+        assert np.array_equal(np.asarray(kept[k]), np.asarray(plain[k])), k
+        moved += bool(np.any(np.asarray(kept[k])))
+    assert moved >= len(kept) - 4       # ids, labels, the selection biases
+
+
+def _kda_inputs(t, b=2, h=3, dk=16, dv=8):
+    """q, k, v, g, beta and a cotangent, under gates from weak to strong."""
     rng = np.random.RandomState(t)
-    b, h, dk, dv = 2, 3, 16, 8
 
     def unit(x):
         return x / np.linalg.norm(x, axis=-1, keepdims=True)
@@ -303,23 +432,64 @@ def test_chunked_kda_is_the_token_by_token_recurrence(t):
                                         (b, t, h, dk))).astype("f4"))
     beta = jnp.asarray(rng.uniform(0.05, 0.95, (b, t, h)).astype("f4"))
     cot = jnp.asarray(rng.randn(b, t, h, dv).astype("f4"))
+    return (q, k, v, g, beta), cot
+
+
+def _assert_gradients(names, got, want):
+    for name, a, w in zip(names, got, want):
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), rtol=1e-3,
+                                   atol=2e-5 * scale, err_msg=name)
+
+
+@pytest.mark.parametrize("t", [40, 150])
+def test_chunked_kda_is_the_token_by_token_recurrence(t):
+    """Chunk 64 and sequences that are no multiple of it, float32, forward
+    and the gradient of every input, under gates from weak to strong."""
+    from mxnet_tpu.ops.lm_ops import kda_chunked
+    xs, cot = _kda_inputs(t)
 
     def loss(fn):
         return lambda *a: jnp.sum(fn(*a) * cot)
     with jax.default_matmul_precision("highest"):
-        want = ref.kda_recurrence(q, k, v, g, beta)
-        got = kda_chunked((q, k, v, g, beta), chunk=64, group=2)
-        g_want = jax.grad(loss(ref.kda_recurrence), range(5))(q, k, v, g,
-                                                               beta)
+        want = ref.kda_recurrence(*xs)
+        got = kda_chunked(xs, chunk=64, group=2)
+        g_want = jax.grad(loss(ref.kda_recurrence), range(5))(*xs)
         g_got = jax.grad(loss(lambda *a: kda_chunked(a, chunk=64, group=2)),
-                         range(5))(q, k, v, g, beta)
+                         range(5))(*xs)
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
-    for name, a, w in zip("qkvgb", g_got, g_want):
-        scale = float(jnp.max(jnp.abs(w)))
-        np.testing.assert_allclose(np.asarray(a), np.asarray(w), rtol=1e-3,
-                                   atol=2e-5 * scale, err_msg=name)
+    _assert_gradients("qkvgb", g_got, g_want)
+
+
+def test_chunked_kdas_own_backward_carries_the_state_through_the_groups():
+    """``kda_chunked`` has a backward pass of its own. Three groups, the
+    last one short (300 tokens in groups of 128), the gate made inside
+    ``pre`` from a rate that is no slice of the sequence: the gradient of
+    every input and of the rate against ``jax.grad`` of the token-by-token
+    recurrence; the output in the dtype asked for."""
+    from mxnet_tpu.ops.lm_ops import kda_chunked
+    t = 300
+    xs, cot = _kda_inputs(t)
+    rate = jnp.asarray(np.linspace(0.5, 1.5, 3).astype("f4"))[:, None]
+
+    def pre(rate, q, k, v, f, beta):
+        return q, k, v, rate * f, beta
+
+    def chunked(rate, *a):
+        return kda_chunked(a, pre, (rate,), chunk=64, group=2)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a) * cot)
+    with jax.default_matmul_precision("highest"):
+        g_want = jax.grad(loss(lambda rate, *a: ref.kda_recurrence(
+            *pre(rate, *a))), range(6))(rate, *xs)
+        g_got = jax.grad(loss(chunked), range(6))(rate, *xs)
+        half = kda_chunked(xs, chunk=64, group=2, dtype=jnp.bfloat16)
+    _assert_gradients(["rate"] + list("qkvgb"), g_got, g_want)
+    assert float(jnp.max(jnp.abs(g_want[0]))) > 0
+    assert half.dtype == jnp.bfloat16 and half.shape == cot.shape
 
 
 @pytest.mark.parametrize("tq,tk,causal", [(96, 96, True), (70, 70, True),
