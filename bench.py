@@ -842,18 +842,13 @@ def main():
     times = []
     epoch_cb = timing_cb(times)
 
-    # epoch 0 = warmup/compile; epochs 1..2 timed (through Module.fit).
-    # steps_per_dispatch=1 pins the per-step-dispatch headline (fit's
-    # default of None would auto-engage the K-step scan here and fold the
-    # grouped_* leg into the headline): the headline must keep matching
-    # the reference's --benchmark 1 per-step semantics.
+    # epoch 0 = warmup/compile; epochs 1..2 timed (through Module.fit)
     mod.fit(it, num_epoch=3, eval_metric=None, kvstore="tpu_sync",
             optimizer="sgd",
             optimizer_params={"learning_rate": 0.05, "momentum": 0.9,
                               "multi_precision": True},
             initializer=mx.initializer.Xavier(factor_type="in",
                                               magnitude=2.0),
-            steps_per_dispatch=1,
             epoch_end_callback=epoch_cb)
     if mod._fused is None:
         raise RuntimeError("tpu_sync did not engage the fused train step — "
@@ -875,31 +870,6 @@ def main():
         mod._fit_step(batch_obj)
         force()
     sync_step_ms = (time.perf_counter() - t1) / n_sync * 1e3
-
-    # grouped dispatch (fit(steps_per_dispatch=K)): K fused steps ride ONE
-    # XLA program (lax.scan over stacked batches), amortising per-dispatch
-    # host/PJRT latency. ON BY DEFAULT (K=30 on the chip; a small K on
-    # CPU keeps the scan path exercised): the dispatch-amortised numbers
-    # ride as the
-    # grouped_* fields while the headline stays the per-step-dispatch fit,
-    # matching the reference's --benchmark 1 semantics. BENCH_K=0 opts out.
-    k_disp = int(os.environ.get("BENCH_K", "30" if on_tpu else "2"))
-    grouped_img_s = grouped_step_ms = grouped_mfu = None
-    if k_disp > 1:
-        t_k = []
-        it.reset()
-        # continues on the already-initialized module; epoch 0 compiles
-        # the scan program, epochs 1..2 are timed
-        mod.fit(it, num_epoch=3, eval_metric=None, kvstore="tpu_sync",
-                optimizer="sgd",
-                optimizer_params={"learning_rate": 0.05, "momentum": 0.9,
-                                  "multi_precision": True},
-                steps_per_dispatch=k_disp,
-                epoch_end_callback=timing_cb(t_k))
-        dt_k = t_k[-1] - t_k[0]
-        n_timed_k = steps * (len(t_k) - 1)
-        grouped_img_s = batch * n_timed_k / dt_k
-        grouped_step_ms = dt_k / n_timed_k * 1e3
 
     # FLOPs/step from XLA cost analysis of the compiled fused program
     flops_per_step = _perfmodel.RESNET50_TRAIN_FLOPS_PER_IMG * batch
@@ -1034,7 +1004,6 @@ def main():
                 optimizer="sgd",
                 optimizer_params={"learning_rate": 0.05, "momentum": 0.9,
                                   "multi_precision": True},
-                steps_per_dispatch=1,
                 epoch_end_callback=timing_cb(t_rec))
         steps_per_epoch = 768 // batch
         dt_rec = t_rec[-1] - t_rec[0]
@@ -1077,14 +1046,6 @@ def main():
         out["mxlint"] = mxlint_metrics
     if kernel_tier_report is not None:
         out["kernel_tier"] = kernel_tier_report
-    if grouped_img_s is not None:
-        out["steps_per_dispatch"] = k_disp
-        out["grouped_img_s"] = round(grouped_img_s, 2)
-        out["grouped_step_ms"] = round(grouped_step_ms, 3)
-        if on_tpu:
-            grouped_mfu = (grouped_img_s / batch) * flops_per_step \
-                / _perfmodel.peak_flops(dev.device_kind)
-            out["grouped_mfu"] = round(grouped_mfu, 4)
     if recordio_img_s is not None:
         out["recordio_img_s"] = round(recordio_img_s, 2)
         out["recordio_input_only_img_s"] = round(input_only_img_s, 2)

@@ -7,7 +7,7 @@
 Drives the main path once through the entry points a user would call, at
 the published widths of the models the repo trains and serves, and checks
 each answer against a reference: the fused ResNet-50 trainer
-(``Module.fit(kvstore="tpu_sync")``, per step and under the K-step scan),
+(``Module.fit(kvstore="tpu_sync")``, one fused step a program),
 the server (``export_compiled`` / ``export_generate`` -> ``serve.Server``)
 and every Pallas kernel in the tree, compiled by Mosaic.
 
@@ -132,9 +132,9 @@ def _fetch(mod):
     return float(np.asarray(jax.device_get(arr)).ravel()[0])
 
 
-def _fit_epochs(mod, it, first, last, k, losses, stamps, bf16=True, lr=0.05):
-    """Epochs [first, last) of ``Module.fit``, ``k`` steps per dispatch;
-    every epoch ends in a host fetch, then its loss and time are kept.
+def _fit_epochs(mod, it, epochs, losses, stamps, bf16=True, lr=0.05):
+    """``epochs`` epochs of ``Module.fit``; every epoch ends in a host
+    fetch, then its loss and time are kept.
     ``bf16``: bf16 compute over f32 masters (``multi_precision``), the
     configuration the repo benches; else float32 throughout."""
     import mxnet_tpu as mx
@@ -145,13 +145,13 @@ def _fit_epochs(mod, it, first, last, k, losses, stamps, bf16=True, lr=0.05):
         losses.append(float(metric.get()[1]))
         stamps.append(time.perf_counter())
 
-    mod.fit(it, begin_epoch=first, num_epoch=last, eval_metric=metric,
+    mod.fit(it, num_epoch=epochs, eval_metric=metric,
             kvstore="tpu_sync", optimizer="sgd",
             optimizer_params={"learning_rate": lr, "momentum": 0.9,
                               "multi_precision": bf16},
             initializer=mx.initializer.Xavier(factor_type="in",
                                               magnitude=2.0),
-            steps_per_dispatch=k, epoch_end_callback=epoch_end)
+            epoch_end_callback=epoch_end)
 
 
 def _forward_logits(sym, arg_params, aux_params, x, ctx):
@@ -167,11 +167,11 @@ def _forward_logits(sym, arg_params, aux_params, x, ctx):
 
 
 def phase_train(ctx=None, num_layers=50, classes=1000, side=224, batch=128,
-                k=4, step_epochs=2, scan_epochs=3, ref_batch=8, lr=0.05):
+                steps_per_epoch=4, epochs=5, ref_batch=8, lr=0.05):
     """ResNet-50, batch 128, bf16 compute over f32 masters, through
-    ``Module.fit(kvstore="tpu_sync")``: ``step_epochs`` epochs of ``k``
-    single-step dispatches, then ``scan_epochs`` epochs of one ``k``-step
-    scan each, all on one repeated learnable batch."""
+    ``Module.fit(kvstore="tpu_sync")``: ``epochs`` epochs of
+    ``steps_per_epoch`` fused steps, a program each, all on one repeated
+    learnable batch."""
     import numpy as np
     import mxnet_tpu as mx
     from mxnet_tpu import models
@@ -181,30 +181,23 @@ def phase_train(ctx=None, num_layers=50, classes=1000, side=224, batch=128,
     sym = models.resnet_symbol(num_classes=classes, num_layers=num_layers,
                                image_shape="3,%d,%d" % (side, side))
     data, label = _learnable_batch(batch, side, classes)
-    it = mx.io.NDArrayIter(np.tile(data, (k, 1, 1, 1)), np.tile(label, k),
-                           batch_size=batch)
+    it = mx.io.NDArrayIter(np.tile(data, (steps_per_epoch, 1, 1, 1)),
+                           np.tile(label, steps_per_epoch), batch_size=batch)
     mod = mx.mod.Module(sym, context=ctx)
     mx.random.seed(0)   # the initializer draws from it
     losses, stamps = [], []
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
         t0 = time.perf_counter()
-        _fit_epochs(mod, it, 0, step_epochs, 1, losses, stamps, lr=lr)
+        _fit_epochs(mod, it, epochs, losses, stamps, lr=lr)
         check(mod._fused is not None, "the fused step did not engage")
-        ran_step = mod._fused._jitted_donate._cache_size()
-        t1 = time.perf_counter()
-        _fit_epochs(mod, it, step_epochs, step_epochs + scan_epochs, k,
-                    losses, stamps, lr=lr)
-        ran_scan = mod._fused._jitted_k._cache_size()
+        compiled = mod._fused._jitted_donate._cache_size()
     bad = [str(w.message) for w in caught
            if "donated buffers were not usable" in str(w.message)]
     check(not bad, "donation refused: %s" % bad[:1])
-    # each fit() builds its step anew around its metric, so each count is
-    # that call's: one donating per-step program, then one K-step scan
-    check(ran_step == 1 and ran_scan == 1,
-          "fit did not run the fused per-step and K-step programs "
-          "(compiled %d, %d)" % (ran_step, ran_scan))
-    steps = (step_epochs + scan_epochs) * k
+    check(compiled == 1, "fit compiled %d donating step programs, not one"
+          % compiled)
+    steps = epochs * steps_per_epoch
     check(mod._optimizer.num_update == steps,
           "optimizer saw %d updates, not %d"
           % (mod._optimizer.num_update, steps))
@@ -212,16 +205,14 @@ def phase_train(ctx=None, num_layers=50, classes=1000, side=224, batch=128,
         where = mod._exec.arg_dict[name]._data.devices()
         check(where == {dev}, "%s lives on %s, not %s" % (name, where, dev))
     check(np.isfinite(losses).all(), "non-finite loss: %s" % losses)
-    check(losses[-1] < losses[0] and losses[-1] < losses[step_epochs - 1],
+    check(losses[-1] < losses[0],
           "loss did not fall on a repeated batch: %s" % losses)
 
-    # epoch 0 and the first scan epoch hold the two compiles
-    step_s = (stamps[step_epochs - 1] - stamps[0]) / ((step_epochs - 1) * k)
-    scan_s = (stamps[-1] - stamps[step_epochs]) / ((scan_epochs - 1) * k)
+    # epoch 0 holds the compile
+    step_s = (stamps[-1] - stamps[0]) / ((epochs - 1) * steps_per_epoch)
     say("train", losses=",".join("%.4f" % v for v in losses))
-    say("train", first_step_epoch_s="%.1f" % (stamps[0] - t0),
-        first_scan_epoch_s="%.1f" % (stamps[step_epochs] - t1),
-        s_per_step="%.4f" % step_s, s_per_step_in_scan="%.4f" % scan_s,
+    say("train", first_epoch_s="%.1f" % (stamps[0] - t0),
+        s_per_step="%.4f" % step_s,
         note="smoke_observations_with_h2d_feed_not_a_benchmark")
     stats = dev.memory_stats()
     if stats:
@@ -650,7 +641,7 @@ def _module_steps(ctx, sym, data, label, steps, bf16):
     # a fifth of the trainer's rate: at 0.05 the first steps on this batch
     # overshoot (7.2, 2.4, 9.9 on the v5e), and a comparison between two
     # placements wants a trajectory that does not amplify their rounding
-    _fit_epochs(mod, it, 0, steps, 1, losses, stamps, bf16=bf16, lr=0.01)
+    _fit_epochs(mod, it, steps, losses, stamps, bf16=bf16, lr=0.01)
     check(mod._fused is not None, "the fused step did not engage")
     check(np.isfinite(losses).all(), "non-finite loss: %s" % losses)
     say("multichip", devices=len(ctx) if isinstance(ctx, list) else 1,

@@ -118,10 +118,6 @@ def main():
     p.add_argument("--load-epoch", type=int, default=None)
     p.add_argument("--disp-batches", type=int, default=20)
     p.add_argument("--device", default=None)
-    p.add_argument("--steps-per-dispatch", type=int, default=1,
-                   help="K>1: run K fused steps per XLA dispatch "
-                        "(lax.scan over stacked batches); amortises "
-                        "host dispatch latency")
     args = p.parse_args()
 
     ctx = pick_ctx()
@@ -166,8 +162,7 @@ def main():
             arg_params=arg_p, aux_params=aux_p,
             begin_epoch=args.load_epoch or 0,
             batch_end_callback=None if args.benchmark else cbs,
-            epoch_end_callback=ep_cbs,
-            steps_per_dispatch=args.steps_per_dispatch)
+            epoch_end_callback=ep_cbs)
 
     if args.benchmark and len(times) >= 2:
         import jax

@@ -91,13 +91,6 @@ RULES = {r.id: r for r in [
          "the registry mirrors label-free series into the chrome trace, "
          "and a direct profiler.record_counter call is invisible to the "
          "Prometheus/JSONL exporters and the flight recorder"),
-    Rule("MXL513", "staged_feed_pass", "warning",
-         "feed the step loop through the staged K-step device feed "
-         "(Module.fit with steps_per_dispatch>1 engages "
-         "mxnet_tpu.data.StagedKFeed) instead of a per-batch device_put/"
-         "nd.array: staged windows commit the H2D on a feeder thread, "
-         "overlapped with the in-flight dispatch, so the loop never "
-         "stalls on input"),
 ]}
 
 
@@ -126,12 +119,6 @@ _NP_NAMES = frozenset(["np", "_np", "numpy", "onp"])
 _LOCKISH = re.compile(r"(?i)(^|_)(lock|cond|mutex|mu|glock|sched_lock)$")
 _THREADISH = re.compile(r"(?i)(thread|proc|worker)")
 _QUEUEISH = re.compile(r"(?i)(queue|^_?q$)")
-
-# MXL513: step-dispatch calls whose enclosing loop is a "step loop", and
-# the ndarray-module aliases whose .array() is a host->device feed
-_STEP_CALLS = frozenset(["_fit_step", "forward_backward", "train_step",
-                         "run_step", "step"])
-_ND_MODULES = frozenset(["nd", "_nd", "ndarray"])
 
 
 def _dotted(node):
@@ -286,7 +273,6 @@ class ModuleLinter(ast.NodeVisitor):
         self._locks_held = []   # stack of (token, node) while visiting
         self._lock_collector = lock_collector
         self._loop_syncs = []   # per-loop: list of (node, expr_src)
-        self._loop_feeds = []   # per-loop: (feed nodes, step-call names)
 
     # -- helpers --
     def _emit(self, rule_id, node, message):
@@ -383,18 +369,6 @@ class ModuleLinter(ast.NodeVisitor):
                 self._emit("MXL202", node,
                            "str() of a traced value concretizes it at "
                            "trace time")
-
-        # MXL513 bookkeeping: per-batch host->device feeds and step
-        # dispatches inside the innermost loop (paired up at loop exit)
-        if self._loop_feeds:
-            feeds, steps = self._loop_feeds[-1]
-            if last == "device_put":
-                feeds.append((node, "device_put"))
-            elif last == "array" and callee and "." in callee \
-                    and callee.rsplit(".", 2)[-2] in _ND_MODULES:
-                feeds.append((node, callee))
-            if last in _STEP_CALLS:
-                steps.append(last)
 
         # MXL103 bookkeeping: host fetches inside the innermost loop
         if self._loop_syncs:
@@ -602,10 +576,8 @@ class ModuleLinter(ast.NodeVisitor):
     # -- MXL103: loop-body fetch batching -----------------------------------
     def _visit_loop_body(self, node):
         self._loop_syncs.append([])
-        self._loop_feeds.append(([], []))
         self.generic_visit(node)
         syncs = self._loop_syncs.pop()
-        feeds, steps = self._loop_feeds.pop()
         if len(syncs) >= 2:
             first = syncs[0][0]
             self._emit("MXL103", first,
@@ -613,17 +585,6 @@ class ModuleLinter(ast.NodeVisitor):
                        "(%s); batch them into one device_get"
                        % (len(syncs),
                           ", ".join(s for _, s in syncs[:4])))
-        # MXL513: a loop that both feeds the device per batch AND
-        # dispatches steps is a hand-rolled train loop bypassing the
-        # staged K-step feed — the H2D serializes with every dispatch
-        if feeds and steps:
-            fnode, fname = feeds[0]
-            self._emit("MXL513", fnode,
-                       "per-batch %s in a loop that dispatches %s "
-                       "serializes the H2D with every step; the staged "
-                       "K-step feed commits the next window on a feeder "
-                       "thread instead" % (fname, "/".join(sorted(set(
-                           steps)))))
 
     def visit_For(self, node):
         self._visit_loop_body(node)

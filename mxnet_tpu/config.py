@@ -267,12 +267,6 @@ register_flag("engine_depth", "MXNET_ENGINE_DEPTH", int, 2,
               "the reference ThreadedEngine's bounded pending-op queue. "
               "1 = fully synchronous stepping; 0/negative = unbounded "
               "(host never throttles; device errors surface late).")
-register_flag("steps_per_dispatch", "MXNET_STEPS_PER_DISPATCH", int, 16,
-              "K used by fit()'s automatic K-step lax.scan dispatch "
-              "(module/fused.py k_step) when the caller leaves "
-              "steps_per_dispatch=None and no per-step host observer "
-              "(batch_end_callback, monitor, lr scheduler, host-side "
-              "metric, checkpoint manager) forces per-step dispatch.")
 register_flag("device_metrics", "MXNET_DEVICE_METRICS", _parse_bool, True,
               "Fold supported eval metrics (acc/top_k/ce/nll/loss) into "
               "the fused train step as device-resident (sum, count) "
@@ -549,7 +543,7 @@ register_flag("telemetry_dir", "MXNET_TELEMETRY_DIR", str, "",
               "no altered SIGTERM disposition.")
 register_flag("telemetry_jsonl", "MXNET_TELEMETRY_JSONL", str, "",
               "Path of the per-window telemetry JSONL snapshot stream "
-              "(one registry snapshot per K-step dispatch window, "
+              "(one registry snapshot per 16-step telemetry window, "
               "appended — the machine-readable sibling of the chrome "
               "trace). Empty: $MXNET_TELEMETRY_DIR/telemetry.jsonl when "
               "the dir is set, else disabled.")
@@ -571,19 +565,6 @@ register_flag("kernel_cost_model", "MXNET_KERNEL_COST_MODEL", str, "",
               "set and valid, tune.cost_model.default_model() ranks "
               "with these weights instead of the shipped hand-rounded "
               "ones. Empty (default): shipped weights.")
-register_flag("data_staged_feed", "MXNET_DATA_STAGED_FEED", _parse_bool,
-              True,
-              "Let Module.fit stage each K-step window's stacked device "
-              "feed on a feeder thread (mxnet_tpu/data/feed.py), "
-              "double-buffered so the async H2D overlaps the in-flight "
-              "dispatch. Only data is staged — PRNG keys and optimizer "
-              "hypers stay on the main thread so bitwise kill/resume "
-              "holds. Off: the dispatch call builds its own stacked feed "
-              "(the pre-staging behaviour).")
-register_flag("data_feed_depth", "MXNET_DATA_FEED_DEPTH", int, 2,
-              "Staged windows in flight for the K-step device feed "
-              "(2 = classic double buffering). Each staged window holds "
-              "K stacked batches of device memory, so keep this small.")
 register_flag("data_decode_threads", "MXNET_DATA_DECODE_THREADS", int, 0,
               "Decode/augment worker threads for StreamingDataIter "
               "(mxnet_tpu/data/record_stream.py). 0 (default): fall back "

@@ -1,5 +1,5 @@
-"""Streaming ingestion tier: sharded record streams feeding the K-step
-dispatch (docs/data.md).
+"""Streaming ingestion tier: sharded record streams feeding the fit loop
+(docs/data.md).
 
 The pieces, bottom-up:
 
@@ -13,10 +13,6 @@ The pieces, bottom-up:
   :class:`StreamingDataIter` turns it into a ``DataIter`` with parallel
   decode/augment and a bitwise kill/resume cursor that rides
   ``CheckpointManager``.
-* ``feed`` — :class:`StagedKFeed`, the zero-stall K-step device feed:
-  double-buffers the next window's K batches into the stacked
-  device-resident layout ``FusedStep.run_k`` scans over, with the async
-  H2D overlapped against the in-flight dispatch.
 """
 from __future__ import annotations
 
@@ -24,9 +20,8 @@ from mxnet_tpu.data.pipeline import PrefetchQueue
 from mxnet_tpu.data.record_stream import (
     ImageDecoder, RawTensorDecoder, ShardedRecordStream, StreamingDataIter,
 )
-from mxnet_tpu.data.feed import StagedKFeed, StagedWindow
 
 __all__ = [
     "PrefetchQueue", "ShardedRecordStream", "StreamingDataIter",
-    "RawTensorDecoder", "ImageDecoder", "StagedKFeed", "StagedWindow",
+    "RawTensorDecoder", "ImageDecoder",
 ]

@@ -236,7 +236,7 @@ class StreamingDataIter(DataIter):
     """
 
     def __init__(self, stream, decoder, batch_size, decode_threads=None,
-                 prefetch_depth=None, ctx=None, data_name="data",
+                 prefetch_depth=2, ctx=None, data_name="data",
                  label_name="softmax_label"):
         super().__init__(batch_size)
         from ..config import flags as _flags
@@ -248,7 +248,7 @@ class StreamingDataIter(DataIter):
         self._nthreads = max(1, int(decode_threads
                                     or _flags.data_decode_threads
                                     or _flags.cpu_worker_nthreads))
-        self._depth = max(2, int(prefetch_depth or _flags.data_feed_depth))
+        self._depth = max(2, int(prefetch_depth))
         from concurrent.futures import ThreadPoolExecutor
         self._pool = ThreadPoolExecutor(self._nthreads)
         self._pq = None

@@ -18,9 +18,8 @@ Semantics note: device sums accumulate in f32 in the compiled program;
 the host path accumulates in python float64. Counts (acc/top_k) are
 integer-valued either way; CE/loss sums agree to f32 rounding. What IS
 bitwise-stable is the device path against itself: the same program
-sequence at any engine depth or steps_per_dispatch produces identical
-bits, which tests/test_async_loop.py and tests/test_step_sync_budget.py
-assert.
+sequence at any engine depth produces identical bits, which
+tests/test_async_loop.py and tests/test_step_sync_budget.py assert.
 """
 from __future__ import annotations
 

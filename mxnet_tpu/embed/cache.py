@@ -16,7 +16,7 @@ in training, only on eviction of a DIRTY row) a d2h pull of the evicted
 rows for write-back. Serving is read-only — rows are never dirty, so
 the served lookup performs ZERO d2h, which mxlint MXL511 pins on the
 lowered program. Hit/miss/spill counters are plain ints published per
-K-step window through ``telemetry.publish_window(embed=...)``.
+telemetry window through ``telemetry.publish_window(embed=...)``.
 
 Bitwise across capacities: a row's update arithmetic depends only on
 its value and its gradient, never on which slot it sits in or when it

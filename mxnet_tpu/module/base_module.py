@@ -134,21 +134,11 @@ class BaseModule:
             checkpoint=None):
         """Epoch loop (reference base_module.py:410-560).
 
-        ``steps_per_dispatch=K > 1`` groups K batches into ONE compiled
-        XLA dispatch (`lax.scan` over the stacked feeds — see
-        ``FusedStep.run_k``), amortising per-step host/PJRT latency.
-        Metric updates stay per-batch; ``batch_end_callback`` fires per
-        batch but only after its group completes; lr/wd schedules advance
-        in steps of K. Requires a module with a fused grouped step
-        (plain :class:`Module`) and no monitor.
-
-        ``steps_per_dispatch=None`` (default) picks K automatically:
-        ``flags.steps_per_dispatch`` (MXNET_STEPS_PER_DISPATCH, default
-        16) when nothing in the loop needs per-step host attention —
-        no monitor/batch_end_callback/checkpoint/sparse_row_id_fn/
-        lr_scheduler, and the eval metric either absent or folded into
-        the device step (see docs/perf.md "Async fit loop"). Otherwise
-        falls back to K=1, reference per-step semantics.
+        Every batch is one fused step, dispatched as one XLA program. The
+        chip is idle under 0.07 % of a traced span in every benchmark cell
+        at one step a program (ledger, PR 28), so nothing groups steps:
+        ``steps_per_dispatch`` accepts ``None`` and ``1`` and selects
+        nothing (ROADMAP.md D16); any other value raises ``ValueError``.
 
         Completed dispatches are NOT waited on synchronously: a
         :class:`~mxnet_tpu.engine.DepthController`
@@ -170,24 +160,17 @@ class BaseModule:
         if initializer is None:
             initializer = _init.Uniform(0.01)
 
-        # validate an EXPLICIT steps_per_dispatch BEFORE any side effect
-        # (bind/install_monitor/init_optimizer are not undone by the
-        # raise); None = decide automatically after the module is set up
-        explicit_k = steps_per_dispatch is not None
-        if explicit_k:
+        # refuse BEFORE any side effect (bind/install_monitor/
+        # init_optimizer are not undone by the raise)
+        if steps_per_dispatch is not None:
             if steps_per_dispatch < 1:
                 raise ValueError("steps_per_dispatch must be >= 1, got %r"
                                  % (steps_per_dispatch,))
             if steps_per_dispatch > 1:
-                if not hasattr(self, "_fit_group"):
-                    raise ValueError(
-                        "steps_per_dispatch > 1 needs a module with a "
-                        "grouped fused step (plain Module); %s has none"
-                        % type(self).__name__)
-                if monitor is not None or sparse_row_id_fn is not None:
-                    raise ValueError(
-                        "steps_per_dispatch > 1 is incompatible with "
-                        "monitor / sparse_row_id_fn")
+                raise ValueError(
+                    "steps_per_dispatch=%r: the K-step scan is gone, fit "
+                    "dispatches one fused step a program; pass 1 or leave "
+                    "it out" % (steps_per_dispatch,))
 
         # every layer boundary below runs under a profiler.span (the
         # table is in docs/observability.md "Spans"); none of them syncs
@@ -259,7 +242,6 @@ class BaseModule:
         # 1. fold the metric into the device step when its math allows:
         #    per-batch update_metric becomes a no-op on the proxy and the
         #    (sum, count) carry moves to host only at reads
-        from ..config import flags as _flags
         if hasattr(self, "_engage_device_metric"):
             if eval_metric is not None and monitor is None:
                 proxy = self._engage_device_metric(eval_metric)
@@ -267,48 +249,33 @@ class BaseModule:
                     eval_metric = proxy
             else:
                 self._detach_device_metric()
-        # 2. with no per-step host observer left, run K steps per dispatch
-        #    (train-loop-under-scan); anything that must see the host
-        #    between steps keeps the reference per-step loop
-        if not explicit_k:
-            auto_k = (monitor is None and sparse_row_id_fn is None
-                      and batch_end_callback is None and ckpt is None
-                      and hasattr(self, "_fit_group")
-                      and getattr(self, "_fused", None) is not None
-                      and (eval_metric is None or
-                           getattr(eval_metric, "_device_resident", False))
-                      and getattr(getattr(self, "_optimizer", None),
-                                  "lr_scheduler", None) is None)
-            steps_per_dispatch = max(1, int(_flags.steps_per_dispatch)) \
-                if auto_k else 1
-        grouped = steps_per_dispatch > 1
-        # 3. dispatch without blocking; bound the in-flight queue so the
+        # 2. dispatch without blocking; bound the in-flight queue so the
         #    host can't run unboundedly ahead of the chip
         from ..engine import DepthController
         depth_ctl = DepthController()
 
-        # 4. run-wide telemetry (docs/observability.md): publish step
+        # 3. run-wide telemetry (docs/observability.md): publish step
         #    time / throughput / engine depth / sync census / span totals
-        #    at K-step window boundaries, using ONLY values this frame
+        #    every 16 steps, using ONLY values this frame
         #    already holds on the host (wall clock, batch shapes, the
         #    in-flight dispatch count) — zero extra device->host syncs,
         #    pinned by tests/test_step_sync_budget.py
         from .. import telemetry as _telemetry
         _telem_t0 = time.monotonic()
-        _telem_every = max(1, int(_flags.steps_per_dispatch))
-        _telem_acc = [0, 0]          # per-step path: (steps, examples)
+        _telem_every = 16
+        _telem_acc = [0, 0]          # (steps, examples) of the open window
 
-        # 5. streaming-tier window stats (docs/data.md): input stall (the
-        #    mx/fit/next spans: time the loop blocked on the iterator /
-        #    staged feed) and feed-queue depth — host-held values, zero
-        #    extra device->host syncs (tests/test_step_sync_budget.py).
+        # 4. streaming-tier window stats (docs/data.md): input stall (the
+        #    mx/fit/next spans: time the loop blocked on the iterator)
+        #    and feed-queue depth — host-held values, zero extra
+        #    device->host syncs (tests/test_step_sync_budget.py).
         #    data/h2d_bytes is counted where the copy is made
-        #    (Executor.prepare_input, Module._stage_group).
+        #    (Executor.prepare_input).
         def _next_ns():
             return _profiler.span_totals().get("mx/fit/next", (0, 0, 0))[1]
 
         _stall_mark = [_next_ns()]
-        _queue_depth = [getattr(train_data, "queue_depth", None)]
+        qd_fn = getattr(train_data, "queue_depth", None)
         has_cursor = hasattr(train_data, "get_cursor") \
             and hasattr(train_data, "seek")
         data_cursor = [None]         # last CONSUMED batch's cursor
@@ -333,7 +300,6 @@ class BaseModule:
                 data = {"input_stall_ms":
                         (next_ns - _stall_mark[0]) / 1e6}
                 _stall_mark[0] = next_ns
-                qd_fn = _queue_depth[0]
                 if qd_fn is not None:
                     try:
                         data["queue_depth"] = qd_fn()
@@ -387,184 +353,76 @@ class BaseModule:
                             except StopIteration:
                                 break
                     nbatch = resume_nbatch
-                if grouped:
-                    # one dispatch per K batches; callbacks fire per batch
-                    # (from THIS frame, so BatchEndParam.locals matches the
-                    # per-step path) but only after the group's dispatch.
-                    # When the module exposes _stage_group, a StagedKFeed
-                    # pre-builds each window's stacked device feed on a feeder
-                    # thread (async H2D overlapped with the in-flight
-                    # dispatch) — the zero-stall K-step feed, docs/data.md.
-                    staged_feed = None
-                    if _flags.data_staged_feed \
-                            and getattr(self, "_fused", None) is not None \
-                            and self.optimizer_initialized \
-                            and hasattr(self, "_stage_group"):
-                        from ..data.feed import StagedKFeed
-                        staged_feed = StagedKFeed(
-                            data_iter, steps_per_dispatch, self._stage_group,
-                            depth=max(2, int(_flags.data_feed_depth)),
-                            cursor_fn=(train_data.get_cursor if has_cursor
-                                       else None),
-                            first_step=global_step)
-                        _queue_depth[0] = staged_feed.queue_depth
+                end_of_batch = False
+                try:
+                    next_data_batch = _timed_next(data_iter, global_step)
+                except StopIteration:
+                    # resume landed exactly on this epoch's end
+                    end_of_batch = True
+                while not end_of_batch:
+                    data_batch = next_data_batch
+                    if monitor is not None:
+                        monitor.tic()
+                    # global_step steps have completed (and, on the save
+                    # grid, been checkpointed) — "kill@step=N" dies HERE,
+                    # so the supervised restart resumes at exactly step N
+                    _fi.fire("step", step=global_step)
+                    # Python, input placement (mx/feed/h2d inside) and
+                    # the enqueue of the step program; never a wait
+                    with _profiler.span("mx/fit/dispatch",
+                                        step=global_step, steps=1):
+                        self._fit_step(data_batch)
+                    depth_ctl.admit(self._dispatch_handles(),
+                                    step=global_step)
+                    # metric BEFORE prefetch/prepare (reference
+                    # base_module.py:528-545): prepare() may switch the
+                    # bucketing module to the NEXT batch's bucket, whose
+                    # executor has no outputs yet
+                    if eval_metric is not None:
+                        with _profiler.span("mx/fit/metric",
+                                            step=global_step):
+                            self.update_metric(eval_metric,
+                                               data_batch.label)
+                    if has_cursor:
+                        # capture BEFORE prefetching the next batch: the
+                        # cursor must reflect batches CONSUMED, not the
+                        # loop's read-ahead
+                        data_cursor[0] = train_data.get_cursor()
                     try:
-                        group, end_of_batch = [], False
-                        staged, win_cursor = None, None
-                        while not end_of_batch:
-                            if staged_feed is not None:
-                                try:
-                                    with _profiler.span("mx/fit/next",
-                                                        step=global_step):
-                                        win = staged_feed.next_window()
-                                except StopIteration:
-                                    win = None
-                                    end_of_batch = True
-                                if win is not None:
-                                    group = list(win.batches)
-                                    staged = win.staged
-                                    win_cursor = win.cursor
-                                    if len(group) < steps_per_dispatch:
-                                        end_of_batch = True  # tail window
-                            else:
-                                try:
-                                    group.append(_timed_next(
-                                        data_iter, global_step + len(group)))
-                                except StopIteration:
-                                    end_of_batch = True
-                            if len(group) == steps_per_dispatch or \
-                                    (end_of_batch and group):
-                                _fi.fire("step", step=global_step)
-                                if len(group) == steps_per_dispatch:
-                                    with _profiler.span("mx/fit/dispatch",
-                                                        step=global_step,
-                                                        steps=len(group)):
-                                        if staged is not None:
-                                            self._fit_group(group, eval_metric,
-                                                            staged=staged)
-                                        else:
-                                            self._fit_group(group, eval_metric)
-                                    depth_ctl.admit(self._dispatch_handles(),
-                                                    step=global_step)
-                                else:
-                                    # tail: per-step path — reuses/compiles
-                                    # the single-step program instead of
-                                    # tracing a second scan variant for the
-                                    # odd group size
-                                    for i, b in enumerate(group):
-                                        with _profiler.span(
-                                                "mx/fit/dispatch",
-                                                step=global_step + i,
-                                                steps=1):
-                                            self._fit_group([b], eval_metric)
-                                        depth_ctl.admit(
-                                            self._dispatch_handles(),
-                                            step=global_step + i)
-                                for data_batch in group:
-                                    if batch_end_callback is not None:
-                                        with _profiler.span("mx/fit/callbacks",
-                                                            step=global_step):
-                                            for cb in _as_list(
-                                                    batch_end_callback):
-                                                cb(BatchEndParam(
-                                                    epoch=epoch, nbatch=nbatch,
-                                                    eval_metric=eval_metric,
-                                                    locals=locals()))
-                                    nbatch += 1
-                                global_step += len(group)
-                                if win_cursor is not None:
-                                    data_cursor[0] = win_cursor
-                                elif has_cursor and staged_feed is None:
-                                    # fit is the only consumer here, so the
-                                    # iterator cursor IS the consumed position
-                                    data_cursor[0] = train_data.get_cursor()
-                                _telem_window(len(group),
-                                              sum(_batch_examples(b)
-                                                  for b in group), global_step)
-                                if ckpt is not None:
-                                    with _profiler.span("mx/fit/checkpoint",
-                                                        step=global_step):
-                                        ckpt.maybe_save(
-                                            _snap_state, global_step,
+                        next_data_batch = _timed_next(data_iter,
+                                                      global_step + 1)
+                        self.prepare(next_data_batch,
+                                     sparse_row_id_fn=sparse_row_id_fn)
+                    except StopIteration:
+                        end_of_batch = True
+                    if monitor is not None:
+                        monitor.toc_print()
+                    if batch_end_callback is not None:
+                        with _profiler.span("mx/fit/callbacks",
+                                            step=global_step):
+                            for cb in _as_list(batch_end_callback):
+                                cb(BatchEndParam(
+                                    epoch=epoch, nbatch=nbatch,
+                                    eval_metric=eval_metric,
+                                    locals=locals()))
+                    nbatch += 1
+                    global_step += 1
+                    _telem_acc[0] += 1
+                    _telem_acc[1] += _batch_examples(data_batch)
+                    if _telem_acc[0] >= _telem_every:
+                        _telem_window(_telem_acc[0], _telem_acc[1],
+                                      global_step)
+                        _telem_acc = [0, 0]
+                    if ckpt is not None:
+                        with _profiler.span("mx/fit/checkpoint",
+                                            step=global_step):
+                            ckpt.maybe_save(_snap_state, global_step,
                                             epoch=epoch, nbatch=nbatch,
                                             meta=meta)
-                                group, staged, win_cursor = [], None, None
-                    finally:
-                        if staged_feed is not None:
-                            staged_feed.close()
-                            _queue_depth[0] = getattr(train_data,
-                                                      "queue_depth", None)
-                else:
-                    end_of_batch = False
-                    try:
-                        next_data_batch = _timed_next(data_iter, global_step)
-                    except StopIteration:
-                        # resume landed exactly on this epoch's end
-                        end_of_batch = True
-                    while not end_of_batch:
-                        data_batch = next_data_batch
-                        if monitor is not None:
-                            monitor.tic()
-                        # global_step steps have completed (and, on the save
-                        # grid, been checkpointed) — "kill@step=N" dies HERE,
-                        # so the supervised restart resumes at exactly step N
-                        _fi.fire("step", step=global_step)
-                        # Python, input placement (mx/feed/h2d inside) and
-                        # the enqueue of the step program; never a wait
-                        with _profiler.span("mx/fit/dispatch",
-                                            step=global_step, steps=1):
-                            self._fit_step(data_batch)
-                        depth_ctl.admit(self._dispatch_handles(),
-                                        step=global_step)
-                        # metric BEFORE prefetch/prepare (reference
-                        # base_module.py:528-545): prepare() may switch the
-                        # bucketing module to the NEXT batch's bucket, whose
-                        # executor has no outputs yet
-                        if eval_metric is not None:
-                            with _profiler.span("mx/fit/metric",
-                                                step=global_step):
-                                self.update_metric(eval_metric,
-                                                   data_batch.label)
-                        if has_cursor:
-                            # capture BEFORE prefetching the next batch: the
-                            # cursor must reflect batches CONSUMED, not the
-                            # loop's read-ahead
-                            data_cursor[0] = train_data.get_cursor()
-                        try:
-                            next_data_batch = _timed_next(data_iter,
-                                                          global_step + 1)
-                            self.prepare(next_data_batch,
-                                         sparse_row_id_fn=sparse_row_id_fn)
-                        except StopIteration:
-                            end_of_batch = True
-                        if monitor is not None:
-                            monitor.toc_print()
-                        if batch_end_callback is not None:
-                            with _profiler.span("mx/fit/callbacks",
-                                                step=global_step):
-                                for cb in _as_list(batch_end_callback):
-                                    cb(BatchEndParam(
-                                        epoch=epoch, nbatch=nbatch,
-                                        eval_metric=eval_metric,
-                                        locals=locals()))
-                        nbatch += 1
-                        global_step += 1
-                        _telem_acc[0] += 1
-                        _telem_acc[1] += _batch_examples(data_batch)
-                        if _telem_acc[0] >= _telem_every:
-                            _telem_window(_telem_acc[0], _telem_acc[1],
-                                          global_step)
-                            _telem_acc = [0, 0]
-                        if ckpt is not None:
-                            with _profiler.span("mx/fit/checkpoint",
-                                                step=global_step):
-                                ckpt.maybe_save(_snap_state, global_step,
-                                                epoch=epoch, nbatch=nbatch,
-                                                meta=meta)
                 # epoch boundary: drain in-flight dispatches before the host
                 # reads metrics/params (one explicit wait, not one per step)
                 depth_ctl.quiesce()
-                if _telem_acc[0]:    # flush the partial per-step window
+                if _telem_acc[0]:    # flush the partial window
                     _telem_window(_telem_acc[0], _telem_acc[1], global_step)
                     _telem_acc = [0, 0]
                 # what ops count rides the step's own state on the device:

@@ -191,39 +191,6 @@ class FusedStep:
             self._jitted = jax.jit(step)
             self._jitted_donate = jax.jit(step, donate_argnums=(0, 2, 3, 4))
 
-        # K steps per dispatch: the classic TPU train-loop-under-scan.
-        # One host->device dispatch executes K full steps over K stacked
-        # batches, amortising the per-dispatch host/PJRT latency.
-        # lr/wd enter once per dispatch; the update count t advances in the
-        # scan carry so t-dependent optimizers (adam bias correction,
-        # schedules consumed via t) stay exact. Retraces automatically when
-        # K (the stacked leading dim) changes.
-        def k_step(params, static_rest, aux_vals, opt_state, met_state,
-                   feeds, lr_vec, wd_vec, rescale, t0, keys):
-            def body(carry, xs):
-                p, a, o, m, t = carry
-                feed, key = xs
-                outs, p2, a2, o2, m2 = step(p, {**static_rest, **feed},
-                                            a, o, m, lr_vec, wd_vec,
-                                            rescale, t, key)
-                return (p2, a2, o2, m2, t + jnp.int32(1)), outs
-
-            (p, a, o, m, _), outs = jax.lax.scan(
-                body, (params, aux_vals, opt_state, met_state,
-                       jnp.int32(t0)),
-                (feeds, keys))
-            return outs, p, a, o, m
-
-        if self._ddp_mesh is not None:
-            # the K-step in_specs depend on which args arrive stacked as
-            # feeds (run_k's split), so the shard_map is built lazily per
-            # feed-name set (stable across a fit run -> one jit cache hit)
-            self._k_fn = k_step
-            self._k_cache = {}
-            self._jitted_k = None
-        else:
-            self._jitted_k = jax.jit(k_step, donate_argnums=(0, 2, 3, 4))
-
     # -------------------------------------------------------------------- ddp
     def _ddp_spec(self, name):
         """Input spec for one executor arg: batch args shard over the dp
@@ -247,32 +214,6 @@ class FusedStep:
         out_specs = (P(self._ddp_axis), P(), P(), P(), P())
         return shard_map(step, mesh=self._ddp_mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-
-    def _ddp_jitted_k(self, feed_names):
-        """The K-step variant of :meth:`_ddp_shard`, cached per feed-name
-        set; feeds are stacked (K, batch, ...) so their batch axis is
-        dim 1 (spec ``P(None, dp)``)."""
-        key = frozenset(feed_names)
-        fn = self._k_cache.get(key)
-        if fn is None:
-            from jax import shard_map
-            from jax.sharding import PartitionSpec as P
-            ax = self._ddp_axis
-            pset = set(self.param_names)
-            static_spec = {k: self._ddp_spec(k) for k in self._exec.arg_dict
-                           if k not in pset and k not in key}
-            feed_spec = {k: (P(None, ax) if k in self._exec._batch_args
-                             else P()) for k in key}
-            in_specs = (P(), static_spec, P(), P(), P(), feed_spec,
-                        P(), P(), P(), P(), P())
-            out_specs = (P(None, ax), P(), P(), P(), P())
-            fn = jax.jit(
-                shard_map(self._k_fn, mesh=self._ddp_mesh,
-                          in_specs=in_specs, out_specs=out_specs,
-                          check_vma=False),
-                donate_argnums=(0, 2, 3, 4))
-            self._k_cache[key] = fn
-        return fn
 
     def _ddp_globalize(self, tree, spec):
         """Promote every leaf of ``tree`` to a global array on the dp mesh
@@ -421,87 +362,6 @@ class FusedStep:
         new_args = dict(rest)
         new_args.update(new_params)
         return outs, new_args, new_aux, new_opt, new_met
-
-    def stack_feeds(self, feeds):
-        """Cast + stack K per-step ``{input_name: jax value}`` feeds into
-        the ``(K, ...)`` device layout ``k_step`` scans over. Factored out
-        of :meth:`run_k` so the staged device feed
-        (mxnet_tpu/data/feed.py) can commit the NEXT window's buffer while
-        the current dispatch is still in flight; both paths run exactly
-        these ops in this order, so staged and unstaged windows are
-        bitwise-identical."""
-        ex = self._exec
-        cdt = self._compute_dtype
-        stacked = {}
-        for name in feeds[0]:
-            vals = [f[name] for f in feeds]
-            if cdt is not None and name in self._data_names \
-                    and vals[0].dtype == jnp.float32:
-                # the step would cast each slice anyway; casting before the
-                # stack halves the stacked buffer
-                vals = [v.astype(cdt) for v in vals]
-            arr = jnp.stack(vals)
-            if ex._mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec as P
-                spec = P(None, "dp") if name in ex._batch_args else P()
-                arr = jax.device_put(arr, NamedSharding(ex._mesh, spec))
-            stacked[name] = arr
-        return stacked
-
-    def run_k(self, arg_vals, aux_vals, opt_state, feeds, keys,
-              met_state=None):
-        """K fused steps in ONE XLA program (`lax.scan` over stacked
-        batches) — see ``k_step`` in :meth:`_build`.
-
-        ``feeds`` is a list of K ``{input_name: jax value}`` dicts (the
-        per-step data/label feeds), or ONE already-stacked
-        ``{input_name: (K, ...) array}`` dict from :meth:`stack_feeds`
-        (the staged device feed pre-commits it so dispatch never waits on
-        the H2D); ``keys`` a list of K PRNG keys. The param/aux/opt-state
-        (and metric-carry) buffers are DONATED; the caller must commit
-        the returned values immediately. Returns
-        ``(outs, new_params, new_aux, new_opt, new_met)`` where each
-        element of ``outs`` is stacked ``(K, ...)`` so callers can still
-        update metrics per sub-batch.
-
-        lr/wd are evaluated once per dispatch (a schedule moves in steps of
-        K); the optimizer update count still advances per inner step.
-        """
-        lr_vec, wd_vec, rescale, t = self.hyper_peek()
-        params, rest = self.split_args(arg_vals)
-        if isinstance(feeds, dict):
-            stacked = feeds
-        else:
-            stacked = self.stack_feeds(feeds)
-        feed_names = frozenset(stacked)
-        static_rest = {k: v for k, v in rest.items() if k not in feed_names}
-        ex = self._exec
-        if self._ddp_mesh is not None:
-            from jax.sharding import PartitionSpec as P
-            from ..parallel import ddp as _ddp
-            mesh, ax = self._ddp_mesh, self._ddp_axis
-            params = self._ddp_globalize(params, P())
-            aux_vals = self._ddp_globalize(aux_vals, P())
-            opt_state = self._ddp_globalize(opt_state, P())
-            static_rest = {k: _ddp.to_global(v, mesh, self._ddp_spec(k))
-                           for k, v in static_rest.items()}
-            stacked = {k: _ddp.to_global(
-                           v, mesh,
-                           P(None, ax) if k in ex._batch_args else P())
-                       for k, v in stacked.items()}
-            kk = _ddp.to_global(jnp.stack(list(keys)), mesh, P())
-            outs, new_params, new_aux, new_opt, new_met = \
-                self._ddp_jitted_k(stacked)(
-                    params, static_rest, aux_vals, opt_state, met_state,
-                    stacked, lr_vec, wd_vec, rescale, t, kk)
-            outs = jax.tree_util.tree_map(
-                lambda o: _ddp.from_global(o, mesh, P(None, ax)), outs)
-            return outs, new_params, new_aux, new_opt, new_met
-        outs, new_params, new_aux, new_opt, new_met = self._jitted_k(
-            params, static_rest, aux_vals, opt_state, met_state, stacked,
-            jnp.asarray(lr_vec), jnp.asarray(wd_vec), rescale, t,
-            jnp.stack(list(keys)))
-        return outs, new_params, new_aux, new_opt, new_met
 
     def lower(self, arg_vals, aux_vals, opt_state, met_state=None,
               donate=False):

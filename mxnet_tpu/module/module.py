@@ -444,11 +444,9 @@ class Module(BaseModule):
             self.update()
 
     def _commit_fused(self, last_outs, new_params, new_aux, new_opt,
-                      n_steps=1, new_met=None):
+                      new_met=None):
         """Commit a donating fused dispatch: the input buffers are dead, so
-        params/aux/opt-state/outputs must all be adopted now. Shared by the
-        per-step and grouped (run_k) paths — the commit protocol must stay
-        identical."""
+        params/aux/opt-state/outputs must all be adopted now."""
         from ..ndarray.ndarray import NDArray
         ex = self._exec
         for k, v in new_aux.items():
@@ -458,8 +456,7 @@ class Module(BaseModule):
         ex.outputs = [NDArray(o, ctx=ex._ctx) for o in last_outs]
         ex._pending = None
         self._fused_opt_state = new_opt
-        for _ in range(n_steps):
-            self._fused.commit_counts()
+        self._fused.commit_counts()
         self._params_dirty = True
         self._fused_pending = None
         self._fused_ran = False
@@ -478,104 +475,6 @@ class Module(BaseModule):
             donate=True, met_state=self._fused_met_state)
         self._commit_fused(outs, new_args, new_aux, new_opt,
                            new_met=new_met)
-
-    def _fit_group(self, data_batches, eval_metric=None, staged=None):
-        """fit's grouped entry (``steps_per_dispatch``): run the batches
-        through :meth:`_fit_step_k`, then update ``eval_metric`` once per
-        sub-batch from the stacked per-step outputs — metric semantics
-        identical to the per-step loop. ``staged`` is an optional
-        pre-built device feed from :meth:`_stage_group` (the zero-stall
-        staged K-step feed, mxnet_tpu/data/feed.py)."""
-        if self._fused is None or not self.optimizer_initialized \
-                or len(data_batches) == 1:
-            if len(data_batches) > 1 and \
-                    not getattr(self, "_warned_group_fallback", False):
-                self._warned_group_fallback = True
-                self.logger.warning(
-                    "steps_per_dispatch: fused step not engaged "
-                    "(optimizer/kvstore/grad_req unfusable?) — falling "
-                    "back to one dispatch per batch")
-            for b in data_batches:
-                self._fit_step(b)
-                if eval_metric is not None:
-                    self.update_metric(eval_metric, b.label)
-            return
-        from ..ndarray.ndarray import NDArray
-        outs = self._fit_step_k(data_batches, staged=staged)
-        if getattr(eval_metric, "_device_resident", False):
-            return  # accumulated inside the scan body; nothing to replay
-        if eval_metric is not None:
-            ex = self._exec
-            last = ex.outputs
-            for i, b in enumerate(data_batches):
-                ex.outputs = [NDArray(o[i], ctx=ex._ctx) for o in outs]
-                self.update_metric(eval_metric, b.label)
-            ex.outputs = last
-
-    def _stage_group(self, data_batches, step=None):
-        """Stage one K-step window's device feed ahead of dispatch (the
-        ``stage_fn`` hook of :class:`mxnet_tpu.data.feed.StagedKFeed`).
-        Runs on the feeder thread while the previous window is still in
-        flight, or on the fit thread inside the dispatch when nothing
-        staged the window: per-batch cast (+ placement) then the
-        cast/stack/commit of ``stack_feeds``, the same ops in the same
-        order either way, so staged and unstaged windows are
-        bitwise-identical. Batches that are not yet where the executor
-        computes make the window's host-to-device copy: it runs under ONE
-        ``mx/feed/h2d`` span carrying the window's ``step`` and the bytes
-        copied. Returns the payload: the stacked scan feed and the
-        pre-cast last feed for the executor rebind. Only reads executor
-        metadata (dtypes/sharding) — thread-safe against the main loop,
-        which only commits donated outputs."""
-        import contextlib
-        from .. import profiler as _profiler
-        from .. import telemetry as _telemetry
-        ex = self._exec
-        place_each = ex._mesh is None
-        items = [self._feed(b) for b in data_batches]
-        copied = {name for it in items for name, v in it.items()
-                  if not ex._is_placed(v, name)}
-        sp = _profiler.span("mx/feed/h2d", step=step) if copied else None
-        with sp or contextlib.nullcontext():
-            feeds = [{name: ex._place_input(ex._cast_input(name, v), name)
-                      if place_each else ex._cast_input(name, v)
-                      for name, v in it.items()} for it in items]
-            stacked = self._fused.stack_feeds(feeds)
-            if sp is not None:
-                # per slice on one device; under a mesh the stacked (and
-                # already down-cast) buffer is what crosses
-                sp.add(bytes=_telemetry.count_h2d(sum(
-                    sum(f[n].nbytes for f in feeds) if place_each
-                    else stacked[n].nbytes for n in copied)))
-        return {"stacked": stacked, "last": feeds[-1]}
-
-    def _fit_step_k(self, data_batches, staged=None):
-        """K fit steps in ONE donating XLA dispatch (`FusedStep.run_k` —
-        the train-loop-under-scan TPU idiom). Caller (:meth:`_fit_group`)
-        guarantees the fused step is engaged and K > 1. Returns the
-        stacked per-step output values (list of ``(K, ...)`` jax arrays)
-        so the fit loop can update metrics per sub-batch. ``staged`` is
-        the window's feed from :meth:`_stage_group` on the feeder thread;
-        without it the window is staged here."""
-        assert self._fused is not None and self.optimizer_initialized \
-            and len(data_batches) > 1
-        from .. import random as _random
-        ex = self._exec
-        if staged is None:
-            staged = self._stage_group(data_batches)
-        # keep the executor's input bindings current (shape checks, later
-        # forward() calls): the pre-cast last feed, placed like any input
-        # (under a mesh the slices were left unplaced for the stack)
-        for name, val in staged["last"].items():
-            ex.arg_dict[name]._rebind(ex.prepare_input(name, val))
-        keys = [_random.next_key() for _ in data_batches]
-        outs, new_params, new_aux, new_opt, new_met = self._fused.run_k(
-            ex._arg_vals(), ex._aux_vals(), self._fused_opt_state,
-            staged["stacked"], keys, met_state=self._fused_met_state)
-        self._commit_fused([o[-1] for o in outs], new_params, new_aux,
-                           new_opt, n_steps=len(data_batches),
-                           new_met=new_met)
-        return outs
 
     # ------------------------------------------------- device-resident metric
     def _engage_device_metric(self, eval_metric):

@@ -14,8 +14,8 @@ The pieces (each its own module, all stdlib-only and import-light):
   labels for the fleet router's single ``/metrics`` scrape.
 
 The one entry point producers on the training path use is
-``publish_window``: called by ``Module.fit`` at K-step window
-boundaries with values it already holds on the host, so telemetry adds
+``publish_window``: called by ``Module.fit`` every 16 steps and at an
+epoch's end with values it already holds on the host, so telemetry adds
 **zero** device→host syncs to the step loop (pinned by
 tests/test_step_sync_budget.py). Serving, the kernel tier, checkpoint,
 and fault injection publish into the same registry from their own code.
@@ -96,8 +96,8 @@ def _stall_attribution(steps, window_s, stall_ms):
 
 def count_h2d(nbytes):
     """Book ``nbytes`` of input copied to the device, at the place where
-    the copy is made (``Executor.prepare_input``, ``Module._stage_group``):
-    the ``data/h2d_bytes`` counter. Returns ``nbytes``."""
+    the copy is made (``Executor.prepare_input``): the ``data/h2d_bytes``
+    counter. Returns ``nbytes``."""
     counter("data/h2d_bytes",
             "host->device input bytes copied for the step loop, counted "
             "where the copy is made (an input already on the executor's "
@@ -108,7 +108,7 @@ def count_h2d(nbytes):
 def publish_window(*, steps, window_s, examples=None, engine_depth=None,
                    global_step=None, source="train", ddp=None,
                    embed=None, data=None):
-    """Publish one K-step window's worth of training telemetry.
+    """Publish one window's worth of training telemetry (fit: 16 steps).
 
     Everything passed in (and everything read here) is already host
     memory: wall-clock seconds, host-side batch shapes, the in-flight
@@ -134,7 +134,7 @@ def publish_window(*, steps, window_s, examples=None, engine_depth=None,
     ``data`` (optional) is fit's host-held input-pipeline summary for
     the window — ``{"input_stall_ms", "queue_depth"}`` (stall = the
     window's ``mx/fit/next`` spans: wall-clock the loop spent blocked on
-    the iterator / staged feed; queue_depth from the feeder's bounded
+    the iterator; queue_depth from the iterator's bounded prefetch
     queue). Publishes ``data/*`` gauges plus the perfmodel-backed
     input-bound/compute-bound attribution (``data/stall_frac``,
     ``data/input_bound`` — docs/data.md). ``data/h2d_bytes`` is counted
@@ -155,7 +155,8 @@ def publish_window(*, steps, window_s, examples=None, engine_depth=None,
     gauge("train/step_time_ms",
           "mean wall-clock ms per step over the last window").set(step_ms)
     counter("train/steps_total", "optimizer steps dispatched").inc(steps)
-    gauge("train/window_steps", "steps per dispatch window (K)").set(steps)
+    gauge("train/window_steps",
+          "steps in the last telemetry window").set(steps)
     if examples is not None and examples > 0:
         gauge("train/examples_per_s",
               "training throughput over the last window").set(
@@ -217,7 +218,7 @@ def publish_window(*, steps, window_s, examples=None, engine_depth=None,
                       examples / window_s)
         if "queue_depth" in data:
             gauge("data/queue_depth",
-                  "prefetch/staged-feed queue occupancy at window end "
+                  "prefetch queue occupancy at window end "
                   "(0 with stalls = producer-bound)").set(
                       data.get("queue_depth", 0))
         if data.get("h2d_bytes"):

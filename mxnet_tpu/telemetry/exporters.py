@@ -8,7 +8,7 @@ Two ways out of the process for the registry's numbers, both stdlib:
   ``/healthz``. This is the *training-side* scrape point; serving
   replicas already have an HTTP front end, so ``serve/http.py`` grows
   the same exposition on its existing ``/metrics`` route instead.
-* ``JsonlWriter`` — appends one registry snapshot per K-step window to
+* ``JsonlWriter`` — appends one registry snapshot per 16-step window to
   a JSONL file next to the chrome trace (``MXNET_TELEMETRY_JSONL``, or
   ``$MXNET_TELEMETRY_DIR/telemetry.jsonl``), giving post-hoc tooling a
   step-time/MFU/engine-depth time series without a scraper running.
@@ -137,7 +137,7 @@ def jsonl_path():
 class JsonlWriter:
     """Append-per-window snapshot stream. Opens/closes per write so the
     stream survives forks and supervised restarts without stale handles;
-    at K-step cadence the syscall cost is noise."""
+    at one write per 16 steps the syscall cost is noise."""
 
     def __init__(self, path):
         self.path = path

@@ -11,7 +11,7 @@ Design constraints, in order:
    under a per-metric lock; nothing here may touch a device array or
    trigger a d2h transfer. Producers are responsible for only publishing
    values they already hold on the host (the training loop samples at
-   K-step window boundaries for exactly this reason — see
+   16-step window boundaries for exactly this reason — see
    ``telemetry.publish_window`` and tests/test_step_sync_budget.py).
 2. **Thread-safe.** Serve worker threads, the micro-batcher, the
    checkpoint save thread, and the training loop all publish

@@ -62,7 +62,8 @@ def trained():
     # the rate goes down with the batch: at batch 8 the real size's 0.05
     # spikes on one init seed in three, whatever the tree
     return chip_smoke.phase_train(ctx=mx.tpu(), num_layers=18, classes=10,
-                                  side=32, batch=8, k=2, ref_batch=4,
+                                  side=32, batch=8, steps_per_epoch=2,
+                                  ref_batch=4,
                                   lr=0.005)
 
 

@@ -43,6 +43,18 @@ def test_unknown_flag_raises():
         config.flags.nope
 
 
+def test_the_scans_three_flags_are_gone_from_the_code_and_the_docs():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    docs = "".join(open(os.path.join(root, "docs", f)).read()
+                   for f in ("data.md", "perf.md"))
+    for name, env in (("steps_per_dispatch", "MXNET_STEPS_PER_DISPATCH"),
+                      ("data_staged_feed", "MXNET_DATA_STAGED_FEED"),
+                      ("data_feed_depth", "MXNET_DATA_FEED_DEPTH")):
+        with pytest.raises(AttributeError):
+            getattr(config.flags, name)
+        assert env not in docs, env
+
+
 def test_enforce_determinism_blocks_autoseed():
     code = (
         "import jax\n"

@@ -49,16 +49,20 @@ def _batch(ctx, rows=BATCH, seed=0):
         label=[mx.nd.array(rng.randint(0, 4, rows).astype("f4"), ctx=ctx)])
 
 
-def _fit(it, k, **kw):
+def _fit_module(it, eval_metric=None, **kw):
     sym = mx.sym.SoftmaxOutput(
         mx.sym.FullyConnected(mx.sym.Variable("data"), num_hidden=4),
         name="softmax")
     mod = mx.mod.Module(sym, context=DEV)
     profiler.reset_spans()
-    mod.fit(it, num_epoch=1, kvstore="tpu_sync", eval_metric=None,
-            initializer=mx.initializer.Xavier(), steps_per_dispatch=k, **kw)
+    mod.fit(it, num_epoch=1, kvstore="tpu_sync", eval_metric=eval_metric,
+            initializer=mx.initializer.Xavier(), **kw)
     assert mod._fused is not None
-    return profiler.spans()
+    return mod, profiler.spans()
+
+
+def _fit(it, **kw):
+    return _fit_module(it, **kw)[1]
 
 
 def _h2d_counter():
@@ -79,12 +83,11 @@ def _assert_children_inside_parents(spans):
                    for p in spans), s
 
 
-@pytest.mark.parametrize("k", [1, 4])
-def test_one_dispatch_span_a_dispatch_with_consecutive_steps(k):
-    spans = _fit(_Iter([_batch(mx.cpu(), seed=i) for i in range(3)]), k)
+def test_one_dispatch_span_a_dispatch_with_consecutive_steps():
+    spans = _fit(_Iter([_batch(mx.cpu(), seed=i) for i in range(3)]))
     disp = _named(spans, "mx/fit/dispatch")
-    assert [s.step for s in disp] == list(range(0, STEPS, k))
-    assert all(s.counts == {"steps": k} for s in disp)
+    assert [s.step for s in disp] == list(range(STEPS))
+    assert all(s.counts == {"steps": 1} for s in disp)
     assert all(s.parent == "mx/fit/epoch" for s in disp)
     epoch, = _named(spans, "mx/fit/epoch")
     assert epoch.counts == {"epoch": 0} and epoch.parent is None
@@ -93,7 +96,7 @@ def test_one_dispatch_span_a_dispatch_with_consecutive_steps(k):
                  "mx/fit/quiesce"):
         assert len(_named(spans, name)) == 1, name
     # every batch was asked for under mx/fit/next, and the end of data too
-    assert len(_named(spans, "mx/fit/next")) == STEPS // k + 1
+    assert len(_named(spans, "mx/fit/next")) == STEPS + 1
     assert all(s.end_ns >= s.start_ns for s in spans)
     _assert_children_inside_parents(spans)
     # the totals agree with the ring, and self time is what no child covers
@@ -102,14 +105,35 @@ def test_one_dispatch_span_a_dispatch_with_consecutive_steps(k):
     assert count == len(disp)
     assert total_ns == sum(s.end_ns - s.start_ns for s in disp)
     assert 0 <= self_ns <= total_ns
-    if k == 1:
-        assert self_ns == total_ns - sum(
-            s.end_ns - s.start_ns for s in _named(spans, "mx/feed/h2d"))
+    assert self_ns == total_ns - sum(
+        s.end_ns - s.start_ns for s in _named(spans, "mx/feed/h2d"))
+
+
+@pytest.mark.parametrize("metric", [None, "acc"])
+def test_default_fit_is_one_step_a_program_and_starts_no_thread(
+        metric, monkeypatch):
+    """Nothing watches the host (no callback, monitor, scheduler or
+    checkpoint; the metric absent or folded into the step): ``fit`` still
+    dispatches one fused step a program, from its own thread."""
+    started, start = [], threading.Thread.start
+    monkeypatch.setattr(threading.Thread, "start",
+                        lambda self: (started.append(self), start(self)))
+    steps = 20          # more than one 16-step telemetry window
+    mod, spans = _fit_module(_Iter([_batch(mx.cpu())], steps=steps),
+                             eval_metric=metric)
+    assert (mod._device_plan is not None) == (metric is not None)
+    disp = _named(spans, "mx/fit/dispatch")
+    assert [s.step for s in disp] == list(range(steps))
+    assert all(s.counts == {"steps": 1} for s in disp)
+    assert mod._fused._jitted_donate._cache_size() == 1
+    assert started == []
+    assert {s.tid for s in spans} == {threading.get_ident()}
+    assert [s.step for s in _named(spans, "mx/fit/publish")] == [16, steps]
 
 
 def test_h2d_span_carries_the_bytes_of_host_batches_per_step():
     before = _h2d_counter()
-    spans = _fit(_Iter([_batch(mx.cpu())]), 1)
+    spans = _fit(_Iter([_batch(mx.cpu())]))
     h2d = _named(spans, "mx/feed/h2d")
     data_bytes, label_bytes = BATCH * FEAT * 4, BATCH * 4
     # data and label of every step, under that step's dispatch
@@ -121,29 +145,11 @@ def test_h2d_span_carries_the_bytes_of_host_batches_per_step():
     assert _h2d_counter() - before == STEPS * (data_bytes + label_bytes)
 
 
-@pytest.mark.parametrize("k", [1, 4])
-def test_no_h2d_span_and_no_bytes_for_resident_batches(k):
+def test_no_h2d_span_and_no_bytes_for_resident_batches():
     before = _h2d_counter()
-    spans = _fit(_Iter([_batch(DEV)]), k)
+    spans = _fit(_Iter([_batch(DEV)]))
     assert _named(spans, "mx/feed/h2d") == []
     assert _h2d_counter() == before      # the repaired counter
-
-
-def test_staged_feed_h2d_is_on_the_feeder_thread_with_the_windows_step():
-    before = _h2d_counter()
-    k = 4
-    spans = _fit(_Iter([_batch(mx.cpu(), seed=i) for i in range(3)]), k)
-    h2d = _named(spans, "mx/feed/h2d")
-    assert [s.step for s in h2d] == [0, 4]           # one a window
-    fit_tid = _named(spans, "mx/fit/epoch")[0].tid
-    assert all(s.tid != fit_tid and s.parent is None for s in h2d)
-    assert len({s.tid for s in h2d}) == 1
-    window_bytes = k * (BATCH * FEAT * 4 + BATCH * 4)
-    assert [s.counts["bytes"] for s in h2d] == [window_bytes] * 2
-    assert _h2d_counter() - before == 2 * window_bytes
-    # the dispatch of a staged window copies nothing itself
-    disp = _named(spans, "mx/fit/dispatch")
-    assert [s.step for s in disp] == [0, 4]
 
 
 def test_profiler_on_adds_no_blocking_wait_to_fit(monkeypatch, tmp_path):
@@ -155,7 +161,7 @@ def test_profiler_on_adds_no_blocking_wait_to_fit(monkeypatch, tmp_path):
 
     def run():
         profiler.reset_sync_counters()
-        spans = _fit(_Iter([_batch(DEV)]), 1)
+        spans = _fit(_Iter([_batch(DEV)]))
         return profiler.sync_counters(), sorted(
             s.name for s in spans if s.name != "mx/compile")
 
@@ -183,7 +189,7 @@ def test_a_new_shape_mid_epoch_leaves_a_compile_span_with_its_step():
     odd = 5
     batches = [_batch(DEV)] * odd + [_batch(DEV, rows=BATCH // 2)] \
         + [_batch(DEV)] * (STEPS - odd - 1)
-    spans = _fit(_Iter(batches), 1)
+    spans = _fit(_Iter(batches))
     count = reg.get("compile/count").value()
     assert count >= 2 and reg.get("compile/seconds").value() > 0
     backend = [s for s in _named(spans, "mx/compile")
@@ -231,7 +237,7 @@ def test_step_is_inherited_and_open_self_time_is_live():
 
 
 def test_publish_window_republishes_the_span_totals():
-    _fit(_Iter([_batch(mx.cpu())]), 1)
+    _fit(_Iter([_batch(mx.cpu())]))
     reg = telemetry.default_registry()
     telemetry.publish_window(steps=1, window_s=0.1)
     totals = profiler.span_totals()
@@ -270,8 +276,7 @@ def test_mesh_executor_copies_only_what_is_not_under_its_sharding(resident):
     before = _h2d_counter()
     profiler.reset_spans()
     mod.fit(_Iter([batch]), num_epoch=1, kvstore="tpu_sync",
-            eval_metric=None, initializer=mx.initializer.Xavier(),
-            steps_per_dispatch=1)
+            eval_metric=None, initializer=mx.initializer.Xavier())
     assert mod._fused is not None and mod._exec._mesh is not None
     h2d = _named(profiler.spans(), "mx/feed/h2d")
     if resident:

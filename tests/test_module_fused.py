@@ -218,84 +218,41 @@ def test_unfusable_optimizer_falls_back():
     assert mod._fused is None  # Nadam updates via NDArray math on host
 
 
-# ------------------------------------------- steps_per_dispatch (run_k/scan)
-def _fit_grouped(k, opt="sgd", opt_params=None, n=64, num_epoch=3,
-                 eval_metric="acc", record_cb=False):
+# ------------------------------------------------- the fit loop's contract
+def _fit_callbacks(n, num_epoch, eval_metric):
     sym = _make_net()
     X, Y = _data(n)
     it = mx.io.NDArrayIter(X, Y, batch_size=16, label_name="softmax_label")
     mod = mx.mod.Module(sym)
     calls = []
-    cb = (lambda p: calls.append(p.nbatch)) if record_cb else None
-    mod.fit(it, num_epoch=num_epoch, kvstore="tpu_sync", optimizer=opt,
-            optimizer_params=opt_params or {"learning_rate": 0.1,
-                                            "momentum": 0.9},
-            arg_params={k_: v.copy() for k_, v in _fixed_params(sym).items()},
+    mod.fit(it, num_epoch=num_epoch, kvstore="tpu_sync", optimizer="sgd",
+            optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
+            arg_params={k: v.copy() for k, v in _fixed_params(sym).items()},
             initializer=None, eval_metric=eval_metric,
-            steps_per_dispatch=k, batch_end_callback=cb)
+            batch_end_callback=lambda p: calls.append((p.epoch, p.nbatch)))
     return mod, calls
 
 
-def test_grouped_dispatch_matches_per_step():
-    """K=4 divides the 4 batches/epoch exactly: the whole epoch is one
-    scan dispatch. Params+aux (BN stats ride the scan carry) must match
-    the per-step fused path."""
-    per = _fit("tpu_sync", "sgd", {"learning_rate": 0.1, "momentum": 0.9})
-    grp, _ = _fit_grouped(4)
-    assert grp._fused is not None
-    _assert_params_close(per, grp)
+def test_callbacks_once_a_batch_and_host_metric_equals_device_folded():
+    """n=288 -> 18 batches an epoch, no multiple of fit's 16-step telemetry
+    window. Callbacks fire once a batch, in order; the metric folded into
+    the step reads what the host metric reads from the same steps."""
+    from mxnet_tpu import config
+    m_dev = mx.metric.create("acc")
+    dev, calls = _fit_callbacks(288, 2, m_dev)
+    assert dev._fused is not None and dev._device_plan is not None
+    assert calls == [(e, b) for e in range(2) for b in range(18)]
+    m_host = mx.metric.create("acc")
+    with config.override(device_metrics=False):
+        host, host_calls = _fit_callbacks(288, 2, m_host)
+    assert host._device_plan is None and host_calls == calls
+    _assert_params_close(host, dev)
+    np.testing.assert_allclose(m_host.get()[1], m_dev.get()[1], atol=1e-6)
 
 
-def test_grouped_dispatch_tail_metric_callbacks():
-    """n=80 -> 5 batches/epoch, K=2 -> two groups + a 1-batch tail (which
-    takes the per-step program rather than tracing a second scan variant
-    for the odd size). Callbacks fire once per batch; the metric
-    accumulates per sub-batch, equal to per-step."""
-    m_grp = mx.metric.create("acc")
-    grp, calls = _fit_grouped(2, n=80, num_epoch=2, eval_metric=m_grp,
-                              record_cb=True)
-    assert calls == list(range(5)) * 2
-    m_per = mx.metric.create("acc")
-    sym = _make_net()
-    X, Y = _data(80)
-    it = mx.io.NDArrayIter(X, Y, batch_size=16, label_name="softmax_label")
-    per = mx.mod.Module(sym)
-    per.fit(it, num_epoch=2, kvstore="tpu_sync", optimizer="sgd",
-            optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
-            arg_params={k: v.copy() for k, v in _fixed_params(sym).items()},
-            initializer=None, eval_metric=m_per)
-    _assert_params_close(per, grp)
-    np.testing.assert_allclose(m_per.get()[1], m_grp.get()[1], atol=1e-6)
-
-
-def test_grouped_adam_update_count_advances_in_scan():
-    """Adam's bias correction depends on t: if the in-scan update count
-    failed to advance, step 2..K would reuse t=1 and diverge fast."""
-    per = _fit("tpu_sync", "adam", {"learning_rate": 0.01}, num_epoch=1)
-    grp, _ = _fit_grouped(4, opt="adam",
-                          opt_params={"learning_rate": 0.01}, num_epoch=1)
-    _assert_params_close(per, grp, rtol=2e-4, atol=2e-6)
-
-
-def test_grouped_dispatch_spmd_matches_single_device():
-    """run_k's mesh branch: stacked feeds re-committed to P(None, 'dp'),
-    params/opt replicated — numerics equal to the single-device run."""
-    sym = _make_net()
-    X, Y = _data(64)
-    it = mx.io.NDArrayIter(X, Y, batch_size=16, label_name="softmax_label")
-    mod = mx.mod.Module(sym, context=[mx.Context("cpu", i) for i in range(4)])
-    mod.fit(it, num_epoch=3, kvstore="tpu_sync", optimizer="sgd",
-            optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
-            arg_params={k: v.copy() for k, v in _fixed_params(sym).items()},
-            initializer=None, steps_per_dispatch=4)
-    assert mod._fused is not None
-    single = _fit("tpu_sync", "sgd", {"learning_rate": 0.1, "momentum": 0.9})
-    _assert_params_close(single, mod)
-
-
-def test_grouped_accepts_numpy_feeds():
-    """set_inputs accepts raw numpy feeds; the grouped path must too
-    (it routes every value through Executor.prepare_input)."""
+def test_fit_accepts_numpy_feeds():
+    """``DataBatch``es of raw numpy arrays through ``_fit_step``: set_inputs
+    routes every value through Executor.prepare_input."""
     from mxnet_tpu.io import DataBatch, DataDesc
     sym = _make_net()
     X, Y = _data(64)
@@ -316,8 +273,9 @@ def test_grouped_accepts_numpy_feeds():
     mod = mx.mod.Module(sym)
     mod.fit(It(), num_epoch=1, eval_metric=None, kvstore="tpu_sync",
             optimizer="sgd", arg_params=_fixed_params(sym),
-            initializer=None, steps_per_dispatch=2)
+            initializer=None)
     assert mod._fused is not None
+    assert mod._optimizer.num_update == 4
 
 
 def test_fused_with_backward_mirror_matches():
@@ -333,28 +291,31 @@ def test_fused_with_backward_mirror_matches():
     _assert_params_close(base, mirrored, rtol=1e-5, atol=1e-7)
 
 
-def test_grouped_rejects_bad_k():
+def test_fit_rejects_bad_k():
     sym = _make_net()
     X, Y = _data(16)
     it = mx.io.NDArrayIter(X, Y, batch_size=16, label_name="softmax_label")
     mod = mx.mod.Module(sym)
-    with pytest.raises(ValueError, match="steps_per_dispatch"):
+    with pytest.raises(ValueError, match="steps_per_dispatch must be >= 1"):
         mod.fit(it, num_epoch=1, steps_per_dispatch=0)
 
 
-def test_grouped_rejects_monitor():
+@pytest.mark.parametrize("with_monitor", [False, True])
+def test_fit_refuses_more_than_one_step_a_program(with_monitor):
     sym = _make_net()
     X, Y = _data(16)
     it = mx.io.NDArrayIter(X, Y, batch_size=16, label_name="softmax_label")
     mod = mx.mod.Module(sym)
-    mon = mx.monitor.Monitor(1)
-    with pytest.raises(ValueError, match="steps_per_dispatch"):
+    mon = mx.monitor.Monitor(1) if with_monitor else None
+    with pytest.raises(ValueError, match="steps_per_dispatch=2: the K-step "
+                       "scan is gone, fit dispatches one fused step"):
         mod.fit(it, num_epoch=1, kvstore="tpu_sync",
                 steps_per_dispatch=2, monitor=mon)
     # the raise fired before bind/install_monitor/init_optimizer: a retry
-    # without the monitor must still engage the fused path
+    # must still engage the fused path
+    assert not mod.binded
     it.reset()
-    mod.fit(it, num_epoch=1, kvstore="tpu_sync", steps_per_dispatch=2,
+    mod.fit(it, num_epoch=1, kvstore="tpu_sync", steps_per_dispatch=1,
             arg_params=_fixed_params(_make_net()), initializer=None)
     assert mod._fused is not None
 

@@ -369,47 +369,6 @@ class TestTelemetryDiscipline:
         assert _rules(good) == []
 
 
-class TestStagedFeedRule:
-    def test_device_put_in_step_loop_fires(self):
-        bad = (
-            "import jax\n"
-            "def train(batches, step):\n"
-            "    for b in batches:\n"
-            "        x = jax.device_put(b)\n"
-            "        step(x)\n")
-        assert "MXL513" in _rules(bad)
-
-    def test_nd_array_feed_in_fit_loop_fires(self):
-        bad = (
-            "from mxnet_tpu.ndarray import ndarray as _nd\n"
-            "def train(mod, arrays):\n"
-            "    for a in arrays:\n"
-            "        batch = _nd.array(a)\n"
-            "        mod._fit_step(batch)\n")
-        assert "MXL513" in _rules(bad)
-
-    def test_feed_without_step_dispatch_passes(self):
-        # fused.stack_feeds' shape: per-name device_put in a loop with no
-        # step dispatch is staging, not a hand-rolled train loop
-        good = (
-            "import jax\n"
-            "def stage(feeds):\n"
-            "    out = {}\n"
-            "    for name in feeds:\n"
-            "        out[name] = jax.device_put(feeds[name])\n"
-            "    return out\n")
-        assert "MXL513" not in _rules(good)
-
-    def test_staged_loop_passes(self):
-        # consuming pre-staged windows: no per-batch feed in the loop
-        good = (
-            "def train(feed, mod):\n"
-            "    while True:\n"
-            "        win = feed.next_window()\n"
-            "        mod._fit_step(win)\n")
-        assert "MXL513" not in _rules(good)
-
-
 # ---------------------------------------------------------------- layer 3
 
 class TestUnguardedSharedWrite:

@@ -3,7 +3,7 @@
 The async-loop contract (docs/perf.md "Async fit loop"): the benched
 ResNet-50 ``Module.fit`` inner loop, with a supported metric folded into
 the device step, performs at most ONE involuntary device->host transfer
-per K-step dispatch window — the metric publish at the epoch/display
+per 16-step telemetry window — the metric publish at the epoch/display
 boundary. Every other read stays on device; the profiler's sync counters
 (``profiler.record_host_sync``) are the evidence.
 
@@ -14,9 +14,7 @@ pays a host metric update with its own d2h — from the same initial params
 must produce bitwise-identical metric values at the epoch boundary:
 engine depth changes only WHEN the host waits, never what the device
 computes, and the host metric consumes the same output bits the device
-carry consumed. (Dispatch granularity — scan vs per-step programs — is a
-separate pre-existing dimension with its own allclose-level parity tests
-in test_module_fused.py; it is held fixed here.)
+carry consumed.
 
 Runs on CPU (tier-1): resnet_symbol is shape-agnostic until bind
 (global_pool), so a 64x64 bind keeps the 50-layer program CPU-feasible
@@ -31,12 +29,11 @@ import mxnet_tpu as mx
 from mxnet_tpu import profiler
 from mxnet_tpu import telemetry
 from mxnet_tpu import config as _config
-from mxnet_tpu.config import flags
 from mxnet_tpu.io import DataBatch, DataDesc
 
 BATCH = 4
 SIDE = 64
-K = flags.steps_per_dispatch  # default 16; the budget window (>= 10)
+K = 16  # fit's telemetry window: the budget window
 N_CLASSES = 100
 
 _logger = logging.getLogger("sync_budget_test")
@@ -98,7 +95,6 @@ def _fit(mod, it, metric, **kw):
             **kw)
 
 
-@pytest.mark.skipif(K < 10, reason="budget window needs K >= 10")
 def test_resnet50_fit_syncs_at_most_once_per_k_steps():
     it = _make_iter()
     mod = _make_module(it)
@@ -108,9 +104,9 @@ def test_resnet50_fit_syncs_at_most_once_per_k_steps():
     arg0 = {k: mx.nd.array(v.asnumpy()) for k, v in arg0.items()}
     aux0 = {k: mx.nd.array(v.asnumpy()) for k, v in aux0.items()}
 
-    # the epoch has exactly K batches, so fit's default (auto) dispatch
-    # runs them as ONE K-step scan; counters cover the whole fit inner
-    # loop including the epoch-end metric read
+    # the epoch has exactly K batches, one telemetry window of K fused
+    # steps; counters cover the whole fit inner loop including the
+    # epoch-end metric read
     m_async = mx.metric.create("acc")
     profiler.reset_sync_counters()
     _fit(mod, it, m_async)
@@ -119,7 +115,7 @@ def test_resnet50_fit_syncs_at_most_once_per_k_steps():
     assert mod._fused is not None, "fused step must engage (tpu_sync)"
     assert mod._device_plan is not None, \
         "accuracy must fold into the device step"
-    # the budget: <= 1 involuntary d2h for the whole K-step window. The
+    # the budget: <= 1 involuntary d2h for the whole 16-step window. The
     # single allowed transfer is the epoch-end metric publish (a few
     # bytes); compile/dispatch/feed never move device data to host.
     # Telemetry is ON (registry default-enabled, no flag) for this run,
@@ -129,7 +125,7 @@ def test_resnet50_fit_syncs_at_most_once_per_k_steps():
     assert counters["d2h_bytes"] <= 64, counters
 
     # ...and the windows really were published from host-held values:
-    # the K-batch epoch is one dispatch window, so every train/ series
+    # the K-batch epoch is one telemetry window, so every train/ series
     # carries the whole epoch
     reg = telemetry.default_registry()
     assert reg.get("train/step_time_ms").value() > 0
@@ -146,16 +142,15 @@ def test_resnet50_fit_syncs_at_most_once_per_k_steps():
     # host metric, so the caller's own metric object reads normally
     acc_async = dict(m_async.get_name_value())
 
-    # ---- per-step-sync baseline: same dispatch granularity (one K-step
-    # scan), but lockstep depth and the reference host metric path — the
-    # K stacked outputs are replayed through EvalMetric.update_dict one
-    # sub-batch at a time, each paying its own d2h ----
+    # ---- per-step-sync baseline: the same steps at lockstep depth and
+    # on the reference host metric path — every batch's outputs go
+    # through EvalMetric.update_dict, each paying its own d2h ----
     it.reset()
     base = _make_module(it, arg_params=arg0, aux_params=aux0)
     m_sync = mx.metric.create("acc")
     with _config.override(engine_depth=1, device_metrics=False):
         profiler.reset_sync_counters()
-        _fit(base, it, m_sync, steps_per_dispatch=K)
+        _fit(base, it, m_sync)
         sync_counters = profiler.sync_counters()
 
     assert base._device_plan is None  # host path, as intended
@@ -163,9 +158,9 @@ def test_resnet50_fit_syncs_at_most_once_per_k_steps():
     assert sync_counters["d2h"] >= K, sync_counters
     acc_sync = dict(m_sync.get_name_value())
 
-    # same initial params, same batches, same program granularity: the
-    # epoch accuracy must agree bitwise (integer hit-counts over 64
-    # samples; depth and metric residency change no device math)
+    # same initial params, same batches: the epoch accuracy must agree
+    # bitwise (integer hit-counts over 64 samples; depth and metric
+    # residency change no device math)
     assert acc_async == acc_sync, (acc_async, acc_sync)
 
 
@@ -283,15 +278,14 @@ def _stream_iter(recs):
                              batch_size=BATCH)
 
 
-@pytest.mark.skipif(K < 10, reason="budget window needs K >= 10")
 def test_streaming_fit_same_budget_and_bitwise_vs_in_memory(tmp_path):
     """The tentpole contract end to end: the benched ResNet-50 fit fed by
-    the STREAMING tier (sharded stream -> parallel decode -> StagedKFeed
-    pre-stacking each K-window off-thread) keeps the <=1-d2h-per-window
-    budget AND lands bitwise-identical params + metric to the same fit
-    fed from memory (NDArrayIter over the same rows in the same order) —
-    the staging machinery moves work off the critical path without
-    touching a single bit of the math."""
+    the STREAMING tier (sharded stream -> parallel decode -> prefetch
+    queue) keeps the <=1-d2h-per-window budget AND lands
+    bitwise-identical params + metric to the same fit fed from memory
+    (NDArrayIter over the same rows in the same order) — the feed moves
+    work off the critical path without touching a single bit of the
+    math."""
     recs = _pack_resnet_records(tmp_path, K * BATCH)
 
     # twin iterator captures the epoch-0 delivered order for the
@@ -313,7 +307,6 @@ def test_streaming_fit_same_budget_and_bitwise_vs_in_memory(tmp_path):
         arg0 = {k: mx.nd.array(v.asnumpy()) for k, v in arg0.items()}
         aux0 = {k: mx.nd.array(v.asnumpy()) for k, v in aux0.items()}
 
-        assert flags.data_staged_feed  # default-on staged K-step feed
         m_stream = mx.metric.create("acc")
         h2d = telemetry.default_registry().get("data/h2d_bytes")
         h2d_before = h2d.value() if h2d is not None else 0
@@ -343,7 +336,7 @@ def test_streaming_fit_same_budget_and_bitwise_vs_in_memory(tmp_path):
                                 label_name="softmax_label")
     base = _make_module(base_it, arg_params=arg0, aux_params=aux0)
     m_base = mx.metric.create("acc")
-    _fit(base, base_it, m_base, steps_per_dispatch=K)
+    _fit(base, base_it, m_base)
 
     assert dict(m_stream.get_name_value()) == dict(m_base.get_name_value())
     arg_s, aux_s = mod.get_params()
