@@ -71,7 +71,11 @@ def check(ok, what):
 
 
 def _rel_err(got, ref):
+    """max|got - ref| / max|ref|; of several arrays (a tuple each), the
+    worst: each is held to its own scale."""
     import numpy as np
+    if isinstance(ref, (tuple, list)):
+        return max(_rel_err(g, r) for g, r in zip(got, ref))
     got = np.asarray(got, np.float32)
     ref = np.asarray(ref, np.float32)
     check(np.isfinite(got).all(), "non-finite values in a result")
@@ -382,7 +386,8 @@ class KernelCase(NamedTuple):
     """One Pallas kernel at a width its users run. ``fn`` calls it with
     ``interpret=False``; ``args`` are (shape, dtype, fill) triples, see
     :func:`make_args`; ``ref`` is pure JAX over the same arrays; ``tol``
-    bounds max|out - ref| / max|ref|."""
+    bounds max|out - ref| / max|ref|, of every array where they return a
+    tuple."""
     name: str
     fn: object
     args: tuple
@@ -431,13 +436,29 @@ def _scale_bias_act_reference(x, scale, bias, act):
     return y.astype(x.dtype)
 
 
+def _kda_tiles(q, k, v, g, beta):
+    """Normals as a KDA core's inputs: q and k of unit length (q over
+    sqrt(d) besides), a log decay a token from -0.001 to -6, beta within
+    (0.05, 0.95)."""
+    import jax
+    import jax.numpy as jnp
+
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True))
+    cdf = jax.scipy.stats.norm.cdf
+    return (unit(q) * q.shape[-1] ** -0.5, unit(k), v,
+            -jnp.exp(jnp.log(1e-3) + cdf(g) * jnp.log(6e3)),
+            0.05 + 0.9 * cdf(beta))
+
+
 def kernel_cases():
     """Every Pallas kernel left in the tree, at real widths. Touches no
     device: tests/test_tpu_aot_compile.py compiles the same table for a
     described chip."""
+    import jax
     import jax.numpy as jnp
     from mxnet_tpu.kernels import attention, bn_act, int8_dequant, mlp, take
-    from mxnet_tpu.ops import pallas_flash
+    from mxnet_tpu.ops import lm_ops, pallas_flash, pallas_kda
     f32, bf16, i32 = jnp.float32, jnp.bfloat16, jnp.int32
     # bf16 carries 8 bits: 2^-8 per rounding, a few roundings per result.
     # f32 kernels are held to bf16's bound too where they contain matmuls:
@@ -553,6 +574,35 @@ def kernel_cases():
                 lambda x, s, b, act=act: _scale_bias_act_reference(
                     x, s, b, act),
                 2e-2 if dt == bf16 else 1e-5)
+
+    # the work inside the chunks of a KDA core: the six values the scan
+    # consumes, and the five gradients from their cotangents. Kimi-Linear's
+    # tile (a group of 16 chunks of 64 tokens over 32 heads of 128), then
+    # the corners of what the kernels take (``pallas_kda.eligible``): the
+    # widest head with the longest chunk, and eight short chunks a tile.
+    # float32 with fp32-contract products on both sides: held to 1e-4,
+    # some twenty times what the chip read (5e-6)
+    def pulled(f):
+        return lambda *a: jax.vjp(f, *a[:5])[1](tuple(a[5:]))
+
+    for b, t, h, d, chunk, sub in [(1, 1024, 32, 128, 64, 16),
+                                   (1, 256, 8, 256, 128, 16),
+                                   (1, 128, 32, 256, 16, 16)]:
+        wide = ((b, t, h, d), f32, "normal")
+        tiles = [wide] * 4 + [((b, t, h), f32, "normal")]
+        scanned = [((t // chunk, b, h, r, w), f32, "normal")
+                   for r, w in [(chunk, d), (chunk, d), (chunk, chunk),
+                                (chunk, d), (chunk, d), (1, d)]]
+
+        def kernels(*x, chunk=chunk, sub=sub):
+            return pallas_kda.kda_intra(*_kda_tiles(*x), chunk, sub, False)
+
+        def plain(*x, chunk=chunk, sub=sub):
+            return lm_ops._intra_plain(*_kda_tiles(*x), chunk, sub)
+        shape = "%dx%dx%dx%d-chunk%d" % (b, t, h, d, chunk)
+        add("pallas_kda[fwd-%s]" % shape, kernels, tiles, plain, 1e-4)
+        add("pallas_kda[bwd-%s]" % shape, pulled(kernels), tiles + scanned,
+            pulled(plain), 1e-4)
     return cases
 
 
@@ -599,7 +649,9 @@ def phase_kernels(cases=None):
         err = _rel_err(got, want)
         say("kernels", case=case.name, err="%.2e" % err, tol=case.tol,
             seconds="%.1f" % (time.perf_counter() - t0))
-        check(got.shape == want.shape and err <= case.tol,
+        check([g.shape for g in jax.tree.leaves(got)]
+              == [w.shape for w in jax.tree.leaves(want)]
+              and err <= case.tol,
               "%s: off its reference by %.3e (tol %g)"
               % (case.name, err, case.tol))
 

@@ -33,7 +33,8 @@ from . import telemetry as _telemetry
 from . import autograd as _autograd
 from .ndarray import ndarray as _nd
 from .ndarray.ndarray import NDArray
-from .ops.registry import STAGE_KEEP, stage_marks
+from .ops.registry import (PROGRAM_GAUGES, STAGE_KEEP, program_counts,
+                           stage_marks)
 
 __all__ = ["Executor", "simple_bind"]
 
@@ -119,7 +120,9 @@ def _graph_eval_fn(symbol):
     and what an op inside marked as dear to recompute (``_STAGE_POLICY``),
     and recomputes the rest of its interior; the gauges
     ``stage/kept_values`` and ``stage/kept_mb`` say what the marked values
-    of the program traced last come to. A node that carries ``device_scope``
+    of the program traced last come to, and the gauges its ops declared
+    (``registry.PROGRAM_GAUGES``: ``kda/intra_kernel``, ``kda/intra_plain``)
+    what they counted in it. A node that carries ``device_scope``
     runs under ``jax.named_scope`` of that name, so a builder names the
     device ops of a generic op by the layer they serve.
     """
@@ -203,7 +206,8 @@ def _graph_eval_fn(symbol):
         values = {}
         aux_updates = {}
         kept = []       # bytes of every value a stage of this trace keeps
-        with _random.trace_scope(key):
+        counts = {}     # what its ops counted (``registry.program_count``)
+        with _random.trace_scope(key), program_counts(counts):
             i = 0
             while i < len(nodes):
                 if i not in stages or not training:
@@ -251,6 +255,8 @@ def _graph_eval_fn(symbol):
         if training:    # set, not added: a retrace counts the same values
             _telemetry.gauge("stage/kept_values", _KEPT_VALUES).set(len(kept))
             _telemetry.gauge("stage/kept_mb", _KEPT_MB).set(sum(kept) / 1e6)
+            for name, doc in PROGRAM_GAUGES.items():
+                _telemetry.gauge(name, doc).set(counts.get(name, 0))
         return outputs, aux_updates
 
     return eval_fn

@@ -3,12 +3,15 @@ depthwise convolution of linear-attention layers, the KDA recurrence
 (Kimi Delta Attention, arXiv:2510.26692) in chunks, and the head that
 gives every token's loss without the tokens x vocabulary array.
 
-All are pure JAX; gradients come from ``jax.vjp`` (the head's from a
-``custom_vjp`` that works through the tokens in blocks, the KDA core's from
-one that walks its groups of chunks in reverse and marks what a
-``mirror_stage`` should keep). Each of the layers a device trace should
-tell apart carries a ``jax.named_scope`` (``mx/kda`` with ``mx/kda/intra``
-and ``mx/kda/scan`` inside it, ``mx/lm_head``; docs/observability.md).
+All are pure JAX but the work inside the KDA core's chunks, which goes to
+two Pallas kernels (``ops/pallas_kda.py``, forward and backward) where a
+head's tile is one of theirs (``pallas_kda.eligible``: heads of 128 or
+256) and stays plain JAX for any other shape. Gradients come from ``jax.vjp`` (the head's from a ``custom_vjp``
+that works through the tokens in blocks, the KDA core's from one that walks
+its groups of chunks in reverse and marks what a ``mirror_stage`` should
+keep). Each of the layers a device trace should tell apart carries a
+``jax.named_scope`` (``mx/kda`` with ``mx/kda/intra`` and ``mx/kda/scan``
+inside it, ``mx/lm_head``; docs/observability.md).
 """
 from __future__ import annotations
 
@@ -18,7 +21,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .registry import register, set_op_meta, stage_keep
+from . import pallas_kda
+from .registry import (program_count, program_gauge, register, set_op_meta,
+                       stage_keep)
 
 _F32 = jnp.float32
 
@@ -114,6 +119,32 @@ def _pair_scores(a, b, g, sub):
     return jnp.concatenate(rows, axis=-2)
 
 
+def _intra_plain(q, k, v, g, beta, chunk, sub):
+    """The work inside the chunks of a group in plain JAX, all its chunks
+    at once: what the scan over them consumes, each (N, B, H, C, .). The
+    path of any tile the kernels do not take, and their oracle."""
+    b, t, h = q.shape[:3]
+    dv = v.shape[-1]
+    n = t // chunk
+
+    def chunks(x):      # (B, T, H, D) -> (N, B, H, C, D)
+        x = x.reshape((b, n, chunk, h) + x.shape[3:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+
+    q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
+    beta = chunks(beta[..., None])                         # (N, B, H, C, 1)
+    gc = jnp.cumsum(g, axis=-2)                            # G_t, <= 0
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    a = jnp.where(strict, _pair_scores(k, k, gc, sub), 0.0) * beta
+    m = _pair_scores(q, k, gc, sub)                        # q.k, s <= t
+    decay = jnp.exp(gc)                                    # within (0, 1]
+    rhs = jnp.concatenate([v * beta, k * decay * beta], -1)
+    sol = _tri_solve(a, rhs)
+    g_end = gc[..., -1:, :]                                # (N, B, H, 1, dk)
+    return (sol[..., :dv], sol[..., dv:], m, q * decay,
+            k * jnp.exp(g_end - gc), g_end)                # k decayed to the end
+
+
 def _kda_group(s, q, k, v, g, beta, chunk, sub):
     """A group of whole chunks from the state ``s`` (B, H, d_k, d_v):
     inside every chunk in matrix form, all the group's chunks at once (the
@@ -122,31 +153,19 @@ def _kda_group(s, q, k, v, g, beta, chunk, sub):
     v: (B, T, H, d_v); beta: (B, T, H), float32, T a multiple of
     ``chunk``. Returns (the state after the group, o (B, T, H, d_v)).
     The two halves run under the scopes ``mx/kda/intra`` (parallel over
-    chunks) and ``mx/kda/scan`` (sequential), for a device trace to split
-    the core by."""
+    chunks: two Pallas kernels, forward and backward, where a head's tile
+    is one of theirs, else plain JAX) and ``mx/kda/scan``
+    (sequential), for a device trace to split the core by."""
     b, t, h, dk = q.shape
     dv = v.shape[-1]
-    n = t // chunk
-
-    def chunks(x):      # (B, T, H, D) -> (N, B, H, C, D)
-        x = x.reshape((b, n, chunk, h) + x.shape[3:])
-        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
-
     hi = lax.Precision.HIGHEST
     with jax.named_scope("mx/kda/intra"):
-        q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
-        beta = chunks(beta[..., None])                     # (N, B, H, C, 1)
-        gc = jnp.cumsum(g, axis=-2)                        # G_t, <= 0
-        strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
-        a = jnp.where(strict, _pair_scores(k, k, gc, sub), 0.0) * beta
-        m = _pair_scores(q, k, gc, sub)                    # q.k, s <= t
-        decay = jnp.exp(gc)                                # within (0, 1]
-        rhs = jnp.concatenate([v * beta, k * decay * beta], -1)
-        sol = _tri_solve(a, rhs)
-        u0, w = sol[..., :dv], sol[..., dv:]
-        q_in = q * decay
-        g_end = gc[..., -1:, :]                            # (N, B, H, 1, dk)
-        k_out = k * jnp.exp(g_end - gc)                    # decay to the end
+        if pallas_kda.eligible(dk, dv, chunk, sub):
+            from ..kernels.tier import resolve_interpret
+            xs = pallas_kda.kda_intra(q, k, v, g, beta, chunk, sub,
+                                      resolve_interpret())
+        else:
+            xs = _intra_plain(q, k, v, g, beta, chunk, sub)
 
     def step(s, x):
         u0_c, w_c, m_c, q_c, k_c, ge_c = x
@@ -157,9 +176,15 @@ def _kda_group(s, q, k, v, g, beta, chunk, sub):
         return s, o
 
     with jax.named_scope("mx/kda/scan"):
-        s, o = lax.scan(step, s, (u0, w, m, q_in, k_out, g_end))
+        s, o = lax.scan(step, s, xs)
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)          # (B, N, C, H, dv)
     return s, o.reshape(b, t, h, dv)
+
+
+def _kda_chunk(t, chunk, sub):
+    """The chunk that ``t`` tokens are cut into: ``chunk``, or the whole
+    sub-blocks that hold a sequence shorter than one."""
+    return min(chunk, -(-t // sub) * sub)
 
 
 def _kda_grouped(pre, xs, chunk, sub, group):
@@ -170,7 +195,7 @@ def _kda_grouped(pre, xs, chunk, sub, group):
     before) and puts the groups first, (N, B, span, ...); ``from_groups``
     undoes it."""
     b, t = xs[0].shape[:2]
-    chunk = min(chunk, -(-t // sub) * sub)
+    chunk = _kda_chunk(t, chunk, sub)
     span = min(group, -(-t // chunk)) * chunk
     pad = (-t) % span
     n = (t + pad) // span
@@ -267,9 +292,30 @@ def kda_chunked(xs, pre=_as_given, consts=(), *, chunk=64, sub=16, group=16,
     k, g: (B, t, H, d_k); v: (B, t, H, d_v); beta: (B, t, H)), and without
     it ``xs`` are those five. ``pre`` closes over no array: what it needs
     beside the slices comes in ``consts``, which get their gradient too.
-    Returns o (B, T, H, d_v) in ``dtype``."""
+    Returns o (B, T, H, d_v) in ``dtype``.
+
+    The work inside chunks goes to the two Pallas kernels of
+    ``ops/pallas_kda.py`` where a head's tile is one of theirs
+    (``pallas_kda.eligible``: d_k and d_v of 128 or 256, a chunk of at
+    most 128 in sub-blocks of whole 8-row registers) and through plain JAX
+    for any other shape; a
+    training program counts its cores of either kind in the gauges
+    ``kda/intra_kernel`` and ``kda/intra_plain``."""
+    q0, _, v0, _, _ = jax.eval_shape(pre, *consts, *(x[:, :1] for x in xs))
+    kernels = pallas_kda.eligible(
+        q0.shape[3], v0.shape[3], _kda_chunk(xs[0].shape[1], chunk, sub), sub)
+    program_count("kda/intra_kernel" if kernels else "kda/intra_plain")
     return _kda_core(pre, chunk, sub, group, jnp.dtype(dtype), tuple(consts),
                      tuple(xs))
+
+
+program_gauge("kda/intra_kernel",
+              "KDA cores of the training program traced last whose work "
+              "inside chunks went to the Pallas kernels (ops/pallas_kda.py)")
+program_gauge("kda/intra_plain",
+              "KDA cores of the training program traced last whose work "
+              "inside chunks went through plain JAX (a head's tile is none "
+              "of the kernels')")
 
 
 @register("_contrib_KDA")

@@ -26,7 +26,8 @@ import threading
 from jax.ad_checkpoint import checkpoint_name
 
 __all__ = ["Operator", "register", "get", "list_ops", "alias",
-           "STAGE_KEEP", "stage_keep", "stage_marks"]
+           "STAGE_KEEP", "stage_keep", "stage_marks", "PROGRAM_GAUGES",
+           "program_gauge", "program_count", "program_counts"]
 
 _REGISTRY: dict[str, "Operator"] = {}
 
@@ -60,6 +61,38 @@ def stage_marks(kept):
         yield
     finally:
         _marks.kept = prev
+
+
+# --------------------------------------------- what a program was built of
+# Gauges fed by a choice an op makes while it is traced (which of two
+# implementations a shape selects): name -> help. When a training program is
+# traced the executor sets each to the number of times the program's ops
+# called ``program_count(name)``, 0 where none did.
+PROGRAM_GAUGES: dict[str, str] = {}
+
+
+def program_gauge(name, help):
+    PROGRAM_GAUGES[name] = help
+
+
+def program_count(name):
+    """One more of ``name`` in the program being traced; nothing outside
+    the trace of one."""
+    counts = getattr(_marks, "counts", None)
+    if counts is not None:
+        counts[name] = counts.get(name, 0) + 1
+
+
+@contextlib.contextmanager
+def program_counts(counts):
+    """While a training program is traced: ``program_count`` adds into the
+    dict ``counts``."""
+    prev = getattr(_marks, "counts", None)
+    _marks.counts = counts
+    try:
+        yield
+    finally:
+        _marks.counts = prev
 
 
 class Operator:

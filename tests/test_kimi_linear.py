@@ -317,14 +317,23 @@ def _traced_gradient(sym, t=LONG_T):
         *_abstract(sym, data=(B, t), softmax_label=(B, t))).jaxpr
 
 
-def _every_eqn(jaxpr):
+def _named_eqns(jaxpr, outer=""):
+    """Every equation, those of inner jaxprs too, with the whole stack of
+    names it was traced under (an inner jaxpr's equations carry only their
+    own part of it)."""
     for e in jaxpr.eqns:
-        yield e
+        name = "/".join(x for x in (outer, str(e.source_info.name_stack))
+                        if x)
+        yield e, name
         for v in e.params.values():
             for sub in v if isinstance(v, (list, tuple)) else (v,):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    yield from _every_eqn(sub)
+                    yield from _named_eqns(sub, name)
+
+
+def _every_eqn(jaxpr):
+    return (e for e, _ in _named_eqns(jaxpr))
 
 
 @pytest.fixture(scope="module")
@@ -356,9 +365,11 @@ def test_a_stage_runs_its_core_forward_once_for_the_step(cfg, traced_step,
                   if e.primitive.name == "scan"
                   and e.params["length"] == LONG_T // (SMALL_CHUNK * chunks)]
         assert groups.count(False) == groups.count(True) == len(kda_layers)
-    else:
-        kernels = sum(e.primitive.name == "pallas_call" for e in traced_step)
-        assert kernels == len(cfg["layers"]) - len(kda_layers) == 1
+    else:       # by name: the KDA core has kernels of its own at 128 lanes
+        kernels = [e.params["name"] or "" for e in traced_step
+                   if e.primitive.name == "pallas_call"]
+        attention = [k for k in kernels if not k.startswith("kda_intra")]
+        assert len(attention) == len(cfg["layers"]) - len(kda_layers) == 1
 
 
 def test_the_stage_gauges_read_what_the_marked_shapes_say(cfg):
@@ -490,6 +501,121 @@ def test_chunked_kdas_own_backward_carries_the_state_through_the_groups():
     _assert_gradients(["rate"] + list("qkvgb"), g_got, g_want)
     assert float(jnp.max(jnp.abs(g_want[0]))) > 0
     assert half.dtype == jnp.bfloat16 and half.shape == cot.shape
+
+
+# ---- the work inside chunks as two Pallas kernels (ops/pallas_kda.py)
+def _plain_path(monkeypatch):
+    """No tile is the kernels': every core takes the plain-JAX path."""
+    from mxnet_tpu.ops import pallas_kda
+    monkeypatch.setattr(pallas_kda, "eligible", lambda *a: False)
+
+
+def _close(names, got, want, rtol):
+    """Float32 round-off of sums of thousands of terms: ``rtol`` of an
+    element, or of the array's largest where the element is small."""
+    for name, a, w in zip(names, got, want):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(w), rtol=rtol,
+            atol=rtol * float(jnp.max(jnp.abs(w))), err_msg=name)
+
+
+@pytest.mark.parametrize("t,b,h", [(64, 2, 3), (200, 1, 2), (200, 1, 8)],
+                         ids=["T64-one-head-a-tile", "T200-two-heads-a-tile",
+                              "T200-eight-heads-a-program"])
+def test_kda_kernels_are_the_plain_path_and_the_recurrence(t, b, h,
+                                                           monkeypatch):
+    """At 128-wide heads, chunk 64, sub-blocks of 16 the work inside
+    chunks runs in the two kernels (interpreted here): forward and the
+    gradients of q, k, v, g, beta and of a rate in ``consts`` against the
+    plain path to float32 round-off, and against the token-by-token
+    recurrence at the tolerances the plain path is held to. 200 tokens are
+    no multiple of the chunk; 3 heads go one a tile, 2 and 8 two a tile."""
+    from mxnet_tpu.ops.lm_ops import kda_chunked
+    xs, cot = _kda_inputs(t, b=b, h=h, dk=128, dv=128)
+    rate = jnp.asarray(np.linspace(0.5, 1.5, h).astype("f4"))[:, None]
+
+    def pre(rate, q, k, v, f, beta):
+        return q, k, v, rate * f, beta
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a) * cot)
+
+    def chunked(rate, *a):
+        return kda_chunked(a, pre, (rate,), chunk=64, group=2)
+
+    def both():
+        return chunked(rate, *xs), jax.grad(loss(chunked), range(6))(rate, *xs)
+    names = ["rate"] + list("qkvgb")
+    with jax.default_matmul_precision("highest"):
+        got, g_got = both()
+        want = ref.kda_recurrence(*pre(rate, *xs))
+        g_want = jax.grad(loss(lambda rate, *a: ref.kda_recurrence(
+            *pre(rate, *a))), range(6))(rate, *xs)
+        _plain_path(monkeypatch)
+        plain, g_plain = both()
+    assert np.isfinite(np.asarray(got)).all()
+    _close(["o"], [got], [plain], 1e-5)
+    _close(names, g_got, g_plain, 1e-4)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    _assert_gradients(names, g_got, g_want)
+
+
+def _kda_symbol(h, chunk=64):
+    """One ``_contrib_KDA`` op as a loss: the smallest training graph
+    with a KDA core."""
+    v = [mx.sym.Variable(n) for n in ("q", "k", "v", "f", "b", "a_log",
+                                      "dt_bias")]
+    return mx.sym.MakeLoss(mx.sym.contrib.KDA(*v, num_heads=h, chunk=chunk))
+
+
+def _kda_shapes(h, d, t=64):
+    wide = (1, t, h * d)
+    return dict(q=wide, k=wide, v=wide, f=wide, b=(1, t, h), a_log=(h,),
+                dt_bias=(h * d,))
+
+
+@pytest.mark.parametrize("d,kernel", [(16, False), (128, True),
+                                      (512, False)],
+                         ids=["d16-plain", "d128-kernels", "d512-plain"])
+def test_the_tile_selects_kernels_or_the_plain_path(d, kernel):
+    """No flag: a 128-wide head's core goes to the kernels, a 16-wide one
+    and one wider than the kernels' tiles through plain JAX. Read where a
+    user would, from the gauges ``kda/intra_kernel`` and
+    ``kda/intra_plain`` that a traced training program sets (0 and 0 for a graph with no KDA core), and from the
+    program itself: the step's forward, the group recomputed inside the
+    core's backward, the backward, a kernel each, every one under
+    ``mx/kda/intra`` for the device trace to charge to ``mx/kda``."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.models import resnet_symbol
+    gauges = [telemetry.gauge("kda/intra_kernel"),
+              telemetry.gauge("kda/intra_plain")]
+    sym = _kda_symbol(2)
+    jaxpr = jax.make_jaxpr(_gradients(sym))(
+        *_abstract(sym, **_kda_shapes(2, d))).jaxpr
+    assert [g.value() for g in gauges] == [int(kernel), int(not kernel)]
+    kernels = [(e.params["name"], name) for e, name in _named_eqns(jaxpr)
+               if e.primitive.name == "pallas_call"]
+    assert sorted(k for k, _ in kernels) == (
+        ["kda_intra_bwd", "kda_intra_fwd", "kda_intra_fwd"] if kernel else [])
+    for k, name in kernels:
+        assert "mx/kda/intra" in name, (k, name)
+        assert ("transpose(jvp(mx/kda/intra))" in name) \
+            == (k == "kda_intra_bwd"), (k, name)
+    net = resnet_symbol(num_classes=10, num_layers=18, image_shape="3,32,32")
+    jax.make_jaxpr(_gradients(net))(
+        *_abstract(net, data=(2, 3, 32, 32), softmax_label=(2,)))
+    assert [g.value() for g in gauges] == [0, 0]
+
+
+def test_the_tiny_models_cores_are_counted_as_plain(cfg):
+    """The five-layer tiny symbol has four KDA layers of 16-wide heads:
+    four plain cores, no kernel, however often the program is traced."""
+    from mxnet_tpu import telemetry
+    for _ in range(2):                     # set, not added
+        _traced_gradient(_symbol(cfg, kda_chunk=SMALL_CHUNK), t=64)
+        assert telemetry.gauge("kda/intra_kernel").value() == 0
+        assert telemetry.gauge("kda/intra_plain").value() == 4
 
 
 @pytest.mark.parametrize("tq,tk,causal", [(96, 96, True), (70, 70, True),

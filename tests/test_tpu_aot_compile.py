@@ -57,7 +57,7 @@ def test_every_kernel_module_has_a_case():
     chip uncompiled."""
     from mxnet_tpu import kernels
     covered = {c.name.split("[")[0] for c in CASES}
-    assert set(kernels.KERNEL_OPS) | {"pallas_flash"} == covered
+    assert set(kernels.KERNEL_OPS) | {"pallas_flash", "pallas_kda"} == covered
 
 
 @pytest.mark.parametrize("case", CASES, ids=[c.name for c in CASES])
