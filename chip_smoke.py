@@ -436,6 +436,34 @@ def _scale_bias_act_reference(x, scale, bias, act):
     return y.astype(x.dtype)
 
 
+def _window_reference(q, k, v, window, block=512):
+    """The dense form of grouped-query attention under a window, float32,
+    for ``block`` rows of queries at a time (all the keys at once would be
+    8.6 GB of scores at 8,192 tokens): query head ``i`` reads KV head ``i
+    // (H / H_kv)``, key ``j`` visible to query ``i`` when ``i - window <
+    j <= i``."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    b, h, t, d = q.shape
+    kf, vf = (jnp.repeat(x.astype(f32), h // k.shape[1], axis=1)
+              for x in (k, v))
+
+    @jax.checkpoint
+    def rows(a):
+        qb, start = a
+        s = jnp.einsum("bhqd,bhkd->bhqk", qb, kf) / d ** 0.5
+        i = start + jnp.arange(block)[:, None]
+        j = jnp.arange(t)[None, :]
+        p = jax.nn.softmax(
+            jnp.where((j <= i) & (j > i - window), s, -1e30), axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, vf)
+
+    qb = jnp.moveaxis(q.astype(f32).reshape(b, h, t // block, block, d), 2, 0)
+    o = jax.lax.map(rows, (qb, jnp.arange(t // block) * block))
+    return jnp.moveaxis(o, 0, 2).reshape(b, h, t, d).astype(q.dtype)
+
+
 def _kda_tiles(q, k, v, g, beta):
     """Normals as a KDA core's inputs: q and k of unit length (q over
     sqrt(d) besides), a log decay a token from -0.001 to -6, beta within
@@ -493,6 +521,27 @@ def kernel_cases():
                                                  "normal")],
         lambda q, k, v: attention.reference_attention(q, k, v, causal=True),
         tol[bf16])
+    # a window layer of Trinity-Mini: 32 query heads of 128 over 4 KV
+    # heads, keys within 2,048 of a query, 8,192 tokens; the output, and
+    # the three gradients from its cotangent (the blockwise backward sums
+    # dk, dv over a group's 8 query heads), each against the dense form
+    b, h, hk, t, d, window = 1, 32, 4, 8192, 128, 2048
+    wide, narrow = ((b, h, t, d), bf16, "normal"), ((b, hk, t, d), bf16,
+                                                    "normal")
+
+    def windowed(q, k, v):
+        return pallas_flash.flash_attention(q, k, v, 128, 128, True, False,
+                                            window)
+
+    def dense(q, k, v):
+        return _window_reference(q, k, v, window)
+    shape = "%dx%d:%dx%dx%d-bfloat16-window%d" % (b, h, hk, t, d, window)
+    add("pallas_flash[%s]" % shape, windowed, [wide, narrow, narrow], dense,
+        tol[bf16])
+    add("pallas_flash[grad-%s]" % shape,
+        lambda q, k, v, do: jax.vjp(windowed, q, k, v)[1](do),
+        [wide, narrow, narrow, wide],
+        lambda q, k, v, do: jax.vjp(dense, q, k, v)[1](do), tol[bf16])
     for b, h, t, d, dt in attn:
         qkv = [((b, h, t, d), dt, "normal")] * 3
         add("flash_attn[%dx%dx%dx%d-%s]" % (b, h, t, d, dt.__name__),

@@ -1,7 +1,8 @@
-"""Ops of a language model built from a Symbol: RMSNorm, the causal
-depthwise convolution of linear-attention layers, the KDA recurrence
-(Kimi Delta Attention, arXiv:2510.26692) in chunks, and the head that
-gives every token's loss without the tokens x vocabulary array.
+"""Ops of a language model built from a Symbol: RMSNorm, the rotary
+position embedding, the causal depthwise convolution of linear-attention
+layers, the KDA recurrence (Kimi Delta Attention, arXiv:2510.26692) in
+chunks, and the head that gives every token's loss without the tokens x
+vocabulary array.
 
 All are pure JAX but the work inside the KDA core's chunks, which goes to
 two Pallas kernels (``ops/pallas_kda.py``, forward and backward) where a
@@ -11,7 +12,7 @@ that works through the tokens in blocks, the KDA core's from one that walks
 its groups of chunks in reverse and marks what a ``mirror_stage`` should
 keep). Each of the layers a device trace should tell apart carries a
 ``jax.named_scope`` (``mx/kda`` with ``mx/kda/intra`` and ``mx/kda/scan``
-inside it, ``mx/lm_head``; docs/observability.md).
+inside it, ``mx/rope``, ``mx/lm_head``; docs/observability.md).
 """
 from __future__ import annotations
 
@@ -35,6 +36,23 @@ def rms_norm(data, gamma, *, eps=1e-5):
     x = data.astype(_F32)
     y = x * lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps)
     return (y * gamma.astype(_F32)).astype(data.dtype)
+
+
+@register("_contrib_RoPE")
+def rope(data, *, theta=10000.0):
+    """Rotary position embedding over the last axis of (B, T, H, D), the
+    halves split at D / 2 (``x cos + rotate_half(x) sin``, ``rotate_half(x)
+    = [-x2, x1]``): pair ``i`` of a token at position ``t`` turns by ``t
+    theta^(-2 i / D)``; positions 0 .. T-1, no scaling. Angles in float32
+    whatever the input's dtype."""
+    with jax.named_scope("mx/rope"):
+        t, n = data.shape[1], data.shape[-1] // 2
+        ang = jnp.arange(t, dtype=_F32)[:, None] \
+            * float(theta) ** (-jnp.arange(n, dtype=_F32) / n)[None, :]
+        cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+        x1, x2 = data[..., :n].astype(_F32), data[..., n:].astype(_F32)
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               -1).astype(data.dtype)
 
 
 @register("_contrib_CausalConv1D")

@@ -12,6 +12,17 @@ the future are never read).
 The value's width may differ from the width q and k share (latent
 attention: 192-wide q.k, 128-wide v): the output takes v's.
 
+``k`` and ``v`` may carry fewer heads than ``q`` (grouped-query attention:
+``H % H_kv == 0``, query head ``i`` reads KV head ``i // (H / H_kv)``). The
+kernel reads the group's one KV head through its index map, so the heads
+are never repeated in memory, and the backward sums ``dK``, ``dV`` over
+the group's query heads. A static ``window`` (with ``causal``) lets query
+``i`` see keys ``i - window < j <= i``: the grid's key axis is then as long
+as the most key blocks any query block sees, its index map starts at the
+block's first visible key block and stops at its last, so a key block
+wholly behind the window or wholly in the future is neither fetched nor
+multiplied; the backward's loop bounds follow the same rule.
+
 Backward: ``jax.custom_vjp`` whose bwd is the flash backward written
 blockwise in plain jax (:func:`_flash_bwd`): per block of queries it
 recomputes the scores against the blocks of keys that block may see (a
@@ -30,16 +41,44 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .registry import register as _register, stage_keep
+from .registry import (program_count, program_gauge,
+                       register as _register, stage_keep)
 
 _NEG_INF = -1e30
 
 
+def _block_span(xp, qi, block_q, block_k, n_k, off, window):
+    """(first, last) key block that a row of query block ``qi`` sees under
+    the causal mask and the window, ``last < first`` where no row sees a
+    key; ``xp`` is ``numpy`` over every query block at once (the grid's
+    length, the gauges) or ``jax.numpy`` over a program's own."""
+    lo = qi * block_q + off - window + 1
+    hi = (qi + 1) * block_q - 1 + off
+    first = xp.minimum(xp.maximum(lo, 0) // block_k, n_k - 1)
+    last = xp.where(hi >= 0, xp.minimum(xp.maximum(hi, 0) // block_k,
+                                        n_k - 1), -1)
+    return first, last
+
+
+def blocks_visited(tq, tk, block_q=128, block_k=128, window=None):
+    """(key blocks a head's causal forward visits, what it would visit
+    without the window): 952 and 2,080 at 8,192 tokens under a window of
+    2,048."""
+    block_q, block_k = min(block_q, tq), min(block_k, tk)
+    n_q, n_k = -(-tq // block_q), -(-tk // block_k)
+    qi = np.arange(n_q)
+    first, last = _block_span(np, qi, block_q, block_k, n_k, tk - tq,
+                              tk + tq if window is None else window)
+    causal = int(np.sum(np.maximum(last + 1, 0)))
+    return int(np.sum(np.maximum(last - first + 1, 0))), causal
+
+
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            block_q, block_k, seq_q, seq_k, causal, sm_scale):
+            block_q, block_k, seq_q, seq_k, causal, sm_scale, window=None):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     n_k = pl.num_programs(2)
@@ -53,9 +92,19 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     # causal: a KV block entirely in this Q block's future contributes
     # nothing — skip its compute (the diagonal offset seq_k - seq_q
     # aligns cross-length attention like blockwise_attention)
-    if causal:
+    if window is not None:
+        # the grid's key axis counts from the query block's first visible
+        # key block (the index maps fetch that one): ``kb`` is the block
+        # this step holds, past the last visible one there is nothing to do
+        first, last = _block_span(jnp, qi, block_q, block_k,
+                                  -(-seq_k // block_k), seq_k - seq_q, window)
+        kb = first + ki
+        visible = kb <= last
+    elif causal:
+        kb = ki
         visible = ki * block_k <= (qi + 1) * block_q - 1 + (seq_k - seq_q)
     else:
+        kb = ki
         visible = True
 
     @pl.when(visible)
@@ -70,13 +119,15 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             q, k_blk,
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # (bq, bk)
-        kv_pos = ki * block_k + jax.lax.broadcasted_iota(
+        kv_pos = kb * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (bq, block_k), 1)
         mask = kv_pos < seq_k                              # tail padding
         if causal:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, block_k), 0)
             mask &= kv_pos <= q_pos + (seq_k - seq_q)
+            if window is not None:
+                mask &= kv_pos > q_pos + (seq_k - seq_q) - window
         s = jnp.where(mask, s, _NEG_INF)
         m = m_scr[:]
         l = l_scr[:]
@@ -98,10 +149,16 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                     / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
 
 
-def _flash_fwd(q, k, v, block_q, block_k, causal, interpret):
+def _flash_fwd(q, k, v, block_q, block_k, causal, interpret, window=None):
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    h_kv, tk = k.shape[1], k.shape[2]
     dv = v.shape[3]
+    if h % h_kv or v.shape[1] != h_kv:
+        raise ValueError("flash_attention: %d query heads over %d and %d "
+                         "key and value heads" % (h, h_kv, v.shape[1]))
+    if window is not None and not causal:
+        raise ValueError("flash_attention: a window needs causal=True")
+    group = h // h_kv
     sm_scale = 1.0 / math.sqrt(d)
     block_q = min(block_q, tq)
     block_k = min(block_k, tk)
@@ -114,10 +171,28 @@ def _flash_fwd(q, k, v, block_q, block_k, causal, interpret):
 
     bh = b * h
     qp = qp.reshape(bh, tq + pad_q, d)
-    kp = kp.reshape(bh, tk + pad_k, d)
-    vp = vp.reshape(bh, tk + pad_k, dv)
+    kp = kp.reshape(b * h_kv, tk + pad_k, d)
+    vp = vp.reshape(b * h_kv, tk + pad_k, dv)
     n_q = (tq + pad_q) // block_q
     n_k = (tk + pad_k) // block_k
+    n_steps = n_k
+    if window is not None:
+        # as many steps as the longest span of key blocks a query block sees
+        first, last = _block_span(np, np.arange(n_q), block_q, block_k, n_k,
+                                  tk - tq, window)
+        n_steps = max(1, int(np.max(last - first + 1)))
+
+    def kv_map(bi, qi, ki):
+        # a group's query heads read their one KV head; under a window the
+        # step's block counts from the query block's first visible one and
+        # stays on its last (a block index that repeats is not fetched again)
+        if group > 1:
+            bi = bi // group
+        if window is not None:
+            first, last = _block_span(jnp, qi, block_q, block_k, n_k,
+                                      tk - tq, window)
+            ki = jnp.minimum(first + ki, jnp.maximum(last, first))
+        return bi, ki, 0
 
     # KV blocks are the innermost grid dim: each (block_k, d) tile is
     # DMA'd per step while the online-softmax state (m, l, acc) persists
@@ -126,14 +201,14 @@ def _flash_fwd(q, k, v, block_q, block_k, causal, interpret):
     # staging capped usable sequence length)
     kernel = functools.partial(
         _kernel, block_q=block_q, block_k=block_k, seq_q=tq, seq_k=tk,
-        causal=causal, sm_scale=sm_scale)
+        causal=causal, sm_scale=sm_scale, window=window)
     out = pl.pallas_call(
         kernel,
-        grid=(bh, n_q, n_k),
+        grid=(bh, n_q, n_steps),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bi, qi, ki: (bi, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bi, qi, ki: (bi, ki, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda bi, qi, ki: (bi, ki, 0)),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, dv), kv_map),
         ],
         out_specs=pl.BlockSpec((1, block_q, dv),
                                lambda bi, qi, ki: (bi, qi, 0)),
@@ -149,13 +224,16 @@ def _flash_fwd(q, k, v, block_q, block_k, causal, interpret):
     return out[:, :, :tq] if pad_q else out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, block_q=128, block_k=128, causal=False,
-                    interpret=None):
+                    interpret=None, window=None):
     """Flash attention on (B, H, T, D) tensors via a pallas TPU kernel.
 
     ``interpret=None`` auto-selects: interpreter off TPU (tests), Mosaic
-    on TPU. f32 accumulation regardless of input dtype.
+    on TPU. f32 accumulation regardless of input dtype. ``k`` and ``v``
+    may be (B, H_kv, T, .) with ``H % H_kv == 0`` (grouped-query
+    attention); ``window`` (static, with ``causal``) hides the keys more
+    than ``window - 1`` positions behind a query.
 
     Fully-masked rows (causal with ``seq_q > seq_k``: queries before the
     first key) return **zeros** — the flash/blockwise convention shared
@@ -167,19 +245,29 @@ def flash_attention(q, k, v, block_q=128, block_k=128, causal=False,
     if interpret is None:
         from ..kernels.tier import resolve_interpret
         interpret = resolve_interpret()
-    return _flash_fwd(q, k, v, block_q, block_k, causal, interpret)
+    return _flash_fwd(q, k, v, block_q, block_k, causal, interpret, window)
 
 
-def _flash_bwd(q, k, v, o, do, causal, block_q=512, block_k=512):
+def _flash_bwd(q, k, v, o, do, causal, block_q=512, block_k=512,
+               window=None):
     """Gradients of ``softmax(q k^T / sqrt(d)) v`` (masked as the forward
     masks) from the output and its cotangent, blockwise: for each block of
     queries, one pass over the key blocks it may see for the rows' log sum
     of exponentials, and one for ``dv += p^T do``, ``ds = p (do v^T -
     rowsum(o do))``, ``dq += ds k``, ``dk += ds^T q``. Scores and the
-    accumulators are float32; the loops' bounds follow the causal mask, so
-    a block of the future costs nothing."""
+    accumulators are float32; the loops' bounds follow the causal mask and
+    the window, so a block of the future or behind the window costs
+    nothing. Under grouped heads q, o and do are taken as (B, H_kv, group,
+    T, .): a group's scores are made against its one KV head, and ``dk``,
+    ``dv`` are summed over the group by the products that make them."""
     b, h, tq, d = q.shape
-    tk, dv = k.shape[2], v.shape[3]
+    h_kv, tk, dv = k.shape[1], k.shape[2], v.shape[3]
+    group = h // h_kv
+    # the axes before (rows, width) of the query side and of the key side
+    qa, ka = ("bng", "bn") if group > 1 else ("bh", "bh")
+    if group > 1:
+        q, o, do = (x.reshape((b, h_kv, group) + x.shape[2:])
+                    for x in (q, o, do))
     f32 = jnp.float32
     scale = 1.0 / math.sqrt(d)
     bq, bk = min(block_q, tq), min(block_k, tk)
@@ -187,7 +275,8 @@ def _flash_bwd(q, k, v, o, do, causal, block_q=512, block_k=512):
     off = tk - tq
 
     def padded(x, pad):
-        return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else x
+        widths = ((0, 0),) * (x.ndim - 2) + ((0, pad), (0, 0))
+        return jnp.pad(x, widths) if pad else x
 
     qp, op, dop = padded(q, pad_q), padded(o, pad_q), padded(do, pad_q)
     kp, vp = padded(k, pad_k), padded(v, pad_k)
@@ -195,7 +284,7 @@ def _flash_bwd(q, k, v, o, do, causal, block_q=512, block_k=512):
     delta = jnp.sum(op.astype(f32) * dop.astype(f32), -1, keepdims=True)
 
     def rows(x, i, size):
-        return jax.lax.dynamic_slice_in_dim(x, i * size, size, axis=2)
+        return jax.lax.dynamic_slice_in_dim(x, i * size, size, axis=x.ndim - 2)
 
     def q_block(i, carry):
         dq, dk, dvv = carry
@@ -207,15 +296,20 @@ def _flash_bwd(q, k, v, o, do, causal, block_q=512, block_k=512):
             n_vis = jnp.clip(((i + 1) * bq - 1 + off) // bk + 1, 0, n_k)
         else:
             n_vis = n_k
+        first = 0       # and the first: a window hides those before it
+        if window is not None:
+            first = jnp.clip((i * bq + off - window + 1) // bk, 0, n_k)
 
         def scores(j):
             kj = rows(kp, j, bk).astype(f32)
-            s = jnp.einsum("bhqd,bhkd->bhqk", qi, kj,
+            s = jnp.einsum("%sqd,%skd->%sqk" % (qa, ka, qa), qi, kj,
                            preferred_element_type=f32)
             kv_pos = j * bk + jnp.arange(bk)[None, :]
             mask = kv_pos < tk
             if causal:
                 mask = mask & (kv_pos <= q_pos + off)
+            if window is not None:
+                mask = mask & (kv_pos > q_pos + off - window)
             return jnp.where(mask, s, _NEG_INF), mask, kj
 
         def stats(j, ml):
@@ -226,9 +320,9 @@ def _flash_bwd(q, k, v, o, do, causal, block_q=512, block_k=512):
             return m_new, l * jnp.exp(m - m_new) \
                 + jnp.sum(p, -1, keepdims=True)
 
-        shape = (b, h, bq, 1)
+        shape = qi.shape[:-1] + (1,)
         m, l = jax.lax.fori_loop(
-            0, n_vis, stats,
+            first, n_vis, stats,
             (jnp.full(shape, _NEG_INF, f32), jnp.zeros(shape, f32)))
         lse = m + jnp.log(jnp.maximum(l, 1e-30))
 
@@ -237,11 +331,11 @@ def _flash_bwd(q, k, v, o, do, causal, block_q=512, block_k=512):
             s, mask, kj = scores(j)
             vj = rows(vp, j, bk).astype(f32)
             p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-            dvj = jnp.einsum("bhqk,bhqd->bhkd", p, doi)
-            dp = jnp.einsum("bhqd,bhkd->bhqk", doi, vj)
+            dvj = jnp.einsum("%sqk,%sqd->%skd" % (qa, qa, ka), p, doi)
+            dp = jnp.einsum("%sqd,%skd->%sqk" % (qa, ka, qa), doi, vj)
             ds = p * (dp - di)
-            dqi = dqi + jnp.einsum("bhqk,bhkd->bhqd", ds, kj)
-            dkj = jnp.einsum("bhqk,bhqd->bhkd", ds, qi)
+            dqi = dqi + jnp.einsum("%sqk,%skd->%sqd" % (qa, ka, qa), ds, kj)
+            dkj = jnp.einsum("%sqk,%sqd->%skd" % (qa, qa, ka), ds, qi)
 
             def add(acc, x):
                 return jax.lax.dynamic_update_slice_in_dim(
@@ -249,41 +343,60 @@ def _flash_bwd(q, k, v, o, do, causal, block_q=512, block_k=512):
             return dqi, add(dk, dkj), add(dvv, dvj)
 
         dqi, dk, dvv = jax.lax.fori_loop(
-            0, n_vis, grads, (jnp.zeros((b, h, bq, d), f32), dk, dvv))
+            first, n_vis, grads, (jnp.zeros(qi.shape, f32), dk, dvv))
         dq = jax.lax.dynamic_update_slice_in_dim(dq, dqi * scale, i * bq,
-                                                 axis=2)
+                                                 axis=dq.ndim - 2)
         return dq, dk, dvv
 
     dq, dk, dvv = jax.lax.fori_loop(
         0, n_q, q_block,
         (jnp.zeros(qp.shape, f32), jnp.zeros(kp.shape, f32),
          jnp.zeros(vp.shape, f32)))
+    if group > 1:
+        dq = dq.reshape((b, h) + dq.shape[-2:])
     return (dq[:, :, :tq].astype(q.dtype), dk[:, :, :tk].astype(k.dtype),
             dvv[:, :, :tk].astype(v.dtype))
 
 
-def _fwd(q, k, v, block_q, block_k, causal, interpret):
+def _fwd(q, k, v, block_q, block_k, causal, interpret, window):
     # inside a mirror_stage the output is kept and the kernel is not run
     # again in the backward pass; q, k, v are recomputed like the rest
-    o = stage_keep(
-        flash_attention(q, k, v, block_q, block_k, causal, interpret))
+    o = stage_keep(flash_attention(q, k, v, block_q, block_k, causal,
+                                   interpret, window))
     return o, (q, k, v, o)
 
 
-def _bwd(block_q, block_k, causal, interpret, res, g):
+def _bwd(block_q, block_k, causal, interpret, window, res, g):
     q, k, v, o = res
-    return _flash_bwd(q, k, v, o, g, causal)
+    return _flash_bwd(q, k, v, o, g, causal, window=window)
 
 
 flash_attention.defvjp(_fwd, _bwd)
 
 
+program_gauge("attn/window_layers",
+              "attention cores of the training program traced last that "
+              "see a window of keys (_contrib_FlashAttention with window)")
+program_gauge("attn/full_layers",
+              "attention cores of the training program traced last that "
+              "see every earlier key")
+program_gauge("attn/kv_blocks_visited",
+              "key blocks a head's forward visits, summed over the "
+              "attention cores of the training program traced last")
+program_gauge("attn/kv_blocks_causal",
+              "key blocks plain causal attention would visit there: what "
+              "the windows save is the difference")
+
+
 # eager/symbolic surface: mx.nd._contrib_FlashAttention(q, k, v, causal=...)
 @_register("_contrib_FlashAttention")
 def _contrib_flash_attention(q, k, v, *, causal=False, block_q=128,
-                             block_k=128):
+                             block_k=128, window=None):
     """(B, H, T, D) flash attention as a registered op (pallas on TPU);
-    ``v`` may be (B, H, T, Dv) of another width than q and k share.
+    ``v`` may be (B, H, T, Dv) of another width than q and k share, ``k``
+    and ``v`` may carry ``H_kv`` heads with ``H % H_kv == 0`` (query head
+    ``i`` reads KV head ``i // (H / H_kv)``), and ``window`` (with
+    ``causal``) hides the keys more than ``window - 1`` behind a query.
 
     Tier-aware: under ``MXNET_KERNEL_TIER=safe|auto`` the call dispatches
     to the kernel-tier attention (kernels/attention.py — the
@@ -294,9 +407,20 @@ def _contrib_flash_attention(q, k, v, *, causal=False, block_q=128,
     default) it lowers this module's kernel with the caller's explicit
     block sizes, unchanged — eligibility rejections (e.g. causal
     cross-length) take the same legacy path and the reason lands in
-    ``tier.stats()['fallback']``."""
-    from ..kernels import attention as _attn
-    out = _attn.attend_or_none(q, k, v, causal=bool(causal))
-    if out is not None:
-        return out
-    return flash_attention(q, k, v, block_q, block_k, bool(causal))
+    ``tier.stats()['fallback']``. A window or grouped heads are this
+    module's kernel's alone."""
+    window = None if window is None else int(window)
+    program_count("attn/full_layers" if window is None
+                  else "attn/window_layers")
+    if causal:
+        visited, plain = blocks_visited(q.shape[2], k.shape[2], block_q,
+                                        block_k, window)
+        program_count("attn/kv_blocks_visited", visited)
+        program_count("attn/kv_blocks_causal", plain)
+    if window is None and k.shape[1] == q.shape[1]:
+        from ..kernels import attention as _attn
+        out = _attn.attend_or_none(q, k, v, causal=bool(causal))
+        if out is not None:
+            return out
+    return flash_attention(q, k, v, block_q, block_k, bool(causal), None,
+                           window)

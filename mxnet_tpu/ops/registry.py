@@ -75,12 +75,12 @@ def program_gauge(name, help):
     PROGRAM_GAUGES[name] = help
 
 
-def program_count(name):
-    """One more of ``name`` in the program being traced; nothing outside
+def program_count(name, n=1):
+    """``n`` more of ``name`` in the program being traced; nothing outside
     the trace of one."""
     counts = getattr(_marks, "counts", None)
     if counts is not None:
-        counts[name] = counts.get(name, 0) + 1
+        counts[name] = counts.get(name, 0) + n
 
 
 @contextlib.contextmanager
