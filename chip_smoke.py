@@ -512,26 +512,30 @@ def kernel_cases():
                 lambda q, k, v, c=causal: attention.reference_attention(
                     q, k, v, causal=c), tol[dt])
     # latent attention without positions (Kimi-Linear's MLA): 192-wide
-    # q.k beside 128-wide v, 32 heads
-    b, h, t, d, dv = 1, 32, 2048, 192, 128
-    add("pallas_flash[%dx%dx%dx%d/%d-bfloat16-causal1]" % (b, h, t, d, dv),
-        lambda q, k, v: pallas_flash.flash_attention(
-            q, k, v, 128, 128, True, False),
-        [((b, h, t, d), bf16, "normal")] * 2 + [((b, h, t, dv), bf16,
-                                                 "normal")],
-        lambda q, k, v: attention.reference_attention(q, k, v, causal=True),
-        tol[bf16])
+    # q.k beside 128-wide v, 32 heads, at the tile the kernel takes from
+    # the shapes (1,024 x 1,024 in bfloat16; float32 operands at these
+    # widths fit 512 x 1,024, at 128 / 128 they fill the 16 MiB of VMEM)
+    b, h, t = 1, 32, 2048
+    for d, dv, dt in [(192, 128, bf16), (192, 128, f32), (128, 128, f32)]:
+        add("pallas_flash[%dx%dx%dx%d/%d-%s-causal1]"
+            % (b, h, t, d, dv, dt.__name__),
+            lambda q, k, v: pallas_flash.flash_attention(
+                q, k, v, None, None, True, False),
+            [((b, h, t, d), dt, "normal")] * 2 + [((b, h, t, dv), dt,
+                                                   "normal")],
+            lambda q, k, v: attention.reference_attention(
+                q, k, v, causal=True), tol[dt])
     # a window layer of Trinity-Mini: 32 query heads of 128 over 4 KV
-    # heads, keys within 2,048 of a query, 8,192 tokens; the output, and
-    # the three gradients from its cotangent (the blockwise backward sums
+    # heads, keys within 2,048 of a query, 8,192 tokens, the kernel's own
+    # tile; the output, and the three gradients from its cotangent (the blockwise backward sums
     # dk, dv over a group's 8 query heads), each against the dense form
     b, h, hk, t, d, window = 1, 32, 4, 8192, 128, 2048
     wide, narrow = ((b, h, t, d), bf16, "normal"), ((b, hk, t, d), bf16,
                                                     "normal")
 
     def windowed(q, k, v):
-        return pallas_flash.flash_attention(q, k, v, 128, 128, True, False,
-                                            window)
+        return pallas_flash.flash_attention(q, k, v, None, None, True,
+                                            False, window)
 
     def dense(q, k, v):
         return _window_reference(q, k, v, window)

@@ -4,10 +4,26 @@ The hot op of long-context training, hand-tiled for the MXU per
 /opt/skills/guides/pallas_guide.md: the Q block lives in VMEM, the kernel
 streams KV blocks with an online softmax (f32 running max / denominator /
 accumulator in VMEM scratch), and the QK^T / PV matmuls run on the MXU
-with ``preferred_element_type=f32``.  Grid = (batch*heads, q_blocks); the
-KV stream is a ``fori_loop`` inside the kernel so the accumulator never
-leaves VMEM.  Causal masking prunes the loop bound (blocks entirely in
-the future are never read).
+with ``preferred_element_type=f32``.  Grid = (batch*heads, q_blocks,
+key steps), the key steps innermost so the accumulator never leaves VMEM.
+Under the causal mask a query block's steps count from its first visible
+key block and stay on its last: a block entirely in the future is neither
+fetched nor multiplied, and a block every row sees whole skips the mask's
+arithmetic.
+
+**The tile** ``(block_q, block_k)`` is the op's own choice from what it
+sees in its input (:func:`tile_for`: the lengths, the widths, the
+operands' itemsize, the window), unless the caller gives one, which is
+taken as given. At blocks of 128 the kernel is bound by its grid steps
+(0.4 us each on a v5e, eight times a 128 x 128 x 128 tile's work), so the
+rule takes the first of 1,024 x 1,024, 512 x 1,024, 512 x 512, 256 x 256
+and 128 x 128 (the order the chip timed them in at 8,192 tokens, PERF.md,
+PR 32) that a program can hold in VMEM and that is no longer than the
+window; a sequence under a tile is one block. **The VMEM budget** is the
+16 MiB a v5e's compiler lets a kernel scope by default (``VMEM_BUDGET``;
+no call raises the limit), counted by :func:`tile_bytes`: bfloat16
+operands at widths up to 256 take 1,024 x 1,024 (14 MiB at 192 / 128),
+float32 operands take it at 128 / 128 and 512 x 1,024 from 192 / 128 on.
 
 The value's width may differ from the width q and k share (latent
 attention: 192-wide q.k, 128-wide v): the output takes v's.
@@ -53,28 +69,91 @@ _NEG_INF = -1e30
 
 def _block_span(xp, qi, block_q, block_k, n_k, off, window):
     """(first, last) key block that a row of query block ``qi`` sees under
-    the causal mask and the window, ``last < first`` where no row sees a
-    key; ``xp`` is ``numpy`` over every query block at once (the grid's
-    length, the gauges) or ``jax.numpy`` over a program's own."""
-    lo = qi * block_q + off - window + 1
+    the causal mask and the window (``None``: every earlier key), ``last <
+    first`` where no row sees a key; ``xp`` is ``numpy`` over every query
+    block at once (the grid's length, the gauges) or ``jax.numpy`` over a
+    program's own."""
     hi = (qi + 1) * block_q - 1 + off
-    first = xp.minimum(xp.maximum(lo, 0) // block_k, n_k - 1)
     last = xp.where(hi >= 0, xp.minimum(xp.maximum(hi, 0) // block_k,
                                         n_k - 1), -1)
-    return first, last
+    if window is None:
+        return qi * 0, last
+    lo = qi * block_q + off - window + 1
+    return xp.minimum(xp.maximum(lo, 0) // block_k, n_k - 1), last
+
+
+# What one program of the forward kernel may hold in VMEM, by
+# :func:`tile_bytes`: the 16 MiB a v5e's compiler lets a kernel scope by
+# default, so no call raises the limit.
+VMEM_BUDGET = 16 * 2 ** 20
+# the tiles tried, best first as timed alone on a v5e at 8,192 tokens
+# (PERF.md, PR 32): a longer key block pays more than a longer query block
+_TILES = ((1024, 1024), (512, 1024), (512, 512), (256, 256), (128, 128))
+
+
+def tile_bytes(block_q, block_k, d, dv, itemsize):
+    """VMEM a program of the forward kernel holds at a tile: the q, k, v
+    and output blocks (two buffers each, the pipeline's), the float32
+    accumulator, the running max and denominator (a lane-wide register
+    row each) and two and a half float32 (block_q, block_k) tiles (the
+    scores, the probabilities and their cast for the second product).
+    Within 1 MB of what Mosaic compiled and refused at 1,024 x 1,024."""
+    blocks = (block_q + block_k) * (d + dv) * itemsize * 2
+    state = block_q * (dv + 2 * 128) * 4
+    return blocks + state + 10 * block_q * block_k
+
+
+def tile_for(tq, tk, d, dv, itemsize, window=None):
+    """(block_q, block_k) of the forward kernel, from the shapes alone: the
+    first of ``_TILES`` that fits ``VMEM_BUDGET`` and, under a window, is no
+    longer than it (a longer block multiplies pairs the mask then hides);
+    no longer than the sequence (one under a tile is one block)."""
+    for block_q, block_k in _TILES:
+        if window is not None and max(block_q, block_k) > max(window, 128):
+            continue
+        if tile_bytes(block_q, block_k, d, dv, itemsize) <= VMEM_BUDGET:
+            break
+    return min(block_q, tq), min(block_k, tk)
+
+
+def _tile(q, k, v, block_q, block_k, window):
+    """The caller's blocks as given (cut to the sequence), else the
+    shapes' own."""
+    tq, tk = q.shape[2], k.shape[2]
+    auto_q, auto_k = tile_for(tq, tk, q.shape[3], v.shape[3],
+                              q.dtype.itemsize, window)
+    return (auto_q if block_q is None else min(block_q, tq),
+            auto_k if block_k is None else min(block_k, tk))
+
+
+def _spans(tq, tk, block_q, block_k, window):
+    """(first, last) visible key block of every query block, as arrays."""
+    n_q, n_k = -(-tq // block_q), -(-tk // block_k)
+    return _block_span(np, np.arange(n_q), block_q, block_k, n_k, tk - tq,
+                       window)
 
 
 def blocks_visited(tq, tk, block_q=128, block_k=128, window=None):
     """(key blocks a head's causal forward visits, what it would visit
     without the window): 952 and 2,080 at 8,192 tokens under a window of
-    2,048."""
+    2,048 at blocks of 128, 70 and 136 at blocks of 512."""
     block_q, block_k = min(block_q, tq), min(block_k, tk)
-    n_q, n_k = -(-tq // block_q), -(-tk // block_k)
-    qi = np.arange(n_q)
-    first, last = _block_span(np, qi, block_q, block_k, n_k, tk - tq,
-                              tk + tq if window is None else window)
+    first, last = _spans(tq, tk, block_q, block_k, window)
     causal = int(np.sum(np.maximum(last + 1, 0)))
     return int(np.sum(np.maximum(last - first + 1, 0))), causal
+
+
+def grid_steps(tq, tk, block_q=128, block_k=128, causal=True, window=None):
+    """Steps one head's forward grid takes: query blocks times the longest
+    span of key blocks a query block sees (all of them without the causal
+    mask). 4,096 at 8,192 tokens at blocks of 128 and 1,088 under a window
+    of 2,048; 256 and 80 at blocks of 512."""
+    block_q, block_k = min(block_q, tq), min(block_k, tk)
+    n_q, n_k = -(-tq // block_q), -(-tk // block_k)
+    if not causal:
+        return n_q * n_k
+    first, last = _spans(tq, tk, block_q, block_k, window)
+    return n_q * max(1, int(np.max(last - first + 1)))
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -82,6 +161,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     n_k = pl.num_programs(2)
+    off = seq_k - seq_q      # the diagonal of cross-length attention
 
     @pl.when(ki == 0)
     def _():
@@ -89,26 +169,28 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # causal: a KV block entirely in this Q block's future contributes
-    # nothing — skip its compute (the diagonal offset seq_k - seq_q
-    # aligns cross-length attention like blockwise_attention)
-    if window is not None:
+    if causal:
         # the grid's key axis counts from the query block's first visible
         # key block (the index maps fetch that one): ``kb`` is the block
-        # this step holds, past the last visible one there is nothing to do
+        # this step holds; past the last visible one (the future) there is
+        # nothing to do and nothing was fetched
         first, last = _block_span(jnp, qi, block_q, block_k,
-                                  -(-seq_k // block_k), seq_k - seq_q, window)
+                                  -(-seq_k // block_k), off, window)
         kb = first + ki
         visible = kb <= last
-    elif causal:
-        kb = ki
-        visible = ki * block_k <= (qi + 1) * block_q - 1 + (seq_k - seq_q)
+        # every row of the query block sees every key of the block: its
+        # last key is no later than the first row's own position (which
+        # also keeps it off the padded tail) and its first key is inside
+        # the last row's window
+        whole = (kb + 1) * block_k - 1 <= qi * block_q + off
+        if window is not None:
+            whole &= kb * block_k > (qi + 1) * block_q - 1 + off - window
     else:
         kb = ki
         visible = True
+        whole = True if seq_k % block_k == 0 else (kb + 1) * block_k <= seq_k
 
-    @pl.when(visible)
-    def _():
+    def attend(masked):
         q = q_ref[0]                                       # (bq, d)
         bq = q.shape[0]
         k_blk = k_ref[0]                                   # (bk, d)
@@ -119,29 +201,39 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             q, k_blk,
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # (bq, bk)
-        kv_pos = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, block_k), 1)
-        mask = kv_pos < seq_k                              # tail padding
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            mask &= kv_pos <= q_pos + (seq_k - seq_q)
-            if window is not None:
-                mask &= kv_pos > q_pos + (seq_k - seq_q) - window
-        s = jnp.where(mask, s, _NEG_INF)
+        if masked:
+            kv_pos = kb * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (bq, block_k), 1)
+            mask = kv_pos < seq_k                          # tail padding
+            if causal:
+                q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (bq, block_k), 0)
+                mask &= kv_pos <= q_pos + off
+                if window is not None:
+                    mask &= kv_pos > q_pos + off - window
+            s = jnp.where(mask, s, _NEG_INF)
         m = m_scr[:]
         l = l_scr[:]
         m_blk = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m, m_blk)
         corr = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
+        if masked:      # a row that sees no key of the block adds nothing
+            p = jnp.where(mask, p, 0.0)
         m_scr[:] = m_new
         l_scr[:] = l * corr + jnp.sum(p, axis=1, keepdims=True)
         acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
             p.astype(v_blk.dtype), v_blk,
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    # a block wholly inside the visible region needs none of the mask's
+    # iota, compare and select passes over the (bq, bk) scores
+    if whole is True:
+        attend(False)
+    else:
+        pl.when(visible & whole)(lambda: attend(False))
+        pl.when(visible & jnp.logical_not(whole))(lambda: attend(True))
 
     @pl.when(ki == n_k - 1)
     def _():
@@ -160,8 +252,7 @@ def _flash_fwd(q, k, v, block_q, block_k, causal, interpret, window=None):
         raise ValueError("flash_attention: a window needs causal=True")
     group = h // h_kv
     sm_scale = 1.0 / math.sqrt(d)
-    block_q = min(block_q, tq)
-    block_k = min(block_k, tk)
+    block_q, block_k = _tile(q, k, v, block_q, block_k, window)
 
     pad_q = (-tq) % block_q
     pad_k = (-tk) % block_k
@@ -175,20 +266,17 @@ def _flash_fwd(q, k, v, block_q, block_k, causal, interpret, window=None):
     vp = vp.reshape(b * h_kv, tk + pad_k, dv)
     n_q = (tq + pad_q) // block_q
     n_k = (tk + pad_k) // block_k
-    n_steps = n_k
-    if window is not None:
-        # as many steps as the longest span of key blocks a query block sees
-        first, last = _block_span(np, np.arange(n_q), block_q, block_k, n_k,
-                                  tk - tq, window)
-        n_steps = max(1, int(np.max(last - first + 1)))
+    # as many steps as the longest span of key blocks a query block sees
+    n_steps = grid_steps(tq, tk, block_q, block_k, causal, window) // n_q
 
     def kv_map(bi, qi, ki):
-        # a group's query heads read their one KV head; under a window the
-        # step's block counts from the query block's first visible one and
-        # stays on its last (a block index that repeats is not fetched again)
+        # a group's query heads read their one KV head; under the causal
+        # mask the step's block counts from the query block's first visible
+        # one and stays on its last (a block index that repeats is not
+        # fetched again: a step in the future costs no DMA)
         if group > 1:
             bi = bi // group
-        if window is not None:
+        if causal:
             first, last = _block_span(jnp, qi, block_q, block_k, n_k,
                                       tk - tq, window)
             ki = jnp.minimum(first + ki, jnp.maximum(last, first))
@@ -225,7 +313,7 @@ def _flash_fwd(q, k, v, block_q, block_k, causal, interpret, window=None):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def flash_attention(q, k, v, block_q=128, block_k=128, causal=False,
+def flash_attention(q, k, v, block_q=None, block_k=None, causal=False,
                     interpret=None, window=None):
     """Flash attention on (B, H, T, D) tensors via a pallas TPU kernel.
 
@@ -233,7 +321,9 @@ def flash_attention(q, k, v, block_q=128, block_k=128, causal=False,
     on TPU. f32 accumulation regardless of input dtype. ``k`` and ``v``
     may be (B, H_kv, T, .) with ``H % H_kv == 0`` (grouped-query
     attention); ``window`` (static, with ``causal``) hides the keys more
-    than ``window - 1`` positions behind a query.
+    than ``window - 1`` positions behind a query. ``block_q`` /
+    ``block_k`` left ``None`` are :func:`tile_for`'s, from the shapes; a
+    caller's own are taken as given.
 
     Fully-masked rows (causal with ``seq_q > seq_k``: queries before the
     first key) return **zeros** — the flash/blockwise convention shared
@@ -386,12 +476,16 @@ program_gauge("attn/kv_blocks_visited",
 program_gauge("attn/kv_blocks_causal",
               "key blocks plain causal attention would visit there: what "
               "the windows save is the difference")
+program_gauge("attn/grid_steps",
+              "steps a head's forward grid takes at the tile the op takes "
+              "from the shapes, summed over the attention cores of the "
+              "training program traced last")
 
 
 # eager/symbolic surface: mx.nd._contrib_FlashAttention(q, k, v, causal=...)
 @_register("_contrib_FlashAttention")
-def _contrib_flash_attention(q, k, v, *, causal=False, block_q=128,
-                             block_k=128, window=None):
+def _contrib_flash_attention(q, k, v, *, causal=False, block_q=None,
+                             block_k=None, window=None):
     """(B, H, T, D) flash attention as a registered op (pallas on TPU);
     ``v`` may be (B, H, T, Dv) of another width than q and k share, ``k``
     and ``v`` may carry ``H_kv`` heads with ``H % H_kv == 0`` (query head
@@ -412,9 +506,12 @@ def _contrib_flash_attention(q, k, v, *, causal=False, block_q=128,
     window = None if window is None else int(window)
     program_count("attn/full_layers" if window is None
                   else "attn/window_layers")
+    tq, tk = q.shape[2], k.shape[2]
+    tile = _tile(q, k, v, block_q, block_k, window)
+    program_count("attn/grid_steps",
+                  grid_steps(tq, tk, *tile, bool(causal), window))
     if causal:
-        visited, plain = blocks_visited(q.shape[2], k.shape[2], block_q,
-                                        block_k, window)
+        visited, plain = blocks_visited(tq, tk, *tile, window)
         program_count("attn/kv_blocks_visited", visited)
         program_count("attn/kv_blocks_causal", plain)
     if window is None and k.shape[1] == q.shape[1]:

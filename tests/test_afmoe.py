@@ -81,7 +81,7 @@ def fitted(cfg):
         g: telemetry.gauge(g).value()
         for g in ("attn/window_layers", "attn/full_layers",
                   "attn/kv_blocks_visited", "attn/kv_blocks_causal",
-                  "stage/kept_values")})
+                  "attn/grid_steps", "stage/kept_values")})
     return seen
 
 
@@ -140,20 +140,31 @@ def test_one_program_a_step_and_no_compile_after_the_first(fitted):
 
 def test_the_gauges_count_the_layers_and_the_key_blocks(cfg, fitted):
     """Set when the training program is traced: four window layers and one
-    full; 300 tokens are three blocks of 128, a causal head visits 1 + 2 +
-    3 = 6 of them a layer, and a window of 160 hides none of those (block
-    0 holds keys 0-127, the last query block starts at 256 and sees back
-    to 97): 30 and 30. A stage keeps each attention's output."""
-    from mxnet_tpu.ops.pallas_flash import blocks_visited
+    full, each counted at the tile the op takes from its shapes. Under
+    the window of 160 that is blocks of 128 (no block longer than the
+    window): 300 tokens are three of them, a causal head visits 1 + 2 + 3
+    = 6 a layer, the window hides none of those (block 0 holds keys 0-127,
+    the last query block starts at 256 and sees back to 97), and the grid
+    is 3 query blocks by the longest span, 3. The full layer's 300 tokens
+    are under one tile: one block, one step. 4 x 6 + 1 and 4 x 9 + 1. A
+    stage keeps each attention's output."""
+    from mxnet_tpu.ops.pallas_flash import (blocks_visited, grid_steps,
+                                            tile_for)
     g = fitted["gauges"]
     assert (g["attn/window_layers"], g["attn/full_layers"]) == (4, 1)
-    t = cfg["sequence_length"]
-    assert blocks_visited(t, t, 128, 128, cfg["sliding_window"]) == (6, 6)
+    t, window = cfg["sequence_length"], cfg["sliding_window"]
+    d = cfg["head_dim"]
+    assert tile_for(t, t, d, d, 4, window) == (128, 128)
+    assert tile_for(t, t, d, d, 4, None) == (t, t)
+    assert blocks_visited(t, t, 128, 128, window) == (6, 6)
+    assert grid_steps(t, t, 128, 128, True, window) == 9
     assert (g["attn/kv_blocks_visited"], g["attn/kv_blocks_causal"]) \
-        == (30, 30)
+        == (25, 25)
+    assert g["attn/grid_steps"] == 37
     assert g["stage/kept_values"] == 5
     # a window of 40 hides block 0 from the last query block
     assert blocks_visited(t, t, 128, 128, 40) == (5, 6)
+    assert grid_steps(t, t, 128, 128, True, 40) == 6
 
 
 def test_every_block_is_a_stage_that_keeps_its_attention_output(cfg):

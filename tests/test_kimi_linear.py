@@ -618,6 +618,26 @@ def test_the_tiny_models_cores_are_counted_as_plain(cfg):
         assert telemetry.gauge("kda/intra_plain").value() == 4
 
 
+@pytest.mark.parametrize("t,tile,gauges", [
+    (64, (64, 64), (0, 1, 1, 1, 1)),            # under one tile: one step
+    (2048, (1024, 1024), (0, 1, 3, 3, 4)),      # 1 + 2 key blocks, 2 x 2
+])
+def test_the_attention_gauges_read_the_tile_the_shapes_give(cfg, t, tile,
+                                                            gauges):
+    """The tiny symbol's one MLA core (no window, every head its own keys)
+    at the tile ``_contrib_FlashAttention`` takes from its shapes: the five
+    ``attn/*`` gauges, set when the training program is traced."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops.pallas_flash import tile_for
+    d = cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
+    assert tile_for(t, t, d, cfg["v_head_dim"], 4) == tile
+    for _ in range(2):                     # set, not added
+        _traced_gradient(_symbol(cfg, kda_chunk=SMALL_CHUNK), t=t)
+        assert tuple(telemetry.gauge("attn/" + g).value() for g in (
+            "window_layers", "full_layers", "kv_blocks_visited",
+            "kv_blocks_causal", "grid_steps")) == gauges
+
+
 @pytest.mark.parametrize("tq,tk,causal", [(96, 96, True), (70, 70, True),
                                           (40, 104, True), (64, 64, False)])
 def test_flash_attention_with_a_value_width_of_its_own(tq, tk, causal):

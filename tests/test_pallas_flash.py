@@ -168,28 +168,33 @@ def test_a_window_as_long_as_the_sequence_is_causal_attention():
 
 
 # the registered op's lowered program (interpreter mode, no source
-# locations in the text), forward and gradients, as the kernel stood before
-# it knew of a window or of grouped heads: SHA-256 of the text at commit
-# a612b22 under the one installation (JAX 0.9.0)
+# locations in the text), forward and gradients, at an explicit 64 x 64:
+# SHA-256 of the text under the one installation (JAX 0.9.0). Taken anew
+# in PR 32, whose kernel skips the mask on a block that is wholly visible
+# and whose causal index map stays on a query block's last visible key
+# block (the digests before it, at commit a612b22, were of the kernel as it
+# stood before it knew of a window or of grouped heads)
 _BEFORE = {
     ("float32", "fwd"):
-        "c993b018dbbf88c0450815a14333c7bf92fbd2612eac14f16c1addaaa9e9430f",
+        "074d690d1ebb8044192c61034613b41033ee3aa56894695d64c5835e439d137d",
     ("float32", "grad"):
-        "3699b2aca1fab4e0be9eae02de65f2ab0fef17668e9ed5eb5014e01e69495db2",
+        "a9998d5cebb7a6f8e0708a58f59fa542d496a41d479a4814d5c4305fffe08e96",
     ("bfloat16", "fwd"):
-        "c0a1832400dd482e7a5bf634a85b24f7a3376770cd891ec0f5b39dba9a0cd265",
+        "826de351ba126f9d34ed700240c6e96fbcf8e01a0a4ce054720f9e3a27e88c0e",
     ("bfloat16", "grad"):
-        "69bcef45b75576c55982c5ab8317db1f77f75f87fedbecc782efad795c6de915",
+        "cf22e63bf366e730dff1f757d63e6b606662ffd1807a5fd7b159d81f17593c1a",
 }
 
 
 @pytest.mark.parametrize("dtype,which", sorted(_BEFORE))
 def test_without_a_window_and_with_every_kv_head_the_program_is_as_before(
         dtype, which):
-    """``window=None, H_kv = H``: the program is the earlier kernel's,
-    character for character, so its output is bit for bit (a changed
-    kernel body, grid or index map would show here; after a deliberate
-    change of that case, or another JAX, take the digests anew)."""
+    """``window=None, H_kv = H``, an explicit 64 x 64: the program is the
+    pinned kernel's, character for character, so its output is bit for bit
+    (a changed kernel body, grid or index map would show here; after a
+    deliberate change of that case, or another JAX, take the digests anew:
+    PR 32 did, for the mask skipped on whole blocks and the clamped causal
+    index map)."""
     import hashlib
     from mxnet_tpu.ops import registry
     op = registry.get("_contrib_FlashAttention").fn
@@ -222,6 +227,120 @@ def test_blocks_visited_under_a_window():
     assert blocks_visited(8192, 8192, 128, 128, 2048) == (952, 2080)
     assert blocks_visited(8192, 8192, 128, 128, None) == (2080, 2080)
     assert blocks_visited(200, 200, 64, 64, 70) == (9, 10)
+    # 16 query blocks: 1 + 2 + 3 + 4, then 5 each for the other 12
+    assert blocks_visited(8192, 8192, 512, 512, 2048) == (70, 136)
+    assert blocks_visited(8192, 8192, 512, 512, None) == (136, 136)
+    # 8 query blocks: 1 + 2, then 3 each for the other 6
+    assert blocks_visited(8192, 8192, 1024, 1024, 2048) == (21, 36)
+    assert blocks_visited(8192, 8192, 1024, 1024, None) == (36, 36)
+
+
+# (tokens, window, query block, key block, causal) -> steps of a head's grid
+@pytest.mark.parametrize("args,steps", [
+    ((8192, 8192, 128, 128, True, None), 4096),
+    ((8192, 8192, 128, 128, True, 2048), 1088),      # 64 x 17
+    ((8192, 8192, 512, 512, True, None), 256),
+    ((8192, 8192, 512, 512, True, 2048), 80),        # 16 x 5
+    ((8192, 8192, 1024, 1024, True, None), 64),
+    ((8192, 8192, 1024, 1024, True, 2048), 24),      # 8 x 3
+    ((200, 328, 128, 128, False, None), 6),          # 2 x 3, padded tails
+    ((64, 256, 64, 128, True, None), 2),             # one query block
+    ((100, 100, 512, 512, True, None), 1),           # under one tile
+])
+def test_grid_steps_are_query_blocks_times_the_longest_span(args, steps):
+    from mxnet_tpu.ops.pallas_flash import grid_steps
+    assert grid_steps(*args) == steps
+
+
+# (tq, tk, d, dv, itemsize, window) -> (block_q, block_k)
+@pytest.mark.parametrize("shape,tile", [
+    ((8192, 8192, 128, 128, 2, None), (1024, 1024)),   # Trinity's full layer
+    ((8192, 8192, 128, 128, 2, 2048), (1024, 1024)),   # and its window layers
+    ((8192, 8192, 192, 128, 2, None), (1024, 1024)),   # Kimi's MLA layer
+    ((8192, 8192, 128, 128, 4, None), (1024, 1024)),   # float32: 15.5 MiB
+    ((8192, 8192, 192, 128, 4, None), (512, 1024)),    # float32: 16.5 at 1,024
+    ((8192, 8192, 576, 512, 2, None), (512, 1024)),    # a wide latent head
+    ((8192, 8192, 128, 128, 2, 512), (512, 512)),      # no longer than the window
+    ((8192, 8192, 128, 128, 2, 100), (128, 128)),
+    ((300, 300, 32, 32, 4, None), (300, 300)),         # under one tile: itself
+    ((64, 4096, 128, 128, 2, None), (64, 1024)),
+    ((1500, 1500, 128, 128, 2, None), (1024, 1024)),   # the tail is padded
+])
+def test_tile_for_reads_the_tile_from_the_shapes(shape, tile):
+    from mxnet_tpu.ops.pallas_flash import (VMEM_BUDGET, tile_bytes,
+                                            tile_for)
+    assert tile_for(*shape) == tile
+    _tq, _tk, d, dv, itemsize, _window = shape
+    assert tile_bytes(*tile, d, dv, itemsize) <= VMEM_BUDGET
+
+
+def test_an_explicit_tile_is_honoured_as_given():
+    """The caller's 64 x 64 is the grid's: 3 x 3 steps a head at 192
+    tokens, where the shapes' own tile is the sequence, one step."""
+    from mxnet_tpu.ops import registry
+    from mxnet_tpu.ops.pallas_flash import _flash_fwd
+    q, k, v = _grouped(4, 4, 192)
+    op = registry.get("_contrib_FlashAttention").fn
+    given, chosen = {}, {}
+    with registry.program_counts(given):
+        a = op(q, k, v, causal=True, block_q=64, block_k=64)
+    with registry.program_counts(chosen):
+        b = op(q, k, v, causal=True)
+    assert given == {"attn/full_layers": 1, "attn/grid_steps": 9,
+                     "attn/kv_blocks_visited": 6, "attn/kv_blocks_causal": 6}
+    assert chosen == {"attn/full_layers": 1, "attn/grid_steps": 1,
+                      "attn/kv_blocks_visited": 1, "attn/kv_blocks_causal": 1}
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
+                               atol=2e-5)
+    for blocks, grid in (((64, 64), (8, 3, 3)), ((None, None), (8, 1, 1)),
+                         ((64, None), (8, 3, 1))):
+        jaxpr = jax.make_jaxpr(lambda *x: _flash_fwd(
+            *x, *blocks, True, True))(q, k, v)     # noqa: B023
+        assert [tuple(e.params["grid_mapping"].grid) for e in jaxpr.eqns
+                if e.primitive.name == "pallas_call"] == [grid]
+
+
+# (query heads, KV heads, tq, tk, d, dv, window, query block, key block);
+# a block of None is the shapes' own (tile_for): 512 under these windows,
+# 1,024 without one
+_CHOSEN = {
+    "a window that is no multiple of the tile":
+        (2, 2, 1200, 1200, 16, 16, 700, None, None),
+    "block_q != block_k": (2, 2, 320, 320, 16, 16, 100, 128, 64),
+    "grouped heads": (4, 2, 1100, 1100, 16, 16, None, None, None),
+    "a value width of its own": (2, 2, 1100, 1100, 24, 16, 600, None, None),
+    "tq != tk": (2, 1, 600, 1300, 16, 16, None, None, None),
+    "a tail that needs padding": (2, 2, 1030, 1030, 16, 16, 520, None, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CHOSEN))
+def test_the_chosen_tile_matches_the_dense_masked_softmax(case):
+    """Forward (the kernel, interpreted, more than one block on each axis
+    but for the query axis of ``tq != tk``) and gradients (through the
+    custom_vjp) against the dense form."""
+    from mxnet_tpu.ops.pallas_flash import grid_steps, tile_for
+    h, hk, tq, tk, d, dv, window, bq, bk = _CHOSEN[case]
+    rng = np.random.RandomState(len(case))
+    mk = lambda n, t, w: jnp.asarray(                         # noqa: E731
+        (rng.randn(1, n, t, w) * 2 / np.sqrt(d)).astype(np.float32))
+    q, k, v, do = mk(h, tq, d), mk(hk, tk, d), mk(hk, tk, dv), mk(h, tq, dv)
+    tile = tile_for(tq, tk, d, dv, 4, window) if bq is None else (bq, bk)
+    assert grid_steps(tq, tk, *tile, True, window) > 1
+
+    def flash(*a):
+        return flash_attention(*a, bq, bk, True, None, window)
+
+    def dense(*a):
+        return _masked_reference(*a, window)
+    got, pull = jax.vjp(flash, q, k, v)
+    want, pull_dense = jax.vjp(dense, q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    for name, a, b in zip("qkv", pull(do), pull_dense(do)):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4, err_msg=name)
 
 
 def test_a_window_needs_causal_and_heads_that_divide():
