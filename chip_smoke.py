@@ -436,23 +436,26 @@ def _scale_bias_act_reference(x, scale, bias, act):
     return y.astype(x.dtype)
 
 
-def _window_reference(q, k, v, window, block=512):
+def _window_reference(q, k, v, window, block=512, scale=None):
     """The dense form of grouped-query attention under a window, float32,
     for ``block`` rows of queries at a time (all the keys at once would be
     8.6 GB of scores at 8,192 tokens): query head ``i`` reads KV head ``i
     // (H / H_kv)``, key ``j`` visible to query ``i`` when ``i - window <
-    j <= i``."""
+    j <= i`` (``window`` None: ``j <= i``); the scores times ``scale``
+    (None: ``1 / sqrt(d)``)."""
     import jax
     import jax.numpy as jnp
     f32 = jnp.float32
     b, h, t, d = q.shape
     kf, vf = (jnp.repeat(x.astype(f32), h // k.shape[1], axis=1)
               for x in (k, v))
+    scale = d ** -0.5 if scale is None else scale
+    window = t if window is None else window
 
     @jax.checkpoint
     def rows(a):
         qb, start = a
-        s = jnp.einsum("bhqd,bhkd->bhqk", qb, kf) / d ** 0.5
+        s = jnp.einsum("bhqd,bhkd->bhqk", qb, kf) * scale
         i = start + jnp.arange(block)[:, None]
         j = jnp.arange(t)[None, :]
         p = jax.nn.softmax(
@@ -546,6 +549,26 @@ def kernel_cases():
         lambda q, k, v, do: jax.vjp(windowed, q, k, v)[1](do),
         [wide, narrow, narrow, wide],
         lambda q, k, v, do: jax.vjp(dense, q, k, v)[1](do), tol[bf16])
+    # Granite 4.0-H's attention layer: 32 query heads of 64 over 8 KV
+    # heads, no window, the scores times attention_multiplier 1/64 (not 1 /
+    # sqrt(64)), 8,192 tokens, the kernel's own tile; output and gradients
+    hk, d, mult = 8, 64, 0.015625
+    wide, narrow = ((b, h, t, d), bf16, "normal"), ((b, hk, t, d), bf16,
+                                                    "normal")
+
+    def scaled(q, k, v):
+        return pallas_flash.flash_attention(q, k, v, None, None, True, False,
+                                            None, mult)
+
+    def dense_scaled(q, k, v):
+        return _window_reference(q, k, v, None, scale=mult)
+    shape = "%dx%d:%dx%dx%d-bfloat16-scale%g" % (b, h, hk, t, d, mult)
+    add("pallas_flash[%s]" % shape, scaled, [wide, narrow, narrow],
+        dense_scaled, tol[bf16])
+    add("pallas_flash[grad-%s]" % shape,
+        lambda q, k, v, do: jax.vjp(scaled, q, k, v)[1](do),
+        [wide, narrow, narrow, wide],
+        lambda q, k, v, do: jax.vjp(dense_scaled, q, k, v)[1](do), tol[bf16])
     for b, h, t, d, dt in attn:
         qkv = [((b, h, t, d), dt, "normal")] * 3
         add("flash_attn[%dx%dx%dx%d-%s]" % (b, h, t, d, dt.__name__),

@@ -4,6 +4,7 @@ from . import resnet
 from .resnet import get_symbol as resnet_symbol
 from .inception_v3 import get_symbol as inception_v3_symbol
 from .alexnet import get_symbol as alexnet_symbol
+from .granite_hybrid import granite_hybrid_symbol
 
 
 def lenet(num_classes=10):

@@ -1,8 +1,9 @@
 """Ops of a language model built from a Symbol: RMSNorm, the rotary
 position embedding, the causal depthwise convolution of linear-attention
-layers, the KDA recurrence (Kimi Delta Attention, arXiv:2510.26692) in
-chunks, and the head that gives every token's loss without the tokens x
-vocabulary array.
+and state-space layers, the KDA recurrence (Kimi Delta Attention,
+arXiv:2510.26692) and Mamba-2's (arXiv:2405.21060) in chunks, in one frame
+of groups of chunks, Mamba-2's gated norm, and the head that gives every
+token's loss without the tokens x vocabulary array.
 
 All are pure JAX but the work inside the KDA core's chunks, which goes to
 two Pallas kernels (``ops/pallas_kda.py``, forward and backward) where a
@@ -10,9 +11,11 @@ head's tile is one of theirs (``pallas_kda.eligible``: heads of 128 or
 256) and stays plain JAX for any other shape. Gradients come from ``jax.vjp`` (the head's from a ``custom_vjp``
 that works through the tokens in blocks, the KDA core's from one that walks
 its groups of chunks in reverse and marks what a ``mirror_stage`` should
-keep). Each of the layers a device trace should tell apart carries a
-``jax.named_scope`` (``mx/kda`` with ``mx/kda/intra`` and ``mx/kda/scan``
-inside it, ``mx/rope``, ``mx/lm_head``; docs/observability.md).
+keep; Mamba-2's core runs in the same frame, in plain JAX). Each of the
+layers a device trace should tell apart carries a ``jax.named_scope``
+(``mx/kda`` with ``mx/kda/intra`` and ``mx/kda/scan`` inside it, ``mx/ssm``
+with ``mx/ssm/intra`` and ``mx/ssm/scan``, ``mx/rope``, ``mx/lm_head``;
+docs/observability.md).
 """
 from __future__ import annotations
 
@@ -23,8 +26,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import pallas_kda
-from .registry import (program_count, program_gauge, register, set_op_meta,
-                       stage_keep)
+from .registry import (program_count, program_gauge, program_max, register,
+                       set_op_meta, stage_keep)
 
 _F32 = jnp.float32
 
@@ -56,15 +59,17 @@ def rope(data, *, theta=10000.0):
 
 
 @register("_contrib_CausalConv1D")
-def causal_conv1d(data, weight, *, act_type="silu"):
+def causal_conv1d(data, weight, bias=None, *, act_type="silu", no_bias=True):
     """Depthwise convolution over time that sees no later token:
     ``y_t = sum_i w[:, i] x_{t-(K-1)+i}`` with zeros before the sequence,
-    then ``act_type`` (``silu`` or ``none``). data: (B, T, C); weight:
-    (C, K)."""
+    plus ``bias`` (C,) with ``no_bias=False``, then ``act_type`` (``silu``
+    or ``none``). data: (B, T, C); weight: (C, K)."""
     k = weight.shape[1]
     t = data.shape[1]
     xp = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0)))
     y = sum(xp[:, i:i + t] * weight[:, i] for i in range(k))
+    if bias is not None and not no_bias:
+        y = y + bias
     return jax.nn.silu(y) if act_type == "silu" else y
 
 
@@ -205,21 +210,25 @@ def _kda_chunk(t, chunk, sub):
     return min(chunk, -(-t // sub) * sub)
 
 
-def _kda_grouped(pre, xs, chunk, sub, group):
-    """How ``xs`` (B, T, ...) are worked through in groups of whole chunks:
-    ``(run, to_groups, from_groups)``. ``run(consts, s, x)`` is one group
-    from the state ``s`` (:func:`_kda_group` after ``pre``); ``to_groups``
-    pads an array with zero rows after the sequence (they change nothing
-    before) and puts the groups first, (N, B, span, ...); ``from_groups``
-    undoes it."""
+# ------------------------------------------- a recurrence in groups of chunks
+# The frame KDA's delta rule and Mamba-2's scalar-decay scan share: a scan
+# over groups of whole chunks that keeps the state on entry to each, and a
+# backward pass of its own that walks the groups in reverse and recomputes a
+# group's interior from its entry state.
+def _group_span(t, chunk, group):
+    """Tokens in a group: ``group`` chunks, or the chunks that hold a
+    sequence shorter than that."""
+    return min(group, -(-t // chunk)) * chunk
+
+
+def _grouped(xs, span):
+    """How ``xs`` (B, T, ...) are worked through ``span`` tokens at a time:
+    ``(to_groups, from_groups)``. ``to_groups`` pads an array with zero rows
+    after the sequence (they change nothing before) and puts the groups
+    first, (N, B, span, ...); ``from_groups`` undoes it."""
     b, t = xs[0].shape[:2]
-    chunk = _kda_chunk(t, chunk, sub)
-    span = min(group, -(-t // chunk)) * chunk
     pad = (-t) % span
     n = (t + pad) // span
-
-    def run(consts, s, x):
-        return _kda_group(s, *pre(*consts, *x), chunk, sub)
 
     def to_groups(x):
         x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
@@ -228,46 +237,45 @@ def _kda_grouped(pre, xs, chunk, sub, group):
     def from_groups(x):
         return jnp.moveaxis(x, 0, 1).reshape((b, n * span) + x.shape[3:])[:, :t]
 
-    return run, to_groups, from_groups
+    return to_groups, from_groups
 
 
-def _kda_fwd_scan(pre, chunk, sub, group, dtype, consts, xs):
-    """The scan over groups from a zero state: (o (B, T, H, d_v) in
-    ``dtype``, the state on entry to every group (N, B, H, d_k, d_v))."""
-    run, to_groups, from_groups = _kda_grouped(pre, xs, chunk, sub, group)
+def _groups_fwd_scan(run, span, state, dtype, consts, xs):
+    """The scan over groups from a zero state of the shape ``state``:
+    (o (B, T, ...) in ``dtype``, the state on entry to every group (N,
+    *state)). ``run(consts, s, x)`` is one group from the state ``s``:
+    (the state after it, its rows of the output in float32)."""
+    to_groups, from_groups = _grouped(xs, span)
 
     def body(s, x):
         after, o = run(consts, s, x)
         return after, (s, o)
 
-    first = [x[:, :1] for x in xs]
-    q0, _, v0, _, _ = jax.eval_shape(pre, *consts, *first)
-    s0 = jnp.zeros((xs[0].shape[0], q0.shape[2], q0.shape[3], v0.shape[3]),
-                   _F32)
-    _, (states, o) = lax.scan(body, s0, tuple(to_groups(x) for x in xs))
+    _, (states, o) = lax.scan(body, jnp.zeros(state, _F32),
+                              tuple(to_groups(x) for x in xs))
     return from_groups(o).astype(dtype), states
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
-def _kda_core(pre, chunk, sub, group, dtype, consts, xs):
-    return _kda_fwd_scan(pre, chunk, sub, group, dtype, consts, xs)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _groups_core(run, span, state, dtype, consts, xs):
+    return _groups_fwd_scan(run, span, state, dtype, consts, xs)[0]
 
 
-def _kda_core_fwd(pre, chunk, sub, group, dtype, consts, xs):
+def _groups_core_fwd(run, span, state, dtype, consts, xs):
     # inside a mirror_stage the output and the groups' entry states are
     # kept: the stage's backward pass recomputes ``xs`` from the
     # projections and never runs this scan again
-    o, states = _kda_fwd_scan(pre, chunk, sub, group, dtype, consts, xs)
+    o, states = _groups_fwd_scan(run, span, state, dtype, consts, xs)
     o, states = stage_keep(o), stage_keep(states)
     return o, (consts, xs, states)
 
 
-def _kda_core_bwd(pre, chunk, sub, group, dtype, res, ct):
+def _groups_core_bwd(run, span, state, dtype, res, ct):
     """The groups in reverse, each recomputed from its entry state (what
     bounds memory in T: a group's interior exists one group at a time),
     the state's cotangent carried from group to group."""
     consts, xs, states = res
-    run, to_groups, from_groups = _kda_grouped(pre, xs, chunk, sub, group)
+    to_groups, from_groups = _grouped(xs, span)
 
     def body(carry, a):
         ds, dconsts = carry
@@ -284,7 +292,11 @@ def _kda_core_bwd(pre, chunk, sub, group, dtype, res, ct):
     return dconsts, tuple(from_groups(d) for d in dxs)
 
 
-_kda_core.defvjp(_kda_core_fwd, _kda_core_bwd)
+_groups_core.defvjp(_groups_core_fwd, _groups_core_bwd)
+
+
+def _kda_run(pre, chunk, sub, consts, s, x):
+    return _kda_group(s, *pre(*consts, *x), chunk, sub)
 
 
 def _as_given(*x):
@@ -320,11 +332,14 @@ def kda_chunked(xs, pre=_as_given, consts=(), *, chunk=64, sub=16, group=16,
     training program counts its cores of either kind in the gauges
     ``kda/intra_kernel`` and ``kda/intra_plain``."""
     q0, _, v0, _, _ = jax.eval_shape(pre, *consts, *(x[:, :1] for x in xs))
-    kernels = pallas_kda.eligible(
-        q0.shape[3], v0.shape[3], _kda_chunk(xs[0].shape[1], chunk, sub), sub)
+    b, t = xs[0].shape[:2]
+    chunk = _kda_chunk(t, chunk, sub)
+    kernels = pallas_kda.eligible(q0.shape[3], v0.shape[3], chunk, sub)
     program_count("kda/intra_kernel" if kernels else "kda/intra_plain")
-    return _kda_core(pre, chunk, sub, group, jnp.dtype(dtype), tuple(consts),
-                     tuple(xs))
+    return _groups_core(functools.partial(_kda_run, pre, chunk, sub),
+                        _group_span(t, chunk, group),
+                        (b, q0.shape[2], q0.shape[3], v0.shape[3]),
+                        jnp.dtype(dtype), tuple(consts), tuple(xs))
 
 
 program_gauge("kda/intra_kernel",
@@ -365,6 +380,132 @@ def kda(q, k, v, f, b, a_log, dt_bias, *, num_heads, chunk=64):
         o = kda_chunked((q, k, v, f, b), pre, (rate, bias), chunk=chunk,
                         dtype=v.dtype)
         return o.reshape(o.shape[:2] + (-1,))
+
+
+# ----------------------------------------------------------------- Mamba-2
+def _ssd_group(heads, chunk, consts, s, xs):
+    """A group of whole chunks of Mamba-2's recurrence from the state ``s``
+    (B, H, P, N), in the chunked (SSD) form, all the group's chunks at
+    once inside them and a short walk from chunk to chunk for the states.
+    ``xs`` are the group's slices of x (B, t, H*P), B and C (B, t, N;
+    one group: shared by the heads) and the raw dt (B, t, H); ``consts``
+    are dt_bias, A_log and D (H,), float32. Returns (the state after the
+    group, y (B, t, H*P) in float32).
+
+    ``delta``, ``log a``, their running sums, the decays and the states
+    are float32; the operands of the four products (C B^T, the masked
+    decays times it against ``delta x``, a chunk's own state, C against
+    the state carried in) are in x's dtype and accumulate in float32."""
+    dt_bias, a_log, d_skip = consts
+    x, bm, cm, dt = xs
+    b, t = x.shape[:2]
+    n, cdt = t // chunk, x.dtype
+    hi = lax.Precision.HIGHEST          # float32 operands stay float32
+    delta = jax.nn.softplus(dt.astype(_F32) + dt_bias)        # (B, t, H)
+    la = delta * -jnp.exp(a_log)                              # log a <= 0
+
+    def chunks(v):      # (B, t, ...) -> (B, n, C, ...)
+        return v.reshape((b, n, chunk) + v.shape[2:])
+
+    def heads_first(v):     # (B, n, C, H, ...) -> (B, n, H, C, ...)
+        return jnp.moveaxis(v, 3, 2)
+
+    xh = heads_first(chunks(x.reshape(b, t, heads, -1)))      # (B, n, H, C, P)
+    delta = heads_first(chunks(delta))                        # (B, n, H, C)
+    bm, cm = chunks(bm), chunks(cm)                           # (B, n, C, N)
+    cs = jnp.cumsum(heads_first(chunks(la)), axis=-1)         # sum_{k<=i} log a
+    dx = (delta[..., None] * xh.astype(_F32)).astype(cdt)     # delta x
+    with jax.named_scope("mx/ssm/intra"):
+        cb = jnp.einsum("bnik,bnjk->bnij", cm, bm, precision=hi,
+                        preferred_element_type=_F32)
+        seen = jnp.tril(jnp.ones((chunk, chunk), bool))
+        # L[i, j] = prod_{j<k<=i} a_k for j <= i: no exponent is positive
+        decay = jnp.exp(jnp.where(seen, cs[..., :, None] - cs[..., None, :],
+                                  -jnp.inf))
+        y = jnp.einsum("bnhij,bnhjp->bnhip",
+                       (decay * cb[:, :, None]).astype(cdt), dx,
+                       precision=hi, preferred_element_type=_F32)
+        # a chunk's own state: its tokens decayed to its last one
+        to_end = jnp.exp(cs[..., -1:] - cs)
+        own = jnp.einsum("bnhjp,bnjk->bnhpk",
+                         (dx.astype(_F32) * to_end[..., None]).astype(cdt),
+                         bm, precision=hi, preferred_element_type=_F32)
+    with jax.named_scope("mx/ssm/scan"):
+        whole = jnp.exp(cs[..., -1])[..., None, None]         # (B, n, H, 1, 1)
+        entry = []
+        for i in range(n):
+            entry.append(s)
+            s = whole[:, i] * s + own[:, i]
+        entry = jnp.stack(entry, 1)                           # (B, n, H, P, N)
+        y = y + jnp.exp(cs)[..., None] * jnp.einsum(
+            "bnik,bnhpk->bnhip", cm, entry.astype(cdt), precision=hi,
+            preferred_element_type=_F32)
+    y = y + d_skip[:, None, None] * xh.astype(_F32)
+    return s, jnp.moveaxis(y, 2, 3).reshape(b, t, -1)
+
+
+program_gauge("ssm/layers",
+              "Mamba-2 cores (_contrib_Mamba2) of the training program "
+              "traced last")
+program_gauge("ssm/chunks",
+              "chunks a sequence is cut into at the op's chunk, the most "
+              "of any Mamba-2 core of the training program traced last")
+program_gauge("ssm/state_mb",
+              "MB of chunk-boundary states (float32, one on entry to each "
+              "group of chunks) that the Mamba-2 cores of the training "
+              "program traced last keep for their backward pass")
+
+
+def ssd_chunked(x, b, c, dt, dt_bias, a_log, d, *, num_heads, chunk=256,
+                group=8):
+    """Mamba-2's core (state-spaces/mamba ``Mamba2``, arXiv:2405.21060; one
+    group of B and C). x: (B, T, H*P) and b, c: (B, T, N) after their
+    convolution; dt: (B, T, H) as projected; dt_bias, a_log, d: (H,). A
+    head ``h`` with ``delta_t = softplus(dt_t + dt_bias_h)`` and ``a_t =
+    exp(-delta_t exp(a_log_h))`` carries the state ``S_t = a_t S_{t-1} +
+    delta_t x_t b_t^T`` (P, N) from zero and gives ``y_t = S_t c_t + d_h
+    x_t``: (B, T, H*P) in x's dtype, before the gated norm.
+
+    Computed in chunks of ``chunk`` tokens (:func:`_ssd_group`), ``group``
+    chunks at a time, in the frame KDA's core runs in (``_groups_core``):
+    a forward scan that keeps the float32 state on entry to each group (a
+    ``mirror_stage`` keeps them and the output), a backward that recomputes
+    a group's interior, its (chunk, chunk) decays among it, one group at a
+    time. A sequence that is no multiple of the chunk is padded after its
+    end. ``delta``, the decays and the states are float32 whatever the
+    inputs' dtype; the products' operands are x's dtype."""
+    with jax.named_scope("mx/ssm"):
+        bsz, t = x.shape[:2]
+        heads = int(num_heads)
+        chunk = min(int(chunk), -(-t // 8) * 8)
+        span = _group_span(t, chunk, int(group))
+        state = (bsz, heads, x.shape[2] // heads, b.shape[2])
+        program_count("ssm/layers")
+        program_max("ssm/chunks", -(-t // chunk))
+        program_count("ssm/state_mb",
+                      -(-t // span) * 4 * bsz * heads * state[2] * state[3]
+                      / 1e6)
+        consts = tuple(v.astype(_F32) for v in (dt_bias, a_log, d))
+        return _groups_core(functools.partial(_ssd_group, heads, chunk), span,
+                            state, x.dtype, consts, (x, b, c, dt))
+
+
+@register("_contrib_Mamba2")
+def mamba2(x, b, c, dt, dt_bias, a_log, d, *, num_heads, chunk=256):
+    """:func:`ssd_chunked` as a registered op: Mamba-2's core from the
+    convolved x (B, T, H*P), b and c (B, T, N), the projected dt (B, T, H)
+    and the per-head dt_bias, a_log and d, in chunks of ``chunk`` tokens."""
+    return ssd_chunked(x, b, c, dt, dt_bias, a_log, d, num_heads=num_heads,
+                       chunk=chunk)
+
+
+@register("_contrib_GatedRMSNorm")
+def gated_rms_norm(data, gate, gamma, *, eps=1e-5):
+    """``RMSNorm(data * silu(gate)) * gamma`` over the last axis (Mamba-2's
+    output norm: the gate first, then the norm, one group); the product and
+    the statistics in float32 whatever the inputs' dtype."""
+    x = data.astype(_F32) * jax.nn.silu(gate.astype(_F32))
+    return rms_norm(x, gamma, eps=eps).astype(data.dtype)
 
 
 # -------------------------------------------------------------------- head
@@ -481,6 +622,8 @@ def _moe_op(data, router_weight, router_bias, gate_weight, up_weight,
 
 set_op_meta("RMSNorm", f32_inputs=(1,))
 set_op_meta("_contrib_KDA", f32_inputs=(5, 6))
+set_op_meta("_contrib_Mamba2", f32_inputs=(4, 5, 6))
+set_op_meta("_contrib_GatedRMSNorm", f32_inputs=(2,))
 set_op_meta("_contrib_LMHeadLoss", index_inputs=(2,))
 set_op_meta("_contrib_MoE", aux_inputs=(6,), aux_outputs=(1,),
             num_visible_outputs=1, f32_inputs=(1, 2),

@@ -241,7 +241,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                     / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
 
 
-def _flash_fwd(q, k, v, block_q, block_k, causal, interpret, window=None):
+def _flash_fwd(q, k, v, block_q, block_k, causal, interpret, window=None,
+               scale=None):
     b, h, tq, d = q.shape
     h_kv, tk = k.shape[1], k.shape[2]
     dv = v.shape[3]
@@ -251,7 +252,7 @@ def _flash_fwd(q, k, v, block_q, block_k, causal, interpret, window=None):
     if window is not None and not causal:
         raise ValueError("flash_attention: a window needs causal=True")
     group = h // h_kv
-    sm_scale = 1.0 / math.sqrt(d)
+    sm_scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
     block_q, block_k = _tile(q, k, v, block_q, block_k, window)
 
     pad_q = (-tq) % block_q
@@ -312,9 +313,9 @@ def _flash_fwd(q, k, v, block_q, block_k, causal, interpret, window=None):
     return out[:, :, :tq] if pad_q else out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, block_q=None, block_k=None, causal=False,
-                    interpret=None, window=None):
+                    interpret=None, window=None, scale=None):
     """Flash attention on (B, H, T, D) tensors via a pallas TPU kernel.
 
     ``interpret=None`` auto-selects: interpreter off TPU (tests), Mosaic
@@ -323,7 +324,8 @@ def flash_attention(q, k, v, block_q=None, block_k=None, causal=False,
     attention); ``window`` (static, with ``causal``) hides the keys more
     than ``window - 1`` positions behind a query. ``block_q`` /
     ``block_k`` left ``None`` are :func:`tile_for`'s, from the shapes; a
-    caller's own are taken as given.
+    caller's own are taken as given. ``scale`` (static) multiplies the
+    scores before the softmax; ``None`` is ``1 / sqrt(D)``.
 
     Fully-masked rows (causal with ``seq_q > seq_k``: queries before the
     first key) return **zeros** — the flash/blockwise convention shared
@@ -335,12 +337,14 @@ def flash_attention(q, k, v, block_q=None, block_k=None, causal=False,
     if interpret is None:
         from ..kernels.tier import resolve_interpret
         interpret = resolve_interpret()
-    return _flash_fwd(q, k, v, block_q, block_k, causal, interpret, window)
+    return _flash_fwd(q, k, v, block_q, block_k, causal, interpret, window,
+                      scale)
 
 
 def _flash_bwd(q, k, v, o, do, causal, block_q=512, block_k=512,
-               window=None):
-    """Gradients of ``softmax(q k^T / sqrt(d)) v`` (masked as the forward
+               window=None, scale=None):
+    """Gradients of ``softmax(scale q k^T) v`` (``scale`` None: ``1 /
+    sqrt(d)``; masked as the forward
     masks) from the output and its cotangent, blockwise: for each block of
     queries, one pass over the key blocks it may see for the rows' log sum
     of exponentials, and one for ``dv += p^T do``, ``ds = p (do v^T -
@@ -359,7 +363,7 @@ def _flash_bwd(q, k, v, o, do, causal, block_q=512, block_k=512,
         q, o, do = (x.reshape((b, h_kv, group) + x.shape[2:])
                     for x in (q, o, do))
     f32 = jnp.float32
-    scale = 1.0 / math.sqrt(d)
+    scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
     bq, bk = min(block_q, tq), min(block_k, tk)
     pad_q, pad_k = (-tq) % bq, (-tk) % bk
     off = tk - tq
@@ -448,17 +452,17 @@ def _flash_bwd(q, k, v, o, do, causal, block_q=512, block_k=512,
             dvv[:, :, :tk].astype(v.dtype))
 
 
-def _fwd(q, k, v, block_q, block_k, causal, interpret, window):
+def _fwd(q, k, v, block_q, block_k, causal, interpret, window, scale):
     # inside a mirror_stage the output is kept and the kernel is not run
     # again in the backward pass; q, k, v are recomputed like the rest
     o = stage_keep(flash_attention(q, k, v, block_q, block_k, causal,
-                                   interpret, window))
+                                   interpret, window, scale))
     return o, (q, k, v, o)
 
 
-def _bwd(block_q, block_k, causal, interpret, window, res, g):
+def _bwd(block_q, block_k, causal, interpret, window, scale, res, g):
     q, k, v, o = res
-    return _flash_bwd(q, k, v, o, g, causal, window=window)
+    return _flash_bwd(q, k, v, o, g, causal, window=window, scale=scale)
 
 
 flash_attention.defvjp(_fwd, _bwd)
@@ -485,12 +489,14 @@ program_gauge("attn/grid_steps",
 # eager/symbolic surface: mx.nd._contrib_FlashAttention(q, k, v, causal=...)
 @_register("_contrib_FlashAttention")
 def _contrib_flash_attention(q, k, v, *, causal=False, block_q=None,
-                             block_k=None, window=None):
+                             block_k=None, window=None, scale=None):
     """(B, H, T, D) flash attention as a registered op (pallas on TPU);
     ``v`` may be (B, H, T, Dv) of another width than q and k share, ``k``
     and ``v`` may carry ``H_kv`` heads with ``H % H_kv == 0`` (query head
-    ``i`` reads KV head ``i // (H / H_kv)``), and ``window`` (with
-    ``causal``) hides the keys more than ``window - 1`` behind a query.
+    ``i`` reads KV head ``i // (H / H_kv)``), ``window`` (with
+    ``causal``) hides the keys more than ``window - 1`` behind a query,
+    and ``scale`` multiplies the scores before the softmax in the place of
+    ``1 / sqrt(D)`` (Granite's ``attention_multiplier``).
 
     Tier-aware: under ``MXNET_KERNEL_TIER=safe|auto`` the call dispatches
     to the kernel-tier attention (kernels/attention.py — the
@@ -502,8 +508,9 @@ def _contrib_flash_attention(q, k, v, *, causal=False, block_q=None,
     block sizes, unchanged — eligibility rejections (e.g. causal
     cross-length) take the same legacy path and the reason lands in
     ``tier.stats()['fallback']``. A window or grouped heads are this
-    module's kernel's alone."""
+    module's kernel's alone, as is a ``scale`` of the caller's."""
     window = None if window is None else int(window)
+    scale = None if scale is None else float(scale)
     program_count("attn/full_layers" if window is None
                   else "attn/window_layers")
     tq, tk = q.shape[2], k.shape[2]
@@ -514,10 +521,10 @@ def _contrib_flash_attention(q, k, v, *, causal=False, block_q=None,
         visited, plain = blocks_visited(tq, tk, *tile, window)
         program_count("attn/kv_blocks_visited", visited)
         program_count("attn/kv_blocks_causal", plain)
-    if window is None and k.shape[1] == q.shape[1]:
+    if window is None and scale is None and k.shape[1] == q.shape[1]:
         from ..kernels import attention as _attn
         out = _attn.attend_or_none(q, k, v, causal=bool(causal))
         if out is not None:
             return out
     return flash_attention(q, k, v, block_q, block_k, bool(causal), None,
-                           window)
+                           window, scale)

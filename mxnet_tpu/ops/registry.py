@@ -27,7 +27,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 __all__ = ["Operator", "register", "get", "list_ops", "alias",
            "STAGE_KEEP", "stage_keep", "stage_marks", "PROGRAM_GAUGES",
-           "program_gauge", "program_count", "program_counts"]
+           "program_gauge", "program_count", "program_max",
+           "program_counts"]
 
 _REGISTRY: dict[str, "Operator"] = {}
 
@@ -81,6 +82,14 @@ def program_count(name, n=1):
     counts = getattr(_marks, "counts", None)
     if counts is not None:
         counts[name] = counts.get(name, 0) + n
+
+
+def program_max(name, n):
+    """``name`` at least ``n`` in the program being traced: for what a
+    program has one of however many of its ops report it."""
+    counts = getattr(_marks, "counts", None)
+    if counts is not None:
+        counts[name] = max(counts.get(name, 0), n)
 
 
 @contextlib.contextmanager

@@ -2,6 +2,7 @@
 (parity: python/mxnet/symbol/register.py codegen)."""
 from __future__ import annotations
 
+import inspect
 import sys
 
 from ..ops import registry as _registry
@@ -64,7 +65,10 @@ def _should_autocreate(op, slot_name, optional, params):
     if not optional:
         return True  # required array input with no symbol given -> variable
     if slot_name == "bias":
-        return not params.get("no_bias", op.name == "Deconvolution")
+        # the op's own default of ``no_bias`` where the caller gives none:
+        # Deconvolution and the causal convolution carry no bias unless asked
+        own = inspect.signature(op.fn).parameters.get("no_bias")
+        return not params.get("no_bias", bool(own is not None and own.default))
     if slot_name == "label":
         return True  # loss heads auto-create their label variable
     if slot_name == "state_cell":

@@ -255,6 +255,8 @@ def test_grid_steps_are_query_blocks_times_the_longest_span(args, steps):
 # (tq, tk, d, dv, itemsize, window) -> (block_q, block_k)
 @pytest.mark.parametrize("shape,tile", [
     ((8192, 8192, 128, 128, 2, None), (1024, 1024)),   # Trinity's full layer
+    ((8192, 8192, 64, 64, 2, None), (1024, 1024)),     # Granite's: heads of 64
+    ((8192, 8192, 64, 64, 4, None), (1024, 1024)),     # float32: 14.8 MiB
     ((8192, 8192, 128, 128, 2, 2048), (1024, 1024)),   # and its window layers
     ((8192, 8192, 192, 128, 2, None), (1024, 1024)),   # Kimi's MLA layer
     ((8192, 8192, 128, 128, 4, None), (1024, 1024)),   # float32: 15.5 MiB
@@ -360,3 +362,65 @@ def test_registered_op_takes_window_and_grouped_heads():
     np.testing.assert_allclose(
         out.asnumpy(), np.asarray(_masked_reference(q, k, v, 40)),
         rtol=2e-5, atol=2e-5)
+
+
+# ---- a scale of the caller's (Granite's attention_multiplier)
+# (query heads, KV heads, tokens, width, scale, window, query block, key
+# block): Granite's 1/64 at heads of 64, four query heads a KV head (its 32
+# over 8), more than one block and the shapes' own tile; a scale with a
+# window; a scale above 1 / sqrt(d)
+_SCALED = [(8, 2, 200, 64, 0.015625, None, 64, 64),
+           (32, 8, 130, 64, 0.015625, None, None, None),
+           (4, 2, 192, 32, 0.05, 70, 64, 32),
+           (4, 4, 256, 16, 1.0, None, 64, 64)]
+
+
+@pytest.mark.parametrize("h,hk,t,d,scale,window,bq,bk", _SCALED)
+def test_a_given_scale_multiplies_the_scores_forward_and_backward(
+        h, hk, t, d, scale, window, bq, bk):
+    """Against the dense masked softmax of ``scale q k^T``: the output,
+    and the three gradients through the custom_vjp and through the
+    blockwise backward at the forward's blocks."""
+    from mxnet_tpu.ops.pallas_flash import _flash_bwd
+    q, k, v = _grouped(h, hk, t, d)
+    q = q * 4       # scores wide enough that the scale shows
+
+    def dense(q, k, v):     # _masked_reference divides by sqrt(d)
+        return _masked_reference(q * (scale * np.sqrt(d)), k, v, window)
+    want, pull_dense = jax.vjp(dense, q, k, v)
+    got, pull = jax.vjp(lambda *a: flash_attention(
+        *a, bq, bk, True, None, window, scale), q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    unscaled = flash_attention(q, k, v, bq, bk, True, None, window)
+    assert float(jnp.max(jnp.abs(unscaled - want))) > 1e-2
+    do = jnp.asarray(np.random.RandomState(1).randn(*want.shape)
+                     .astype(np.float32))
+    wants = pull_dense(do)
+    for gots in (pull(do), _flash_bwd(q, k, v, got, do, True, bq or 512,
+                                      bk or 512, window, scale)):
+        for name, a, b in zip("qkv", gots, wants):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_default_scale_is_one_over_the_root_of_the_width(dtype):
+    """``scale=None`` and ``scale = 1 / sqrt(d)`` are one program, bit for
+    bit, forward and gradients; the registered op passes the keyword on
+    (the pinned digests above hold the default to the kernel of before)."""
+    from mxnet_tpu.ops import registry
+    op = registry.get("_contrib_FlashAttention").fn
+    q, k, v = (x.astype(dtype) for x in _grouped(4, 2, 192, 64))
+
+    def run(**kw):
+        out, pull = jax.vjp(lambda *a: op(*a, causal=True, block_q=64,
+                                          block_k=64, **kw), q, k, v)
+        return (out,) + pull(jnp.ones_like(out))
+    for a, b in zip(run(), run(scale=0.125)):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    other = run(scale=0.015625)[0]
+    assert float(jnp.max(jnp.abs(other.astype(jnp.float32)
+                                 - run()[0].astype(jnp.float32)))) > 1e-2
