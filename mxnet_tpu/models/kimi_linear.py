@@ -106,7 +106,7 @@ def _experts(x, p, hidden, inter, n_experts, top_k, held, scale):
         gate_weight=_var(p + "moe_gate_weight", (n, inter, hidden)),
         up_weight=_var(p + "moe_up_weight", (n, inter, hidden)),
         down_weight=_var(p + "moe_down_weight", (n, hidden, inter)),
-        counters=_var(p + "moe_counters", (3,), init=_init.Zero()),
+        counters=_var(p + "moe_counters", (4,), init=_init.Zero()),
         experts_held=(lo, hi), top_k=top_k, scale=scale, name=p + "moe")
     return _swiglu(x, p + "shared", hidden, inter) + routed
 

@@ -600,22 +600,26 @@ def _swiglu_op(data, gate_weight, up_weight, down_weight):
 def _moe_op(data, router_weight, router_bias, gate_weight, up_weight,
             down_weight, counters, *, experts_held, top_k, scale=1.0):
     """:func:`mxnet_tpu.parallel.moe.expert_layer` over (B, T, d) as a
-    registered op. The auxiliary state ``counters`` (3,) is carried on the
+    registered op. The auxiliary state ``counters`` (4,) is carried on the
     device and never read by the host in a step: steps seen, then a step's
-    running mean of the assignments this rank held and of the largest
-    number of tokens one held expert received (float32 like every
-    auxiliary state: a mean stays as exact after a million steps as after
-    one, where a sum would pass 2**24 in two thousand)."""
-    from ..parallel.moe import expert_layer
+    running mean of the assignments this rank held, of the largest number
+    of tokens one held expert received, and of the sorted assignments the
+    layer's blocks passed over (float32 like every auxiliary state: a mean
+    stays as exact after a million steps as after one, where a sum would
+    pass 2**24 in two thousand)."""
+    from ..parallel import moe
     b, t, d = data.shape
-    y, counts = expert_layer(
+    lo, hi = (int(v) for v in experts_held)
+    y, counts = moe.expert_layer(
         data.reshape(b * t, d), router_weight, router_bias, gate_weight,
-        up_weight, down_weight,
-        experts_held=tuple(int(v) for v in experts_held), top_k=int(top_k),
+        up_weight, down_weight, experts_held=(lo, hi), top_k=int(top_k),
         scale=float(scale))
     steps = counters[:1] + 1
-    seen = jnp.stack([jnp.sum(counts), jnp.max(counts)]).astype(
-        counters.dtype)
+    block = moe.block_rows(b * t, int(top_k), hi - lo,
+                           router_weight.shape[0], gate_weight.shape[1])
+    seen = jnp.stack([jnp.sum(counts), jnp.max(counts),
+                      moe.trips(counts, block) * block]).astype(
+                          counters.dtype)
     means = counters[1:] + (seen - counters[1:]) / steps
     return y.reshape(b, t, d), jnp.concatenate([steps, means])
 
@@ -633,5 +637,10 @@ set_op_meta("_contrib_MoE", aux_inputs=(6,), aux_outputs=(1,),
                  "experts this rank holds, mean over the expert layers"),
                 ("moe/max_expert_tokens",
                  "tokens a step that the busiest held expert received, "
-                 "mean over the expert layers"))),),
-            shape_hook=lambda ins, p: list(ins[:6]) + [ins[6] or (3,)])
+                 "mean over the expert layers"),
+                ("moe/rows_visited",
+                 "sorted assignments a step that the expert layer's "
+                 "blocks passed over (trips x block), mean over the "
+                 "expert layers; over moe/assignments_held: 1 is no "
+                 "row passed over in vain"))),),
+            shape_hook=lambda ins, p: list(ins[:6]) + [ins[6] or (4,)])

@@ -102,8 +102,8 @@ def test_fit_follows_the_reference_losses_and_three_adam_steps(cfg, fitted):
             <= 0.02 * np.linalg.norm(moved) + 1e-12, k
     assert len(aux) == 4            # the expert layers' counters
     for name, v in aux.items():
-        steps_seen, held, largest = v.asnumpy()
-        assert steps_seen == 3 and 0 < largest <= held, name
+        steps_seen, held, largest, visited = v.asnumpy()
+        assert steps_seen == 3 and 0 < largest <= held <= visited, name
 
 
 def test_every_leafs_gradient_is_the_references(cfg, fitted):

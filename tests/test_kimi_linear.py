@@ -148,7 +148,8 @@ def fitted(cfg):
     mod = _fit(cfg, _symbol(cfg), w0, data, label, 3, each_step)
     seen.update(mod=mod, w0=w0, ref=_ref_steps(cfg, w0, data, label, 3),
                 held=telemetry.gauge("moe/assignments_held").value(),
-                largest=telemetry.gauge("moe/max_expert_tokens").value())
+                largest=telemetry.gauge("moe/max_expert_tokens").value(),
+                visited=telemetry.gauge("moe/rows_visited").value())
     return seen
 
 
@@ -170,8 +171,8 @@ def test_fit_follows_the_reference_losses_and_three_adam_steps(cfg, fitted):
             <= 0.02 * np.linalg.norm(moved) + 1e-12, k
     # the counters: three steps, the held experts' assignments a step
     for name, v in aux.items():
-        steps_seen, held, largest = v.asnumpy()
-        assert steps_seen == 3 and 0 < largest <= held, name
+        steps_seen, held, largest, visited = v.asnumpy()
+        assert steps_seen == 3 and 0 < largest <= held <= visited, name
 
 
 def test_every_leafs_gradient_is_the_references(cfg, fitted):
@@ -705,11 +706,18 @@ def test_routing_however_skewed_is_one_exact_grouped_product():
     assert 0 < int(c2.min()) and int(c2.sum()) < n
     np.testing.assert_allclose(np.asarray(y2), np.asarray(masked(x2, even)),
                                rtol=2e-4, atol=2e-5)
-    # the layer is one program for any routing: no branch on the counts
-    eqns = jax.make_jaxpr(lambda *a: moe.expert_layer(*a, **kw))(
-        x, jnp.asarray(w_r), jnp.zeros(e), wg, wu, wd).jaxpr.eqns
+    # the layer is one program for any routing: no branch on the counts,
+    # one loop over the blocks that hold a row, three products a block
+    eqns = list(_every_eqn(jax.make_jaxpr(
+        lambda *a: moe.expert_layer(*a, **kw))(
+            x, jnp.asarray(w_r), jnp.zeros(e), wg, wu, wd).jaxpr))
     names = [q.primitive.name for q in eqns]
-    assert "cond" not in names and names.count("ragged_dot_general") == 3
+    assert "cond" not in names and names.count("while") == 1
+    loop, = (q for q in eqns if q.primitive.name == "while")
+    inside = [q.primitive.name
+              for q in _every_eqn(loop.params["body_jaxpr"].jaxpr)]
+    assert inside.count("ragged_dot_general") == 3 \
+        and names.count("ragged_dot_general") == 3
 
 
 def test_expert_layer_gradients_reach_router_and_experts():
@@ -737,6 +745,165 @@ def test_expert_layer_gradients_reach_router_and_experts():
         np.testing.assert_allclose(np.asarray(a), np.asarray(w), rtol=2e-3,
                                    atol=2e-4)
     assert float(jnp.max(jnp.abs(got[1]))) > 0      # the router learns
+
+
+def _hand_routed(monkeypatch, total, n, e=8, top_k=2, lo=2):
+    """``moe.route`` replaced by a routing made by hand: the first
+    ``total`` assignments (token by token, slot by slot) fall to the held
+    experts ``lo + slot``, every other one to an absent expert; the
+    weights are the router's own, so its gradient flows."""
+    from mxnet_tpu.parallel import moe
+    slot = np.arange(n * top_k) % top_k
+    chosen = np.where(np.arange(n * top_k) < total, lo + slot,
+                      np.where(slot == 0, 0, e - 1)).reshape(n, top_k)
+    chosen = jnp.asarray(chosen, jnp.int32)
+
+    def route(x, router_weight, router_bias, top_k, scale):
+        s = jax.nn.sigmoid(x @ router_weight.T)
+        picked = jnp.take_along_axis(s, chosen, axis=-1)
+        return chosen, scale * picked / jnp.sum(picked, -1, keepdims=True)
+    monkeypatch.setattr(moe, "route", route)
+    return route
+
+
+WALK_BLOCK = 16
+
+
+@pytest.mark.parametrize("n,total", [
+    (24, 0), (24, 1), (24, WALK_BLOCK), (24, WALK_BLOCK + 1), (24, 48),
+    (25, 50)], ids=["none", "one", "a_block", "a_block_and_one",
+                    "every_assignment", "blocks_do_not_divide"])
+def test_the_block_walk_is_the_masked_sum_forward_and_backward(
+        n, total, monkeypatch):
+    """The loop over blocks of 16 sorted assignments against the masked
+    sum over the held experts, result and all five gradients, from no
+    held assignment to every one (the most trips) and a last block that
+    the assignments do not fill; one block of all ``n * k`` rows gives
+    the same to rounding."""
+    from mxnet_tpu.parallel import moe
+    rng = np.random.RandomState(total)
+    hid, inter, e, top_k = 8, 4, 8, 2
+    route = _hand_routed(monkeypatch, total, n, e, top_k)
+    x = jnp.asarray(rng.randn(n, hid).astype("f4"))
+    w_r = jnp.asarray(rng.randn(e, hid).astype("f4"))
+    wg, wu = (jnp.asarray(rng.randn(2, inter, hid).astype("f4"))
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(2, hid, inter).astype("f4"))
+    kw = dict(experts_held=(2, 4), top_k=top_k, scale=2.0)
+
+    def plain(x, w_r, wg, wu, wd):
+        ch, wt = route(x, w_r, None, top_k, 2.0)
+        return sum(moe.swiglu(x, wg[i], wu[i], wd[i]) * jnp.sum(
+            jnp.where(ch == 2 + i, wt, 0.0), -1, keepdims=True)
+            for i in (0, 1))
+
+    def walked(block):
+        monkeypatch.setattr(moe, "block_rows", lambda *_a: block)
+
+        def layer(x, w_r, wg, wu, wd):
+            return moe.expert_layer(x, w_r, jnp.zeros(e), wg, wu, wd, **kw)
+        y, counts = layer(x, w_r, wg, wu, wd)
+        return (y, counts) + jax.grad(
+            lambda *a: jnp.sum(layer(*a)[0] ** 2), range(5))(
+                x, w_r, wg, wu, wd)
+    y, counts, *got = walked(WALK_BLOCK)
+    assert int(counts.sum()) == total
+    assert int(moe.trips(counts, WALK_BLOCK)) == -(-total // WALK_BLOCK)
+    want = jax.grad(lambda *a: jnp.sum(plain(*a) ** 2), range(5))(
+        x, w_r, wg, wu, wd)
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(plain(x, w_r, wg, wu, wd)),
+                               rtol=2e-4, atol=2e-5)
+    for name, a, w in zip(("x", "router", "gate", "up", "down"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), rtol=1e-3,
+                                   atol=1e-4, err_msg=name)
+    y1, _, *one = walked(n * top_k)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y1), rtol=1e-5,
+                               atol=1e-6)
+    for name, a, w in zip(("x", "router", "gate", "up", "down"), got, one):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), rtol=1e-5,
+                                   atol=1e-5, err_msg=name)
+
+
+def test_no_value_of_the_layer_is_every_assignment_tall():
+    """Forward and backward traced at the block the layer takes from its
+    own shapes: 2,048 assignments, 2 of 16 experts held, blocks of 512.
+    Nothing ``n * k`` rows by the model's or the experts' width exists,
+    loop bodies included; what a block makes is ``B`` rows tall."""
+    from mxnet_tpu.parallel import moe
+    n, hid, inter, e, top_k, held = 1024, 24, 12, 16, 2, 2
+    block = moe.block_rows(n, top_k, held, e, inter)
+    assert block == 512 and n * top_k == 4 * block
+    f32 = jnp.float32
+    shapes = [jax.ShapeDtypeStruct(s, f32) for s in (
+        (n, hid), (e, hid), (e,), (held, inter, hid), (held, inter, hid),
+        (held, hid, inter))]
+    traced = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(moe.expert_layer(
+            *a, experts_held=(4, 6), top_k=top_k)[0] ** 2),
+        (0, 1, 3, 4, 5)))(*shapes)
+    tall = {}
+    for q in _every_eqn(traced.jaxpr):
+        for v in q.outvars:
+            shape = getattr(v.aval, "shape", ())
+            if len(shape) == 2 and shape[1] in (hid, inter):
+                tall.setdefault(shape[0], set()).add(q.primitive.name)
+    assert n * top_k not in tall, tall[n * top_k]
+    assert "ragged_dot_general" in tall[block]
+    assert max(tall) == n                  # the tokens themselves
+    # a rank that holds every expert: one block of every assignment
+    assert moe.block_rows(n, top_k, e, e, inter) == n * top_k
+    # the cells: two even shares (Trinity's 16 of 128 experts), the held
+    # experts' 8 x 1,024 hidden units (Kimi's 8 of 256)
+    assert moe.block_rows(8192, 8, 16, 128, 1024) == 16384
+    assert moe.block_rows(8192, 8, 8, 256, 1024) == 8192
+    assert moe.block_rows(8192, 8, 8, 256, 100) == 4096
+    rng = np.random.RandomState(2)
+    x, w_r = (jnp.asarray(rng.randn(*s.shape).astype("f4"))
+              for s in shapes[:2])
+    whole = [jnp.asarray(rng.randn(e, *s.shape[1:]).astype("f4"))
+             for s in shapes[3:]]
+    _, counts = moe.expert_layer(x, w_r, jnp.zeros(e), *whole,
+                                 experts_held=(0, e), top_k=top_k)
+    assert int(counts.sum()) == n * top_k
+    assert int(moe.trips(counts, n * top_k)) == 1
+
+
+def test_rows_visited_is_whole_blocks_and_reaches_the_metrics(
+        cfg, fitted, monkeypatch):
+    """The op's state on a routing made by hand: [steps, held, busiest,
+    trips x block]; then the same gauge after the fit's epoch."""
+    from mxnet_tpu.ops import registry
+    from mxnet_tpu.parallel import moe
+    n, hid, inter, e, top_k = 24, 8, 4, 8, 2
+    _hand_routed(monkeypatch, WALK_BLOCK + 1, n, e, top_k)
+    monkeypatch.setattr(moe, "block_rows", lambda *_a: WALK_BLOCK)
+    rng = np.random.RandomState(0)
+    arrays = [jnp.asarray(rng.randn(*s).astype("f4")) for s in (
+        (1, n, hid), (e, hid), (e,), (2, inter, hid), (2, inter, hid),
+        (2, hid, inter))]
+    _, state = registry.get("_contrib_MoE").fn(
+        *arrays, jnp.zeros(4), experts_held=(2, 4), top_k=top_k)
+    assert state.tolist() == [1.0, WALK_BLOCK + 1, 9.0, 2 * WALK_BLOCK]
+    _, state = registry.get("_contrib_MoE").fn(
+        *arrays, state, experts_held=(2, 4), top_k=top_k)
+    assert state.tolist() == [2.0, WALK_BLOCK + 1, 9.0, 2 * WALK_BLOCK]
+    monkeypatch.undo()
+    # the tiny model: 256 assignments a layer, one block of them all
+    every = B * T * cfg["num_experts_per_token"]
+    lo, hi = cfg["experts_held"]
+    assert moe.block_rows(B * T, cfg["num_experts_per_token"], hi - lo,
+                          cfg["num_experts_published"],
+                          cfg["moe_intermediate_size"]) == every
+    assert fitted["visited"] == every
+    assert fitted["mod"]._op_counters()["moe/rows_visited"][0] == every
+    from mxnet_tpu.telemetry import prom
+    assert "mxtpu_moe_rows_visited %d" % every in prom.exposition()
+    # where the benchmark's readers find the two older gauges
+    _args, aux = fitted["mod"].get_params()
+    for v in aux.values():
+        steps, held, busiest, visited = v.asnumpy().tolist()
+        assert steps == 3 and 0 < busiest <= held <= visited == every
 
 
 def test_head_loss_is_cross_entropy_and_never_the_whole_logits():
