@@ -110,6 +110,15 @@ def _mirror_stages(nodes, entries):
     return out
 
 
+def _device_scope(node):
+    """The name a node's device ops run under: the builder's
+    (``mx.AttrScope(device_scope=...)`` names a generic op by the layer it
+    serves) where the node carries one, else ``mx/op/`` and the op's
+    registered name. Never both: a reader that adds scopes up counts a
+    device op once."""
+    return node.attrs.get("device_scope") or "mx/op/" + node.op.name
+
+
 def _graph_eval_fn(symbol):
     """Build eval(arg_vals, aux_vals, key, training) -> (outputs, aux_updates).
 
@@ -122,9 +131,9 @@ def _graph_eval_fn(symbol):
     ``stage/kept_values`` and ``stage/kept_mb`` say what the marked values
     of the program traced last come to, and the gauges its ops declared
     (``registry.PROGRAM_GAUGES``: ``kda/intra_kernel``, ``kda/intra_plain``)
-    what they counted in it. A node that carries ``device_scope``
-    runs under ``jax.named_scope`` of that name, so a builder names the
-    device ops of a generic op by the layer they serve.
+    what they counted in it. Every op node runs under a
+    ``jax.named_scope`` (:func:`_device_scope`), so that each device op of
+    the compiled program says in its ``op_name`` which node it serves.
     """
     nodes = symbol._topo()
     entries = list(symbol._entries)
@@ -161,11 +170,7 @@ def _graph_eval_fn(symbol):
             params = dict(node.params)
             if "_training" in node.op.param_names:
                 params["_training"] = training
-            scope = node.attrs.get("device_scope")
-            if scope:   # the builder's name for the node's device ops
-                with jax.named_scope(scope):
-                    out = node.op.fn(*ins, **params)
-            else:
+            with jax.named_scope(_device_scope(node)):
                 out = node.op.fn(*ins, **params)
             values[id(node)] = out
             route_aux(node, out)
@@ -189,9 +194,12 @@ def _graph_eval_fn(symbol):
             if id(node) in kdeferred:
                 continue    # forced lazily only if a guard rejects
             kp = kplan.get(id(node))
-            if kp is not None and _gfuse.try_eval(
-                    kp, node, read, values, route_aux, training):
-                continue
+            if kp is not None:
+                with jax.named_scope(_device_scope(node)):
+                    fused = _gfuse.try_eval(kp, node, read, values,
+                                            route_aux, training)
+                if fused:
+                    continue
             force(node)
         return aux_updates, read
 

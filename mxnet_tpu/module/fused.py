@@ -26,6 +26,13 @@ import numpy as _np
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry as _telemetry
+
+_PARAM_BYTES = ("bytes of the parameters that the fused training step "
+                "traced last updates, from the shapes and dtypes it was "
+                "traced with")
+_STATE_BYTES = "bytes of the optimizer state the same step holds for them"
+
 
 def _flatten_state(state):
     """Eager create_state result -> fused state tuple (see the contract in
@@ -35,6 +42,12 @@ def _flatten_state(state):
     if isinstance(state, tuple):
         return state
     return (state,)
+
+
+def _nbytes(tree):
+    """Bytes the arrays of ``tree`` hold, from their shapes and dtypes."""
+    return sum(v.size * v.dtype.itemsize
+               for v in jax.tree_util.tree_leaves(tree))
 
 
 class FusedStep:
@@ -113,21 +126,29 @@ class FusedStep:
 
         def step(params, rest, aux_vals, opt_state, met_state, lr_vec,
                  wd_vec, rescale, t, key):
+            # The device ops of the step outside the graph's nodes run
+            # under four named scopes (``mx/cast``, ``mx/allreduce``,
+            # ``mx/opt``, ``mx/metric``), as every node runs under its own
+            # (executor._device_scope): names on the compiled program's
+            # instructions, which change none of them.
             diff = params
             if cdt is not None:
-                rest = {k: (v.astype(cdt)
-                            if k in dnames and v.dtype == jnp.float32 else v)
-                        for k, v in rest.items()}
+                with jax.named_scope("mx/cast"):
+                    rest = {k: (v.astype(cdt)
+                                if k in dnames and v.dtype == jnp.float32
+                                else v)
+                            for k, v in rest.items()}
 
             def f(d):
                 if cdt is not None:
                     # each master at its own shape: XLA fuses the convert
                     # into its consumer, and the transpose of `convert`
                     # hands the optimizer an f32 gradient of the same shape
-                    d = {k: (v.astype(cdt)
-                             if v.dtype == jnp.float32 and k not in keepf
-                             and v.size > 0 else v)
-                         for k, v in d.items()}
+                    with jax.named_scope("mx/cast"):
+                        d = {k: (v.astype(cdt)
+                                 if v.dtype == jnp.float32 and k not in keepf
+                                 and v.size > 0 else v)
+                             for k, v in d.items()}
                 return eval_fn({**rest, **d}, aux_vals, key, True)
 
             from ..executor import mirror_wrap
@@ -146,14 +167,21 @@ class FusedStep:
                 # (the ps-lite server aggregation, collapsed into the step).
                 # Each psum depends only on its own bucket's grads, so the
                 # scheduler may hoist it over the rest of the backward.
-                grads = reducer.reduce(grads)
+                with jax.named_scope("mx/allreduce"):
+                    grads = reducer.reduce(grads)
             new_params = {}
             new_opt = {}
-            for i, k in enumerate(pnames):
-                nw, ns = update(params[k], grads[k], opt_state[k],
-                                lr_vec[i], wd_vec[i], rescale, t)
-                new_params[k] = nw.astype(params[k].dtype)
-                new_opt[k] = ns
+            with jax.named_scope("mx/opt"):
+                for i, k in enumerate(pnames):
+                    nw, ns = update(params[k], grads[k], opt_state[k],
+                                    lr_vec[i], wd_vec[i], rescale, t)
+                    new_params[k] = nw.astype(params[k].dtype)
+                    new_opt[k] = ns
+            # set, not added: a retrace counts the same arrays
+            _telemetry.gauge("opt/param_bytes", _PARAM_BYTES).set(
+                _nbytes([params[k] for k in pnames]))
+            _telemetry.gauge("opt/state_bytes", _STATE_BYTES).set(
+                _nbytes([opt_state[k] for k in pnames]))
             new_aux = {**aux_vals, **auxu}
             # metric carry update happens in the SAME program, over the
             # traced outputs/labels — no host round-trip. met_state=None
@@ -161,7 +189,8 @@ class FusedStep:
             # the public forward_backward path never accumulates.
             new_met = met_state
             if met_fn is not None and met_state is not None:
-                new_met = met_fn(met_state, outs, rest)
+                with jax.named_scope("mx/metric"):
+                    new_met = met_fn(met_state, outs, rest)
             return outs, new_params, new_aux, new_opt, new_met
 
         # Shardings are not pinned here: the executor commits params/aux/

@@ -593,6 +593,10 @@ def _swiglu_op(data, gate_weight, up_weight, down_weight):
     """``(silu(x Wg^T) * (x Wu^T)) Wd^T`` over the last axis; the
     matrices stored (out, in) as FullyConnected's."""
     from ..parallel.moe import swiglu
+    # three products a row: two of in x hidden, one of hidden x out
+    program_count("dense/flops_fwd", 2 * data.size * (
+        gate_weight.shape[0] + up_weight.shape[0]) + 2 * (
+            data.size // data.shape[-1]) * down_weight.size)
     return swiglu(data, gate_weight, up_weight, down_weight)
 
 

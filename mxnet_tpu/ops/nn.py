@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .registry import register, alias
+from .registry import register, alias, program_count, program_gauge
 from .. import random as _random
 
 
@@ -40,6 +40,7 @@ def fully_connected(data, weight, bias=None, *, num_hidden=0, no_bias=False,
     else:
         x = data
     # weight layout: (num_hidden, in_units) — reference convention
+    program_count("dense/flops_fwd", 2 * x.size * weight.shape[0])
     out = jnp.matmul(x, weight.T)
     if not no_bias and bias is not None:
         out = out + bias
@@ -47,6 +48,10 @@ def fully_connected(data, weight, bias=None, *, num_hidden=0, no_bias=False,
 
 
 alias("FullyConnected", "fully_connected")
+program_gauge("dense/flops_fwd",
+              "operations of the forward pass of every FullyConnected and "
+              "_contrib_SwiGLU of the training program traced last: 2 x "
+              "rows x in x out a product, from shapes, whatever runs it")
 
 
 # ---------------------------------------------------------------------------

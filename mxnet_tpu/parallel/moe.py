@@ -69,7 +69,11 @@ def route(x, router_weight, router_bias, top_k, scale):
 
 def _grouped(rows, w, counts):
     """rows (R, in) x the group's own matrix of w (G, out, in), groups of
-    ``counts`` rows in turn; rows past the last group belong to none."""
+    ``counts`` rows in turn; rows past the last group belong to none.
+    Traced under ``mx/moe/experts`` like all of the walk, forward and
+    backward; the TPU compiler makes each product a kernel call of its
+    own and names it itself (``op_name="ragged-dot-none"``), so in the
+    compiled text the scope stops here (docs/observability.md)."""
     return lax.ragged_dot(rows, jnp.swapaxes(w, 1, 2), counts)
 
 

@@ -67,3 +67,33 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     text = jax.jit(case.fn).lower(*args).compile().as_text()
     # a program without the custom call would mean the kernel was bypassed
     assert "tpu_custom_call" in text
+
+
+def test_the_compiler_names_the_grouped_products_itself(one_chip):
+    """Where the expert layer's scope stops (tests/test_device_scopes.py):
+    the program traces ``lax.ragged_dot`` under ``mx/moe/experts``, and the
+    TPU compiler makes each a kernel call of its own, ``%ragged-dot-none``,
+    with ``op_name="ragged-dot-none"`` in place of the name stack. What is
+    traced beside them keeps the scope. If this fails the compiler has
+    begun to keep the name: then ``moe_ms.train`` counts the products."""
+    import re
+    import jax.numpy as jnp
+    from mxnet_tpu.parallel import moe
+    n, d, experts, held, h, k = 1024, 256, 16, 4, 256, 2
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, rw, rb, wg, wu, wd):
+        y, _ = moe.expert_layer(x, rw, rb, wg, wu, wd,
+                                experts_held=(0, held), top_k=k)
+        return jnp.sum(y.astype(jnp.float32))
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 3, 4, 5))).lower(
+        S((n, d)), S((experts, d), jnp.float32), S((experts,), jnp.float32),
+        S((held, h, d)), S((held, h, d)), S((held, d, h))).compile().as_text()
+    products = re.findall(
+        r'^\s*%(ragged-dot-none[.\d]*) = .*metadata=\{op_name="([^"]*)"',
+        text, re.M)
+    assert len(products) >= 9       # three a pass: forward, again, backward
+    assert {op for _, op in products} == {"ragged-dot-none"}
+    assert re.search(r'op_name="[^"]*mx/moe/experts[^"]*"', text)
