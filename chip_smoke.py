@@ -482,6 +482,16 @@ def _kda_tiles(q, k, v, g, beta):
             0.05 + 0.9 * cdf(beta))
 
 
+def _kda_scanned(s0, u0, w, m, q_in, k_out, g_end):
+    """Normals as what the scan over a KDA group's chunks takes: the
+    products' left factors small enough that the state neither dies nor
+    grows over the group, a chunk's log decay below -0.05."""
+    import jax.numpy as jnp
+    c, dk = w.shape[-2:]
+    return (s0, u0, w * (0.5 * dk ** -0.5), m * c ** -0.5, q_in * dk ** -0.5,
+            k_out * (0.5 * c ** -0.5), -0.05 - jnp.abs(g_end))
+
+
 def kernel_cases():
     """Every Pallas kernel left in the tree, at real widths. Touches no
     device: tests/test_tpu_aot_compile.py compiles the same table for a
@@ -658,8 +668,8 @@ def kernel_cases():
     # widest head with the longest chunk, and eight short chunks a tile.
     # float32 with fp32-contract products on both sides: held to 1e-4,
     # some twenty times what the chip read (5e-6)
-    def pulled(f):
-        return lambda *a: jax.vjp(f, *a[:5])[1](tuple(a[5:]))
+    def pulled(f, n=5):     # the gradients of the first n arguments
+        return lambda *a: jax.vjp(f, *a[:n])[1](tuple(a[n:]))
 
     for b, t, h, d, chunk, sub in [(1, 1024, 32, 128, 64, 16),
                                    (1, 256, 8, 256, 128, 16),
@@ -667,8 +677,7 @@ def kernel_cases():
         wide = ((b, t, h, d), f32, "normal")
         tiles = [wide] * 4 + [((b, t, h), f32, "normal")]
         scanned = [((t // chunk, b, h, r, w), f32, "normal")
-                   for r, w in [(chunk, d), (chunk, d), (chunk, chunk),
-                                (chunk, d), (chunk, d), (1, d)]]
+                   for r, w in pallas_kda._six(chunk, d, d)]
 
         def kernels(*x, chunk=chunk, sub=sub):
             return pallas_kda.kda_intra(*_kda_tiles(*x), chunk, sub, False)
@@ -679,6 +688,28 @@ def kernel_cases():
         add("pallas_kda[fwd-%s]" % shape, kernels, tiles, plain, 1e-4)
         add("pallas_kda[bwd-%s]" % shape, pulled(kernels), tiles + scanned,
             pulled(plain), 1e-4)
+
+    # the scan from chunk to chunk of a KDA group with the state in VMEM:
+    # the last state and o, and the seven gradients from their cotangents.
+    # Kimi-Linear's group (16 chunks of 64 tokens over 32 heads of 128),
+    # then the corners of ``pallas_kda.scan_eligible``: the widest head
+    # with the longest chunk, and short chunks (four heads' blocks fit)
+    def scan(*x):
+        return pallas_kda.kda_scan(*_kda_scanned(*x), False)
+
+    def scan_plain(*x):
+        return lm_ops._scan_plain(*_kda_scanned(*x))
+
+    for n, b, h, d, chunk in [(16, 1, 32, 128, 64), (2, 1, 8, 256, 128),
+                              (8, 1, 32, 256, 16)]:
+        state = ((b, h, d, d), f32, "normal")
+        six = [((n, b, h, r, w), f32, "normal")
+               for r, w in pallas_kda._six(chunk, d, d)]
+        shape = "%dx%dx%dx%d-chunk%d" % (b, n * chunk, h, d, chunk)
+        add("pallas_kda[scan-fwd-%s]" % shape, scan, [state] + six,
+            scan_plain, 1e-4)
+        add("pallas_kda[scan-bwd-%s]" % shape, pulled(scan, 7),
+            [state] + six + [state, six[0]], pulled(scan_plain, 7), 1e-4)
     return cases
 
 

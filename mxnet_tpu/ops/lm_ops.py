@@ -5,10 +5,12 @@ arXiv:2510.26692) and Mamba-2's (arXiv:2405.21060) in chunks, in one frame
 of groups of chunks, Mamba-2's gated norm, and the head that gives every
 token's loss without the tokens x vocabulary array.
 
-All are pure JAX but the work inside the KDA core's chunks, which goes to
-two Pallas kernels (``ops/pallas_kda.py``, forward and backward) where a
-head's tile is one of theirs (``pallas_kda.eligible``: heads of 128 or
-256) and stays plain JAX for any other shape. Gradients come from ``jax.vjp`` (the head's from a ``custom_vjp``
+All are pure JAX but the two halves of the KDA core, the work inside chunks
+and the scan that carries the state from chunk to chunk: each goes to two
+Pallas kernels (``ops/pallas_kda.py``, forward and backward) where a head's
+tile is one of theirs (``pallas_kda.eligible``, ``pallas_kda.scan_eligible``:
+heads of 128 or 256) and stays plain JAX for any other shape (``_intra_plain``,
+``_scan_plain``: the kernels' oracles). Gradients come from ``jax.vjp`` (the head's from a ``custom_vjp``
 that works through the tokens in blocks, the KDA core's from one that walks
 its groups of chunks in reverse and marks what a ``mirror_stage`` should
 keep; Mamba-2's core runs in the same frame, in plain JAX). Each of the
@@ -168,27 +170,13 @@ def _intra_plain(q, k, v, g, beta, chunk, sub):
             k * jnp.exp(g_end - gc), g_end)                # k decayed to the end
 
 
-def _kda_group(s, q, k, v, g, beta, chunk, sub):
-    """A group of whole chunks from the state ``s`` (B, H, d_k, d_v):
-    inside every chunk in matrix form, all the group's chunks at once (the
-    pseudo-values ``U = (I + A)^-1 (beta V - beta K+ S_0)``), then from
-    chunk to chunk a scan that carries the state. q, k, g: (B, T, H, d_k);
-    v: (B, T, H, d_v); beta: (B, T, H), float32, T a multiple of
-    ``chunk``. Returns (the state after the group, o (B, T, H, d_v)).
-    The two halves run under the scopes ``mx/kda/intra`` (parallel over
-    chunks: two Pallas kernels, forward and backward, where a head's tile
-    is one of theirs, else plain JAX) and ``mx/kda/scan``
-    (sequential), for a device trace to split the core by."""
-    b, t, h, dk = q.shape
-    dv = v.shape[-1]
+def _scan_plain(s, *xs):
+    """The scan from chunk to chunk of a group in plain JAX, from the state
+    ``s`` (B, H, d_k, d_v) over the six values ``xs`` of the work inside
+    chunks, each (N, B, H, C, .): (the state after the group, o (N, B, H,
+    C, d_v)). The path of any tile the kernels do not take, and their
+    oracle."""
     hi = lax.Precision.HIGHEST
-    with jax.named_scope("mx/kda/intra"):
-        if pallas_kda.eligible(dk, dv, chunk, sub):
-            from ..kernels.tier import resolve_interpret
-            xs = pallas_kda.kda_intra(q, k, v, g, beta, chunk, sub,
-                                      resolve_interpret())
-        else:
-            xs = _intra_plain(q, k, v, g, beta, chunk, sub)
 
     def step(s, x):
         u0_c, w_c, m_c, q_c, k_c, ge_c = x
@@ -198,8 +186,35 @@ def _kda_group(s, q, k, v, g, beta, chunk, sub):
             + jnp.matmul(jnp.swapaxes(k_c, -1, -2), u, precision=hi)
         return s, o
 
+    return lax.scan(step, s, xs)
+
+
+def _kda_group(s, q, k, v, g, beta, chunk, sub):
+    """A group of whole chunks from the state ``s`` (B, H, d_k, d_v):
+    inside every chunk in matrix form, all the group's chunks at once (the
+    pseudo-values ``U = (I + A)^-1 (beta V - beta K+ S_0)``), then from
+    chunk to chunk a scan that carries the state. q, k, g: (B, T, H, d_k);
+    v: (B, T, H, d_v); beta: (B, T, H), float32, T a multiple of
+    ``chunk``. Returns (the state after the group, o (B, T, H, d_v)).
+    The two halves run under the scopes ``mx/kda/intra`` (parallel over
+    chunks) and ``mx/kda/scan`` (sequential), for a device trace to split
+    the core by; each is two Pallas kernels, forward and backward, where a
+    head's tile is one of theirs (``pallas_kda.eligible``,
+    ``pallas_kda.scan_eligible``), else plain JAX."""
+    from ..kernels.tier import resolve_interpret
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    with jax.named_scope("mx/kda/intra"):
+        if pallas_kda.eligible(dk, dv, chunk, sub):
+            xs = pallas_kda.kda_intra(q, k, v, g, beta, chunk, sub,
+                                      resolve_interpret())
+        else:
+            xs = _intra_plain(q, k, v, g, beta, chunk, sub)
     with jax.named_scope("mx/kda/scan"):
-        s, o = lax.scan(step, s, xs)
+        if pallas_kda.scan_eligible(dk, dv, chunk):
+            s, o = pallas_kda.kda_scan(s, *xs, resolve_interpret())
+        else:
+            s, o = _scan_plain(s, *xs)
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)          # (B, N, C, H, dv)
     return s, o.reshape(b, t, h, dv)
 
@@ -327,15 +342,21 @@ def kda_chunked(xs, pre=_as_given, consts=(), *, chunk=64, sub=16, group=16,
     The work inside chunks goes to the two Pallas kernels of
     ``ops/pallas_kda.py`` where a head's tile is one of theirs
     (``pallas_kda.eligible``: d_k and d_v of 128 or 256, a chunk of at
-    most 128 in sub-blocks of whole 8-row registers) and through plain JAX
-    for any other shape; a
+    most 128 in sub-blocks of whole 8-row registers), the scan from chunk
+    to chunk to the two that keep the state in VMEM
+    (``pallas_kda.scan_eligible``: the same widths, a chunk of whole
+    registers), and either through plain JAX for any other shape; a
     training program counts its cores of either kind in the gauges
-    ``kda/intra_kernel`` and ``kda/intra_plain``."""
+    ``kda/intra_kernel`` and ``kda/intra_plain``, ``kda/scan_kernel`` and
+    ``kda/scan_plain``."""
     q0, _, v0, _, _ = jax.eval_shape(pre, *consts, *(x[:, :1] for x in xs))
     b, t = xs[0].shape[:2]
     chunk = _kda_chunk(t, chunk, sub)
-    kernels = pallas_kda.eligible(q0.shape[3], v0.shape[3], chunk, sub)
-    program_count("kda/intra_kernel" if kernels else "kda/intra_plain")
+    dk, dv = q0.shape[3], v0.shape[3]
+    program_count("kda/intra_kernel" if pallas_kda.eligible(dk, dv, chunk, sub)
+                  else "kda/intra_plain")
+    program_count("kda/scan_kernel" if pallas_kda.scan_eligible(dk, dv, chunk)
+                  else "kda/scan_plain")
     return _groups_core(functools.partial(_kda_run, pre, chunk, sub),
                         _group_span(t, chunk, group),
                         (b, q0.shape[2], q0.shape[3], v0.shape[3]),
@@ -349,6 +370,14 @@ program_gauge("kda/intra_plain",
               "KDA cores of the training program traced last whose work "
               "inside chunks went through plain JAX (a head's tile is none "
               "of the kernels')")
+program_gauge("kda/scan_kernel",
+              "KDA cores of the training program traced last whose scan "
+              "from chunk to chunk went to the Pallas kernels that keep the "
+              "state in VMEM (ops/pallas_kda.py)")
+program_gauge("kda/scan_plain",
+              "KDA cores of the training program traced last whose scan "
+              "from chunk to chunk went through lax.scan (a head's tile is "
+              "none of the kernels')")
 
 
 @register("_contrib_KDA")

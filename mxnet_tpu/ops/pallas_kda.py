@@ -1,18 +1,29 @@
-"""Pallas TPU kernels for the work inside the chunks of the KDA core
-(``ops/lm_ops.py::_kda_group``, scope ``mx/kda/intra``): one kernel forward,
-one backward, a program the tiles of up to eight heads of one chunk.
+"""Pallas TPU kernels for the KDA core (``ops/lm_ops.py::_kda_group``): the
+work inside chunks (scope ``mx/kda/intra``) and the scan from chunk to
+chunk (``mx/kda/scan``), each as one kernel forward and one backward.
 
-A tile is one head's chunk: q, k, g of (C, d_k), v of (C, d_v) and beta of
-(C,), read straight from the (B, T, H, d) arrays as blocks of their (B, T,
-H*d) view, and held in VMEM for everything the plain path spreads over
-dozens of fusions: the cumulative log decay, the exact ``sub`` x ``sub`` x
-d_k blocks of decays (made once, shared by the k.k and the q.k scores), the
-earlier sub-blocks' scores as MXU products, the solve ``(I + A)^-1 [beta V,
-beta K decay]`` by blocks. The forward writes what the chunk-to-chunk scan
-consumes, in the (N, B, H, C, .) order ``lax.scan`` takes. The backward
-takes the same tiles and the six cotangents, recomputes the tile's forward
-in VMEM (the decay blocks kept there, never re-read from HBM) and writes
-the gradients of q, k, v, g and beta.
+**Inside chunks** a program takes the tiles of up to eight heads of one
+chunk. A tile is one head's chunk: q, k, g of (C, d_k), v of (C, d_v) and
+beta of (C,), read straight from the (B, T, H, d) arrays as blocks of their
+(B, T, H*d) view, and held in VMEM for everything the plain path spreads
+over dozens of fusions: the cumulative log decay, the exact ``sub`` x
+``sub`` x d_k blocks of decays (made once, shared by the k.k and the q.k
+scores), the earlier sub-blocks' scores as MXU products, the solve ``(I +
+A)^-1 [beta V, beta K decay]`` by blocks. The forward writes what the scan
+consumes, in the (N, B, H, C, .) order it takes. The backward takes the
+same tiles and the six cotangents, recomputes the tile's forward in VMEM
+(the decay blocks kept there, never re-read from HBM) and writes the
+gradients of q, k, v, g and beta.
+
+**From chunk to chunk** (:func:`kda_scan`) a program carries the state of a
+block of heads, (heads, d_k, d_v), in a VMEM scratch while the grid's last
+axis, sequential, walks the group's chunks: it reads the six values as the
+blocks the first kernel wrote (no transpose, no copy between the two),
+writes o a chunk at a time and the state once, after the last chunk. Under
+``jax.vjp`` the forward also writes the state on entry to every chunk; the
+backward walks the chunks last to first with the state's cotangent in the
+scratch, recomputes ``u`` and writes the six cotangents in the order the
+first kernel's backward reads them.
 
 The arithmetic is the plain path's: float32 throughout, every product at
 ``Precision.HIGHEST`` (Mosaic's ``contract_precision<fp32>``), no exponent
@@ -38,16 +49,28 @@ _BLOCK = 8 * 64 * 128   # elements of one array that a program takes at most
 _WIDEST, _LONGEST = 256, 128    # head and chunk up to which a tile's values,
                                 # the blocks and the decays fit 16 MiB of VMEM
 _MASKED = -1e30         # an exponent whose exp() is 0
+_SCAN_VMEM = 12 << 20   # bytes of blocks a program of the scan may hold
+
+
+def _fits(d_k, d_v, chunk):
+    """Whole 128-lane registers across a head, and no wider or longer than
+    what Mosaic has compiled for a v5e (tests/test_tpu_aot_compile.py
+    holds the corners)."""
+    return (d_k % 128 == 0 and d_v % 128 == 0 and max(d_k, d_v) <= _WIDEST
+            and chunk <= _LONGEST)
 
 
 def eligible(d_k, d_v, chunk, sub):
-    """Whether a tile of this shape is one the kernels take: whole
-    128-lane registers across a head, sub-blocks of whole registers, and
-    no wider or longer than what Mosaic has compiled for a v5e
-    (tests/test_tpu_aot_compile.py holds the corners)."""
-    return (d_k % 128 == 0 and d_v % 128 == 0 and sub % _SUBLANES == 0
-            and chunk % sub == 0 and max(d_k, d_v) <= _WIDEST
-            and chunk <= _LONGEST)
+    """Whether a tile of this shape is one the kernels of the work inside
+    chunks take: :func:`_fits`, in sub-blocks of whole registers."""
+    return (_fits(d_k, d_v, chunk) and sub % _SUBLANES == 0
+            and chunk % sub == 0)
+
+
+def scan_eligible(d_k, d_v, chunk):
+    """Whether the scan over a group's chunks is the kernels':
+    :func:`_fits`, in chunks of whole 8-row registers."""
+    return _fits(d_k, d_v, chunk) and chunk % _SUBLANES == 0
 
 
 def _mm(a, b, dims=_NN):
@@ -400,19 +423,31 @@ def _in_specs(chunk, h, hb, dk, dv):
             pl.BlockSpec((1, chunk, h), lambda b, n, j: (b, n, 0))]
 
 
-def _scan_specs(chunk, hb, dk, dv):
-    """The six values of a tile that the scan consumes, (N, B, H, ., .)."""
-    def block(r, d):
-        return pl.BlockSpec((None, None, hb, r, d),
-                            lambda b, n, j: (n, b, j, 0, 0))
-    return [block(chunk, dv), block(chunk, dk), block(chunk, chunk),
-            block(chunk, dk), block(chunk, dk), block(1, dk)]
+def _six(chunk, dk, dv):
+    """(rows, width) of the six values of a tile that the scan consumes:
+    u0, w, m, q_in, k_out, g_end."""
+    return ((chunk, dv), (chunk, dk), (chunk, chunk), (chunk, dk),
+            (chunk, dk), (1, dk))
+
+
+def _by_chunk(b, n, j):
+    return n, b, j
+
+
+def _chunk_block(hb, r, d, at):
+    """``hb`` heads' (r, d) of one chunk of an (N, B, H, r, d) array;
+    ``at`` maps a grid point to (chunk, sequence, block of heads)."""
+    return pl.BlockSpec((None, None, hb, r, d),
+                        lambda *grid: at(*grid) + (0, 0))
+
+
+def _scan_specs(chunk, hb, dk, dv, at=_by_chunk):
+    return [_chunk_block(hb, r, d, at) for r, d in _six(chunk, dk, dv)]
 
 
 def _scan_shapes(b, n, h, chunk, dk, dv):
     return [jax.ShapeDtypeStruct((n, b, h, r, d), _F32)
-            for r, d in ((chunk, dv), (chunk, dk), (chunk, chunk),
-                         (chunk, dk), (chunk, dk), (1, dk))]
+            for r, d in _six(chunk, dk, dv)]
 
 
 @functools.partial(jax.jit, static_argnums=(5, 6, 7))
@@ -488,3 +523,193 @@ def _bwd(chunk, sub, interpret, res, cts):
 
 
 kda_intra.defvjp(_fwd, _bwd)
+
+
+# ------------------------------------------------ from chunk to chunk
+# The scan that carries the state through a group's chunks, as one kernel
+# forward and one backward: a program holds the state of a block of heads
+# in VMEM while the chunk axis of the grid, the last and sequential, passes.
+def _scan_heads(h, chunk, dk, dv, backward):
+    """Heads a program of the scan takes: as many as a program of the
+    work inside chunks, or the fewer whose blocks (the six values twice in
+    the backward, o or its cotangent, three states) fit ``_SCAN_VMEM``
+    twice over beside the state carried."""
+    six = chunk * (dv + 3 * dk + chunk) + _SUBLANES * dk
+    state = dk * dv
+    blocks = (2 if backward else 1) * six + chunk * dv + 3 * state
+    return max(n for n in (1, 2, 4, 8)
+               if n <= _heads_a_program(h, chunk, max(dk, dv))[0]
+               and h % n == 0
+               and (n == 1 or 4 * n * (2 * blocks + state) <= _SCAN_VMEM))
+
+
+def _turned(x):
+    """A (1, n) row as the (n, 1) column, an (n, 1) column as the row:
+    through the diagonal of an (n, n) value, whole registers."""
+    n = x.size
+    return jnp.sum(jnp.where(_iota((n, n), 0) == _iota((n, n), 1), x, 0.0),
+                   int(x.shape[0] == 1), keepdims=True)
+
+
+def _scan_fwd_kernel(s0_ref, u0_ref, w_ref, m_ref, qin_ref, kout_ref,
+                     gend_ref, send_ref, o_ref, *rest, heads):
+    """``_kda_group``'s step for the heads of a program at one chunk; the
+    last of ``rest`` is the state carried, before it (under ``jax.vjp``)
+    the block that takes the state on entry to the chunk. The heads go a
+    stage at a time: while one's products wait for its ``u``, another's
+    fill the MXU."""
+    s_scr, entry_ref = rest[-1], rest[0] if len(rest) > 1 else None
+    c = u0_ref.shape[1]
+    n = pl.program_id(2)
+
+    @pl.when(n == 0)
+    def _():
+        s_scr[...] = s0_ref[...]
+
+    states = [s_scr[i] for i in range(heads)]
+    if entry_ref is not None:
+        for i, s in enumerate(states):
+            entry_ref[i] = s
+    # w.s and q.s in one product: the state is the MXU's weights once
+    both = [_mm(jnp.concatenate([w_ref[i], qin_ref[i]], 0), s)
+            for i, s in enumerate(states)]
+    for i, s in enumerate(states):
+        u = u0_ref[i] - both[i][:c]
+        o_ref[i] = both[i][c:] + _mm(m_ref[i], u)
+        s_scr[i] = s * _turned(jnp.exp(gend_ref[i])) \
+            + _mm(kout_ref[i], u, _TN)
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _():
+        send_ref[...] = s_scr[...]
+
+
+def _scan_bwd_kernel(entry_ref, u0_ref, w_ref, m_ref, qin_ref, kout_ref,
+                     gend_ref, do_ref, dsend_ref, ds0_ref, du0_ref, dw_ref,
+                     dm_ref, dqin_ref, dkout_ref, dgend_ref, ds_scr, *,
+                     heads):
+    """The step's transpose for the heads of a program at one chunk, the
+    chunks met last to first: ``u`` recomputed from the state on entry,
+    the state's cotangent carried in ``ds_scr``; a stage at a time over
+    the heads, as the forward."""
+    c = u0_ref.shape[1]
+    n = pl.program_id(2)
+
+    @pl.when(n == 0)
+    def _():
+        ds_scr[...] = dsend_ref[...]
+
+    first = []
+    for i in range(heads):
+        do, ds = do_ref[i], ds_scr[i]
+        u = u0_ref[i] - _mm(w_ref[i], entry_ref[i])
+        du = _mm(m_ref[i], do, _TN) + _mm(kout_ref[i], ds)
+        du0_ref[i] = du
+        first.append((do, ds, u, du))
+    for i, (do, ds, u, du) in enumerate(first):
+        s, w = entry_ref[i], w_ref[i]
+        e = _turned(jnp.exp(gend_ref[i]))
+        both = jnp.concatenate([do, du], 0)
+        by_s = _mm(both, s, _NT)
+        dqin_ref[i] = by_s[:c]
+        dw_ref[i] = -by_s[c:]
+        dm_ref[i] = _mm(do, u, _NT)
+        dkout_ref[i] = _mm(u, ds, _NT)
+        dgend_ref[i] = _turned(jnp.sum(ds * s, 1, keepdims=True) * e)
+        # q^T.do - w^T.du in one product over both chunks' rows
+        ds_scr[i] = ds * e + _mm(jnp.concatenate([qin_ref[i], -w], 0), both,
+                                 _TN)
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _():
+        ds0_ref[...] = ds_scr[...]
+
+
+def _scan_call(kernel, name, b, h, n, hb, dk, dv, interpret, **specs):
+    return pl.pallas_call(
+        functools.partial(kernel, heads=hb), grid=(b, h // hb, n),
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), _F32)],
+        # the state stays in VMEM while a group's chunks pass: the last
+        # grid axis is sequential
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret, name=name, **specs)
+
+
+def _scan_blocks(n, hb, chunk, dk, dv, reverse):
+    """Block specs over the grid (sequence, block of heads, chunk): (a
+    state's, a state's a chunk, o's, the six values'), the chunks met
+    first to last or, with ``reverse``, last to first."""
+    def at(b, j, i):
+        return (n - 1 - i if reverse else i), b, j
+    return (pl.BlockSpec((None, hb, dk, dv), lambda b, j, i: (b, j, 0, 0)),
+            _chunk_block(hb, dk, dv, at), _chunk_block(hb, chunk, dv, at),
+            _scan_specs(chunk, hb, dk, dv, at))
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8))
+def scan_fwd(s0, u0, w, m, q_in, k_out, g_end, keep, interpret):
+    """``(s_end, o)`` of a group from the state ``s0`` (B, H, d_k, d_v) and
+    the six values of :func:`intra_fwd`; with ``keep`` also the state on
+    entry to every chunk, (N, B, H, d_k, d_v)."""
+    n, b, h, chunk, dv = u0.shape
+    dk = w.shape[-1]
+    hb = _scan_heads(h, chunk, dk, dv, False)
+    state, states, o, six = _scan_blocks(n, hb, chunk, dk, dv, False)
+    return _scan_call(
+        _scan_fwd_kernel, "kda_scan_fwd", b, h, n, hb, dk, dv, interpret,
+        in_specs=[state] + six,
+        # with ``keep`` (True is 1) one output more: the entry states
+        out_specs=[state, o] + [states] * keep,
+        out_shape=[jax.ShapeDtypeStruct(s0.shape, _F32),
+                   jax.ShapeDtypeStruct(u0.shape, _F32)]
+        + [jax.ShapeDtypeStruct((n,) + s0.shape, _F32)] * keep,
+    )(s0, u0, w, m, q_in, k_out, g_end)
+
+
+@functools.partial(jax.jit, static_argnums=(4,))
+def scan_bwd(entry, xs, do, ds_end, interpret):
+    """``(ds0, *the cotangents of the six values xs)`` from the states on
+    entry to the chunks and the cotangents of o and of the last state."""
+    n, b, h, chunk, dv = xs[0].shape
+    dk = xs[1].shape[-1]
+    hb = _scan_heads(h, chunk, dk, dv, True)
+    state, states, o, six = _scan_blocks(n, hb, chunk, dk, dv, True)
+    return tuple(_scan_call(
+        _scan_bwd_kernel, "kda_scan_bwd", b, h, n, hb, dk, dv, interpret,
+        in_specs=[states] + six + [o, state],
+        out_specs=[state] + six,
+        out_shape=[jax.ShapeDtypeStruct(ds_end.shape, _F32)]
+        + _scan_shapes(b, n, h, chunk, dk, dv),
+    )(entry, *xs, do, ds_end))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def kda_scan(s0, u0, w, m, q_in, k_out, g_end, interpret):
+    """The scan from chunk to chunk of a group as two kernels, with the
+    arithmetic of ``lm_ops._kda_group``'s step: ``u = u0 - w.s``, ``o =
+    q_in.s + m.u``, ``s <- s * exp(g_end)^T + k_out^T.u`` a chunk, float32,
+    every product at ``Precision.HIGHEST``. ``s0``: (B, H, d_k, d_v); the
+    six values of :func:`kda_intra`: (N, B, H, C, .). Returns ``(s_end,
+    o)``, o (N, B, H, C, d_v). The backward reads the six values again and
+    the state on entry to every chunk, which the forward writes only under
+    ``jax.vjp``."""
+    return tuple(scan_fwd(s0, u0, w, m, q_in, k_out, g_end, False,
+                          interpret))
+
+
+def _scan_fwd_rule(*args):
+    *given, interpret = args
+    s_end, o, entry = scan_fwd(*given, True, interpret)
+    return (s_end, o), (entry, tuple(given[1:]))
+
+
+def _scan_bwd_rule(interpret, res, cts):
+    ds_end, do = cts
+    # named here: a backward function is traced outside the scope its
+    # forward ran under
+    with jax.named_scope("mx/kda/scan"):
+        return scan_bwd(*res, do, ds_end, interpret)
+
+
+kda_scan.defvjp(_scan_fwd_rule, _scan_bwd_rule)

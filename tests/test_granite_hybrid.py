@@ -209,7 +209,8 @@ def fitted(cfg):
         g: telemetry.gauge(g).value()
         for g in ("ssm/layers", "ssm/chunks", "ssm/state_mb",
                   "attn/window_layers", "attn/full_layers",
-                  "stage/kept_values", "stage/kept_mb", "kda/intra_plain")})
+                  "stage/kept_values", "stage/kept_mb", "kda/intra_plain",
+                  "kda/scan_plain")})
     return seen
 
 
@@ -332,7 +333,7 @@ def test_the_gauges_count_the_cores_their_chunks_and_their_states(cfg,
     assert (g["ssm/layers"], g["ssm/chunks"]) == (2, 5)
     assert g["ssm/state_mb"] == pytest.approx(2 * 4 * B * 4 * 32 * 16 / 1e6)
     assert (g["attn/window_layers"], g["attn/full_layers"]) == (0, 1)
-    assert g["kda/intra_plain"] == 0
+    assert g["kda/intra_plain"] == 0 and g["kda/scan_plain"] == 0
     assert g["stage/kept_values"] == 5
     t, inner = cfg["sequence_length"], 4 * 32
     assert g["stage/kept_mb"] == pytest.approx(

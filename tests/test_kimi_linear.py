@@ -509,6 +509,7 @@ def _plain_path(monkeypatch):
     """No tile is the kernels': every core takes the plain-JAX path."""
     from mxnet_tpu.ops import pallas_kda
     monkeypatch.setattr(pallas_kda, "eligible", lambda *a: False)
+    monkeypatch.setattr(pallas_kda, "scan_eligible", lambda *a: False)
 
 
 def _close(names, got, want, rtol):
@@ -562,6 +563,53 @@ def test_kda_kernels_are_the_plain_path_and_the_recurrence(t, b, h,
     _assert_gradients(names, g_got, g_want)
 
 
+def _scan_inputs(n, b, h, c, dk, dv):
+    """A state, the six values a group's scan consumes and the cotangents
+    of its two results: none of them zero, decays from weak to strong."""
+    rng = np.random.RandomState(n * h)
+
+    def normal(*shape, scale=1.0):
+        return jnp.asarray((rng.randn(*shape) * scale).astype("f4"))
+    xs = (normal(n, b, h, c, dv), normal(n, b, h, c, dk, scale=dk ** -.5),
+          normal(n, b, h, c, c, scale=c ** -.5),
+          normal(n, b, h, c, dk, scale=dk ** -.5),
+          normal(n, b, h, c, dk, scale=dk ** -.5),
+          jnp.asarray(-np.exp(rng.uniform(np.log(1e-3), np.log(20.0),
+                                          (n, b, h, 1, dk))).astype("f4")))
+    return (normal(b, h, dk, dv),) + xs, (normal(b, h, dk, dv),
+                                          normal(n, b, h, c, dv))
+
+
+@pytest.mark.parametrize("n,b,h,hb,c,dk,dv", [
+    (1, 2, 3, 1, 64, 128, 128), (5, 1, 8, 8, 64, 128, 128),
+    (3, 2, 2, 2, 16, 128, 256), (16, 1, 4, 4, 8, 128, 128)],
+    ids=["one-chunk-one-head-a-program", "five-chunks-eight-heads-a-program",
+         "three-chunks-d_v-256-two-heads", "sixteen-chunks-four-heads"])
+def test_kda_scan_kernels_are_the_plain_scan(n, b, h, hb, c, dk, dv):
+    """The scan from chunk to chunk as two kernels (interpreted here)
+    against ``lax.scan`` over the same step (``lm_ops._scan_plain``): the
+    last state and o to 1e-5, the gradients of the state on entry and of
+    all six values to 1e-4, from a state and cotangents (of the last state
+    too) that are not zero; a group of one chunk and of many, one head a
+    program (``hb``) and several."""
+    from mxnet_tpu.ops import lm_ops, pallas_kda
+    assert pallas_kda.scan_eligible(dk, dv, c)
+    assert pallas_kda._scan_heads(h, c, dk, dv, True) == hb
+    args, cts = _scan_inputs(n, b, h, c, dk, dv)
+
+    def pulled(f):
+        out, pull = jax.vjp(f, *args)
+        return out, pull(cts)
+    (got, g_got), (want, g_want) = (
+        pulled(lambda *a: pallas_kda.kda_scan(*a, True)),
+        pulled(lm_ops._scan_plain))
+    for x in g_want:
+        assert float(jnp.max(jnp.abs(x))) > 0
+    _close(["s_end", "o"], got, want, 1e-5)
+    _close(["s0", "u0", "w", "m", "q_in", "k_out", "g_end"], g_got, g_want,
+           1e-4)
+
+
 def _kda_symbol(h, chunk=64):
     """One ``_contrib_KDA`` op as a loss: the smallest training graph
     with a KDA core."""
@@ -582,31 +630,35 @@ def _kda_shapes(h, d, t=64):
 def test_the_tile_selects_kernels_or_the_plain_path(d, kernel):
     """No flag: a 128-wide head's core goes to the kernels, a 16-wide one
     and one wider than the kernels' tiles through plain JAX. Read where a
-    user would, from the gauges ``kda/intra_kernel`` and
-    ``kda/intra_plain`` that a traced training program sets (0 and 0 for a graph with no KDA core), and from the
-    program itself: the step's forward, the group recomputed inside the
-    core's backward, the backward, a kernel each, every one under
-    ``mx/kda/intra`` for the device trace to charge to ``mx/kda``."""
+    user would, from the gauges ``kda/intra_kernel``, ``kda/intra_plain``,
+    ``kda/scan_kernel`` and ``kda/scan_plain`` that a traced training
+    program sets (0 for a graph with no KDA core), and from the program
+    itself: the step's forward, the group recomputed inside the core's
+    backward, the backward, a kernel each for the work inside chunks and
+    for the scan over them, every one under ``mx/kda/intra`` or
+    ``mx/kda/scan`` for the device trace to charge to ``mx/kda``."""
     from mxnet_tpu import telemetry
     from mxnet_tpu.models import resnet_symbol
-    gauges = [telemetry.gauge("kda/intra_kernel"),
-              telemetry.gauge("kda/intra_plain")]
+    gauges = [telemetry.gauge("kda/%s_%s" % (half, path))
+              for half in ("intra", "scan") for path in ("kernel", "plain")]
     sym = _kda_symbol(2)
     jaxpr = jax.make_jaxpr(_gradients(sym))(
         *_abstract(sym, **_kda_shapes(2, d))).jaxpr
-    assert [g.value() for g in gauges] == [int(kernel), int(not kernel)]
+    assert [g.value() for g in gauges] == [int(kernel), int(not kernel)] * 2
     kernels = [(e.params["name"], name) for e, name in _named_eqns(jaxpr)
                if e.primitive.name == "pallas_call"]
     assert sorted(k for k, _ in kernels) == (
-        ["kda_intra_bwd", "kda_intra_fwd", "kda_intra_fwd"] if kernel else [])
+        ["kda_intra_bwd", "kda_intra_fwd", "kda_intra_fwd", "kda_scan_bwd",
+         "kda_scan_fwd", "kda_scan_fwd"] if kernel else [])
     for k, name in kernels:
-        assert "mx/kda/intra" in name, (k, name)
-        assert ("transpose(jvp(mx/kda/intra))" in name) \
-            == (k == "kda_intra_bwd"), (k, name)
+        scope = "mx/kda/" + k.split("_")[1]
+        assert scope in name, (k, name)
+        assert ("transpose(jvp(%s))" % scope in name) \
+            == k.endswith("_bwd"), (k, name)
     net = resnet_symbol(num_classes=10, num_layers=18, image_shape="3,32,32")
     jax.make_jaxpr(_gradients(net))(
         *_abstract(net, data=(2, 3, 32, 32), softmax_label=(2,)))
-    assert [g.value() for g in gauges] == [0, 0]
+    assert [g.value() for g in gauges] == [0, 0, 0, 0]
 
 
 def test_the_tiny_models_cores_are_counted_as_plain(cfg):
@@ -617,6 +669,8 @@ def test_the_tiny_models_cores_are_counted_as_plain(cfg):
         _traced_gradient(_symbol(cfg, kda_chunk=SMALL_CHUNK), t=64)
         assert telemetry.gauge("kda/intra_kernel").value() == 0
         assert telemetry.gauge("kda/intra_plain").value() == 4
+        assert telemetry.gauge("kda/scan_kernel").value() == 0
+        assert telemetry.gauge("kda/scan_plain").value() == 4
 
 
 @pytest.mark.parametrize("t,tile,gauges", [
