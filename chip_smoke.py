@@ -482,6 +482,21 @@ def _kda_tiles(q, k, v, g, beta):
             0.05 + 0.9 * cdf(beta))
 
 
+def _ssd_group_args(x, bm, cm, dt, dt_bias, a_log, d, s):
+    """Normals as a group of Mamba-2's core takes them: B and C over
+    N^(1/4) (so C.B is of order 1), ``delta`` about 0.02 and ``exp(A_log)``
+    from 1 to 16 (a token's decay down to about 0.7, a chunk's to
+    exp(-80)), the skip about 1; (consts, state, xs) in
+    ``lm_ops._ssd_group``'s order."""
+    import jax
+    import jax.numpy as jnp
+    cdf = jax.scipy.stats.norm.cdf
+    scale = bm.shape[-1] ** -0.25
+    return ((dt_bias - 4.0, jnp.log(1.0 + 15.0 * cdf(a_log)), 1.0 + 0.1 * d),
+            s, (x, (bm * scale).astype(bm.dtype),
+                (cm * scale).astype(cm.dtype), dt))
+
+
 def _kda_scanned(s0, u0, w, m, q_in, k_out, g_end):
     """Normals as what the scan over a KDA group's chunks takes: the
     products' left factors small enough that the state neither dies nor
@@ -710,6 +725,39 @@ def kernel_cases():
             scan_plain, 1e-4)
         add("pallas_kda[scan-bwd-%s]" % shape, pulled(scan, 7),
             [state] + six + [state, six[0]], pulled(scan_plain, 7), 1e-4)
+    # a group of Mamba-2's core (``lm_ops._ssd_group``) as the two kernels
+    # of ``ops/pallas_ssd.py``: the state after the group and y, and the
+    # gradients of x, B, C, dt, the three per-head vectors and the state
+    # on entry from the cotangents of both. Granite-4.0-H's group (8
+    # chunks of 256 tokens, 64 heads of 64, a state of 128, bfloat16
+    # operands), then the corners of ``pallas_ssd.eligible``: heads of
+    # 128 over a state of 256 in chunks of 128, float32. bfloat16 products
+    # on both sides, rounded at the same points: the bf16 bound
+    def ssd(h, chunk):
+        def kernels(*a):
+            from mxnet_tpu.kernels import tier
+            with tier.force_compiled():
+                return lm_ops._ssd_kernel_group(h, chunk,
+                                                *_ssd_group_args(*a))
+
+        def plain(*a):
+            return lm_ops._ssd_group(h, chunk, *_ssd_group_args(*a))
+        return kernels, plain
+
+    for b, t, h, p, n, chunk, dt in [(1, 2048, 64, 64, 128, 256, bf16),
+                                     (1, 512, 4, 128, 256, 128, f32)]:
+        kernels, plain = ssd(h, chunk)
+        vec = ((h,), f32, "normal")
+        given = [((b, t, h * p), dt, "normal"), ((b, t, n), dt, "normal"),
+                 ((b, t, n), dt, "normal"), ((b, t, h), f32, "normal"),
+                 vec, vec, vec, ((b, h, p, n), f32, "normal")]
+        shape = "%dx%dx%dx%d-n%d-chunk%d-%s" % (b, t, h, p, n, chunk,
+                                                dt.__name__)
+        add("pallas_ssd[fwd-%s]" % shape, kernels, given, plain, tol[dt])
+        add("pallas_ssd[bwd-%s]" % shape, pulled(kernels, 8),
+            given + [((b, h, p, n), f32, "normal"),
+                     ((b, t, h * p), f32, "normal")],
+            pulled(plain, 8), tol[dt])
     return cases
 
 

@@ -5,15 +5,15 @@ arXiv:2510.26692) and Mamba-2's (arXiv:2405.21060) in chunks, in one frame
 of groups of chunks, Mamba-2's gated norm, and the head that gives every
 token's loss without the tokens x vocabulary array.
 
-All are pure JAX but the two halves of the KDA core, the work inside chunks
-and the scan that carries the state from chunk to chunk: each goes to two
-Pallas kernels (``ops/pallas_kda.py``, forward and backward) where a head's
-tile is one of theirs (``pallas_kda.eligible``, ``pallas_kda.scan_eligible``:
-heads of 128 or 256) and stays plain JAX for any other shape (``_intra_plain``,
-``_scan_plain``: the kernels' oracles). Gradients come from ``jax.vjp`` (the head's from a ``custom_vjp``
+All are pure JAX but the two halves of the KDA core and Mamba-2's chunks:
+KDA's work inside chunks and its scan go each to two Pallas kernels
+(``ops/pallas_kda.py``) where a head's tile is one of theirs (heads of 128 or
+256), Mamba-2's group of chunks to three (``ops/pallas_ssd.py``) where its core
+is (heads of 64 or 128); plain JAX (``_intra_plain``, ``_scan_plain``,
+``_ssd_group``: the kernels' oracles) for any other shape. Gradients come from ``jax.vjp`` (the head's from a ``custom_vjp``
 that works through the tokens in blocks, the KDA core's from one that walks
 its groups of chunks in reverse and marks what a ``mirror_stage`` should
-keep; Mamba-2's core runs in the same frame, in plain JAX). Each of the
+keep; Mamba-2's core runs in the same frame). Each of the
 layers a device trace should tell apart carries a ``jax.named_scope``
 (``mx/kda`` with ``mx/kda/intra`` and ``mx/kda/scan`` inside it, ``mx/ssm``
 with ``mx/ssm/intra`` and ``mx/ssm/scan``, ``mx/rope``, ``mx/lm_head``;
@@ -424,7 +424,8 @@ def _ssd_group(heads, chunk, consts, s, xs):
     ``delta``, ``log a``, their running sums, the decays and the states
     are float32; the operands of the four products (C B^T, the masked
     decays times it against ``delta x``, a chunk's own state, C against
-    the state carried in) are in x's dtype and accumulate in float32."""
+    the state carried in) are in x's dtype and accumulate in float32. The
+    path of any core the kernels do not take, and their oracle."""
     dt_bias, a_log, d_skip = consts
     x, bm, cm, dt = xs
     b, t = x.shape[:2]
@@ -473,12 +474,49 @@ def _ssd_group(heads, chunk, consts, s, xs):
     return s, jnp.moveaxis(y, 2, 3).reshape(b, t, -1)
 
 
+def _ssd_kernel_group(heads, chunk, consts, s, xs):
+    """:func:`_ssd_group` where the core's tile is the kernels'
+    (``pallas_ssd.eligible``): ``delta``, ``log a`` and its running sums,
+    (B, t, H), in XLA under ``mx/ssm/intra``; the chunks, inside each and
+    from one to the next, in the kernels of ``ops/pallas_ssd.py`` under
+    ``mx/ssm/scan``, with the decays and the state in VMEM."""
+    from ..kernels.tier import resolve_interpret
+    from . import pallas_ssd
+    dt_bias, a_log, d_skip = consts
+    x, bm, cm, dt = xs
+    b, t = x.shape[:2]
+    with jax.named_scope("mx/ssm/intra"):
+        delta = jax.nn.softplus(dt.astype(_F32) + dt_bias)    # (B, t, H)
+        la = delta * -jnp.exp(a_log)                          # log a <= 0
+        # the running sums as one product with the lower triangle of ones
+        # (float32 operands kept float32), not a window as long as the
+        # chunk over every token
+        cs = jnp.einsum("ij,bnjh->bnih", jnp.tril(jnp.ones((chunk, chunk),
+                                                           _F32)),
+                        la.reshape(b, t // chunk, chunk, heads),
+                        precision=lax.Precision.HIGHEST).reshape(b, t, heads)
+    with jax.named_scope("mx/ssm/scan"):
+        after, y = pallas_ssd.ssd_scan(
+            x, bm.astype(x.dtype), cm.astype(x.dtype), delta, cs, d_skip,
+            s.reshape(b, -1, s.shape[-1]), chunk, resolve_interpret())
+    # y leaves the kernel rounded to x's dtype, as the frame's output is
+    return after.reshape(s.shape), y.astype(_F32)
+
+
 program_gauge("ssm/layers",
               "Mamba-2 cores (_contrib_Mamba2) of the training program "
               "traced last")
 program_gauge("ssm/chunks",
               "chunks a sequence is cut into at the op's chunk, the most "
               "of any Mamba-2 core of the training program traced last")
+program_gauge("ssm/core_kernel",
+              "Mamba-2 cores of the training program traced last whose "
+              "chunks went to the Pallas kernels that build the decays and "
+              "carry the state in VMEM (ops/pallas_ssd.py)")
+program_gauge("ssm/core_plain",
+              "Mamba-2 cores of the training program traced last whose "
+              "chunks went through plain JAX (the core's tile is none of "
+              "the kernels')")
 program_gauge("ssm/state_mb",
               "MB of chunk-boundary states (float32, one on entry to each "
               "group of chunks) that the Mamba-2 cores of the training "
@@ -495,14 +533,25 @@ def ssd_chunked(x, b, c, dt, dt_bias, a_log, d, *, num_heads, chunk=256,
     delta_t x_t b_t^T`` (P, N) from zero and gives ``y_t = S_t c_t + d_h
     x_t``: (B, T, H*P) in x's dtype, before the gated norm.
 
-    Computed in chunks of ``chunk`` tokens (:func:`_ssd_group`), ``group``
-    chunks at a time, in the frame KDA's core runs in (``_groups_core``):
-    a forward scan that keeps the float32 state on entry to each group (a
-    ``mirror_stage`` keeps them and the output), a backward that recomputes
-    a group's interior, its (chunk, chunk) decays among it, one group at a
-    time. A sequence that is no multiple of the chunk is padded after its
-    end. ``delta``, the decays and the states are float32 whatever the
-    inputs' dtype; the products' operands are x's dtype."""
+    Computed in chunks of ``chunk`` tokens, ``group`` chunks at a time, in
+    the frame KDA's core runs in (``_groups_core``): a forward scan that
+    keeps the float32 state on entry to each group (a ``mirror_stage``
+    keeps them and the output), a backward that recomputes a group's
+    interior, its (chunk, chunk) decays among it, one group at a time. A
+    sequence that is no multiple of the chunk is padded after its end.
+    ``delta``, the decays and the states are float32 whatever the inputs'
+    dtype; the products' operands are x's dtype.
+
+    A group goes to the Pallas kernels of ``ops/pallas_ssd.py``
+    (:func:`_ssd_kernel_group`: the decays and the state in VMEM) where the
+    core is one of theirs (``pallas_ssd.eligible``: heads of 64 or 128, a
+    state of 128 or 256, a chunk of 128 or 256), and through plain JAX
+    (:func:`_ssd_group`) for any other shape; a training program counts
+    its cores of either kind in the gauges ``ssm/core_kernel`` and
+    ``ssm/core_plain``."""
+    # imported here, not above KDA's code: a Pallas kernel's cache key
+    # carries the source lines of the frames that called it
+    from . import pallas_ssd
     with jax.named_scope("mx/ssm"):
         bsz, t = x.shape[:2]
         heads = int(num_heads)
@@ -510,12 +559,15 @@ def ssd_chunked(x, b, c, dt, dt_bias, a_log, d, *, num_heads, chunk=256,
         span = _group_span(t, chunk, int(group))
         state = (bsz, heads, x.shape[2] // heads, b.shape[2])
         program_count("ssm/layers")
+        kernel = pallas_ssd.eligible(state[2], state[3], chunk, heads)
+        program_count("ssm/core_kernel" if kernel else "ssm/core_plain")
         program_max("ssm/chunks", -(-t // chunk))
         program_count("ssm/state_mb",
                       -(-t // span) * 4 * bsz * heads * state[2] * state[3]
                       / 1e6)
         consts = tuple(v.astype(_F32) for v in (dt_bias, a_log, d))
-        return _groups_core(functools.partial(_ssd_group, heads, chunk), span,
+        run = _ssd_kernel_group if kernel else _ssd_group
+        return _groups_core(functools.partial(run, heads, chunk), span,
                             state, x.dtype, consts, (x, b, c, dt))
 
 

@@ -4,7 +4,8 @@ small size on the CPU: hidden 64, Mamba-2 of 4 heads of 32 with a state of
 16 in chunks of 64, attention of 4 heads of 16 over 2 KV heads, the
 published layers 4-6 (Mamba, attention, Mamba), 300 tokens: no multiple of
 the chunk. The core ``_contrib_Mamba2`` against the reference's
-token-by-token recurrence, forward and every input's gradient; the
+token-by-token recurrence, forward and every input's gradient, and its
+Pallas kernels (``ops/pallas_ssd.py``) against the plain core; the
 convolution's bias; the symbol's losses, every leaf's gradient (the tied
 leaf's the sum of its two uses) and three fused Adam steps; each of
 Granite's four multipliers shown to matter; the gauges and scopes the
@@ -23,7 +24,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
 from references import granite_hybrid as ref     # noqa: E402
 from runners.train_lm_cfg import build_symbol    # noqa: E402
-from test_kimi_linear import _fit                # noqa: E402
+from test_kimi_linear import (_abstract, _fit, _gradients,  # noqa: E402
+                              _named_eqns)
 
 B = 2
 
@@ -103,6 +105,150 @@ def test_the_core_is_the_token_by_token_recurrence(t, chunk, group):
         np.testing.assert_allclose(
             a, b, rtol=1e-4, atol=1e-5 * float(jnp.max(jnp.abs(b))),
             err_msg=name)
+
+
+# ---- the core's chunks as two Pallas kernels (ops/pallas_ssd.py)
+def _plain_core(monkeypatch):
+    """No core is the kernels': every one takes ``_ssd_group``."""
+    from mxnet_tpu.ops import pallas_ssd
+    monkeypatch.setattr(pallas_ssd, "eligible", lambda *a: False)
+
+
+def _close(names, got, want, rtol):
+    """``rtol`` of an element, or of the array's largest where the element
+    is small."""
+    for name, a, w in zip(names, got, want):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(w), rtol=rtol,
+            atol=rtol * float(jnp.max(jnp.abs(w))), err_msg=name)
+
+
+_NAMES = ("x", "B", "C", "dt", "dt_bias", "A_log", "D")
+
+
+# (tokens, heads, head width, state, chunk, group): more than one group
+# and a sequence that is no multiple of the chunk, heads of 64 two to a
+# register and of 128 one to a register, the cell's chunk of 256 and 128
+@pytest.mark.parametrize("t,h,p,n,chunk,group", [
+    (300, 4, 64, 128, 128, 1), (520, 2, 128, 256, 256, 1)],
+    ids=["T300-heads64-chunk128-three-groups",
+         "T520-heads128-state256-chunk256-three-groups"])
+def test_ssd_kernels_are_the_plain_core_and_the_recurrence(
+        t, h, p, n, chunk, group, monkeypatch):
+    """At shapes the kernels take (interpreted here), float32: the core's
+    output and the gradients of x, B, C, dt, dt_bias, A_log and D against
+    the plain ``_ssd_group`` in the same frame to float32 round-off, and
+    against the reference's token-by-token recurrence at the tolerances
+    the plain core is held to; the state passes from group to group."""
+    from mxnet_tpu.ops import pallas_ssd
+    from mxnet_tpu.ops.lm_ops import ssd_chunked
+    assert pallas_ssd.eligible(p, n, chunk, h)
+    args = _core_inputs(t, h=h, p=p, n=n)
+    w = jax.random.normal(jax.random.PRNGKey(9), (B, t, h * p))
+
+    def both(core):
+        return core(*args), jax.grad(lambda *a: jnp.sum(core(*a) * w),
+                                     argnums=range(7))(*args)
+
+    def chunked(*a):
+        return ssd_chunked(*a, num_heads=h, chunk=chunk, group=group)
+    with jax.default_matmul_precision("highest"):
+        got, g_got = both(chunked)
+        want, g_want = both(_token_by_token)
+        _plain_core(monkeypatch)
+        plain, g_plain = both(chunked)
+    _close(["y"], [got], [plain], 1e-5)
+    _close(_NAMES, g_got, g_plain, 1e-4)
+    _close(["y"], [got], [want], 1e-4)
+    for name, a, b in zip(_NAMES, g_got, g_want):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-4, atol=1e-5 * float(jnp.max(jnp.abs(b))),
+            err_msg=name)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_groups_kernels_are_its_plain_path_from_a_state(dtype):
+    """One group from a state that is not zero, with a cotangent on the
+    state after it too: the state after the group, y, and the gradients
+    of x, B, C, dt, the three per-head vectors and the state carried in,
+    against ``_ssd_group``. In bfloat16 both round the products' operands
+    at the same points, and a rounding that falls the other way (the sums
+    run in another order) moves a value by a bfloat16 step: the outputs
+    held to 2e-3, the gradients to 2e-2, of their largest."""
+    from mxnet_tpu.ops import lm_ops
+    h, p, n, chunk, t = 4, 64, 128, 128, 256
+    x, bm, cm, dt, dt_bias, a_log, d = _core_inputs(t, h=h, p=p, n=n)
+    given = (x.astype(dtype), bm.astype(dtype), cm.astype(dtype), dt,
+             dt_bias, a_log, d, jax.random.normal(jax.random.PRNGKey(3),
+                                                  (B, h, p, n)))
+    cts = (jax.random.normal(jax.random.PRNGKey(4), (B, h, p, n)),
+           jax.random.normal(jax.random.PRNGKey(5), (B, t, h * p)))
+
+    def group(run):
+        def f(x, bm, cm, dt, dt_bias, a_log, d, s):
+            return run(h, chunk, (dt_bias, a_log, d), s, (x, bm, cm, dt))
+        out, pull = jax.vjp(f, *given)
+        return out, pull(cts)
+    with jax.default_matmul_precision("highest"):
+        (got, g_got), (want, g_want) = (group(lm_ops._ssd_kernel_group),
+                                        group(lm_ops._ssd_group))
+    for x_ in g_want:
+        assert float(jnp.max(jnp.abs(x_.astype(jnp.float32)))) > 0
+    tol = (1e-5, 1e-4) if dtype == jnp.float32 else (2e-3, 2e-2)
+    _close(["s_end", "y"], got, want, tol[0])
+    _close(_NAMES + ("s0",), [g.astype(jnp.float32) for g in g_got],
+           [g.astype(jnp.float32) for g in g_want], tol[1])
+
+
+def _mamba_symbol(h):
+    """One ``_contrib_Mamba2`` op as a loss."""
+    import mxnet_tpu as mx
+    v = [mx.sym.Variable(k) for k in ("x", "b", "c", "dt", "dt_bias",
+                                      "a_log", "d")]
+    return mx.sym.MakeLoss(mx.sym.contrib.Mamba2(*v, num_heads=h,
+                                                 chunk=128))
+
+
+def _mamba_shapes(h, p, n, t=256):
+    return dict(x=(1, t, h * p), b=(1, t, n), c=(1, t, n), dt=(1, t, h),
+                dt_bias=(h,), a_log=(h,), d=(h,))
+
+
+@pytest.mark.parametrize("p,n,kernel", [(32, 16, False), (64, 128, True),
+                                        (128, 256, True), (64, 64, False),
+                                        (256, 128, False)],
+                         ids=["p32-plain", "p64-kernels", "p128-n256-kernels",
+                              "n64-plain", "p256-plain"])
+def test_the_core_selects_kernels_or_the_plain_path(p, n, kernel):
+    """No flag: heads of 64 or 128 over a state of 128 or 256 go to the
+    kernels, any other core to ``_ssd_group``. Read where a user would,
+    from the gauges ``ssm/core_kernel`` and ``ssm/core_plain`` that a
+    traced training program sets (0 for a graph with no Mamba-2 core), and
+    from the program: the step's forward, the group recomputed inside the
+    core's backward, and the backward's walk for the states on entry to
+    the chunks and its walk back, each a kernel under ``mx/ssm/scan``, the
+    backward's two entered by name."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.models import resnet_symbol
+    gauges = [telemetry.gauge("ssm/core_" + k) for k in ("kernel", "plain")]
+    h = 256 // p
+    sym = _mamba_symbol(h)
+    jaxpr = jax.make_jaxpr(_gradients(sym))(
+        *_abstract(sym, **_mamba_shapes(h, p, n))).jaxpr
+    assert [g.value() for g in gauges] == [int(kernel), int(not kernel)]
+    kernels = [(e.params["name"], name) for e, name in _named_eqns(jaxpr)
+               if e.primitive.name == "pallas_call"]
+    assert sorted(k for k, _ in kernels) == (
+        ["ssd_scan_bwd", "ssd_scan_fwd", "ssd_scan_fwd", "ssd_scan_states"]
+        if kernel else [])
+    for k, name in kernels:
+        assert "mx/ssm/scan" in name, (k, name)
+        assert ("transpose(jvp(mx/ssm/scan))" in name) \
+            == (k != "ssd_scan_fwd"), (k, name)
+    net = resnet_symbol(num_classes=10, num_layers=18, image_shape="3,32,32")
+    jax.make_jaxpr(_gradients(net))(
+        *_abstract(net, data=(2, 3, 32, 32), softmax_label=(2,)))
+    assert [g.value() for g in gauges] == [0, 0]
 
 
 def test_a_state_outlives_three_chunks():
@@ -210,7 +356,7 @@ def fitted(cfg):
         for g in ("ssm/layers", "ssm/chunks", "ssm/state_mb",
                   "attn/window_layers", "attn/full_layers",
                   "stage/kept_values", "stage/kept_mb", "kda/intra_plain",
-                  "kda/scan_plain")})
+                  "kda/scan_plain", "ssm/core_kernel", "ssm/core_plain")})
     return seen
 
 
@@ -225,6 +371,35 @@ def test_fit_follows_the_reference_losses_and_three_adam_steps(cfg, fitted):
     assert "head_weight" not in args        # one matrix, two uses
     for k in sorted(w0):
         moved = np.asarray(fitted["ref"][-1][1][k]) - np.asarray(w0[k])
+        got = args[k].asnumpy() - np.asarray(w0[k])
+        assert np.linalg.norm(got - moved) \
+            <= 0.02 * np.linalg.norm(moved) + 1e-12, k
+
+
+def test_fit_through_the_kernels_follows_the_reference(cfg):
+    """The same fit with Mamba-2 cores the kernels take (two heads of 64, a
+    state of 128, chunks of 128: ``ssm/core_kernel`` 2): three steps'
+    losses and every leaf after them as the reference's."""
+    from mxnet_tpu import telemetry
+    cfg = dict(cfg, mamba_d_head=64, mamba_n_heads=2, mamba_d_state=128,
+               mamba_chunk_size=128)
+    w0 = _weights(cfg)
+    data, label = _tokens(cfg)
+    losses = []
+    mod = _fit(cfg, build_symbol(cfg), w0, data, label, 3,
+               lambda m: losses.append(m.get_outputs()[0].asnumpy()))
+    assert telemetry.gauge("ssm/core_kernel").value() == 2
+    assert telemetry.gauge("ssm/core_plain").value() == 0
+    step = jax.jit(lambda p, m, v, t: ref.train_step(
+        cfg, p, m, v, t, jnp.asarray(data), jnp.asarray(label)))
+    p, m = w0, jax.tree.map(jnp.zeros_like, w0)
+    v = m
+    for t, got in zip((1, 2, 3), losses):
+        rows, _none, p, m, v = step(p, m, v, t)
+        np.testing.assert_allclose(got, rows, rtol=2e-4, atol=2e-5)
+    args, _ = mod.get_params()
+    for k in sorted(w0):
+        moved = np.asarray(p[k]) - np.asarray(w0[k])
         got = args[k].asnumpy() - np.asarray(w0[k])
         assert np.linalg.norm(got - moved) \
             <= 0.02 * np.linalg.norm(moved) + 1e-12, k
@@ -334,6 +509,8 @@ def test_the_gauges_count_the_cores_their_chunks_and_their_states(cfg,
     assert g["ssm/state_mb"] == pytest.approx(2 * 4 * B * 4 * 32 * 16 / 1e6)
     assert (g["attn/window_layers"], g["attn/full_layers"]) == (0, 1)
     assert g["kda/intra_plain"] == 0 and g["kda/scan_plain"] == 0
+    # heads of 32 over a state of 16 are no tile of the kernels'
+    assert (g["ssm/core_kernel"], g["ssm/core_plain"]) == (0, 2)
     assert g["stage/kept_values"] == 5
     t, inner = cfg["sequence_length"], 4 * 32
     assert g["stage/kept_mb"] == pytest.approx(

@@ -57,7 +57,8 @@ def test_every_kernel_module_has_a_case():
     chip uncompiled."""
     from mxnet_tpu import kernels
     covered = {c.name.split("[")[0] for c in CASES}
-    assert set(kernels.KERNEL_OPS) | {"pallas_flash", "pallas_kda"} == covered
+    assert set(kernels.KERNEL_OPS) | {"pallas_flash", "pallas_kda",
+                                      "pallas_ssd"} == covered
 
 
 @pytest.mark.parametrize("case", CASES, ids=[c.name for c in CASES])
@@ -97,3 +98,41 @@ def test_the_compiler_names_the_grouped_products_itself(one_chip):
     assert len(products) >= 9       # three a pass: forward, again, backward
     assert {op for _, op in products} == {"ragged-dot-none"}
     assert re.search(r'op_name="[^"]*mx/moe/experts[^"]*"', text)
+
+
+def test_the_ssd_kernels_run_under_the_scan_scope(one_chip):
+    """Where ``ssm_ms.train`` finds Mamba-2's core: in the compiled text
+    of a core's forward and gradient (heads of 64, a state of 128, chunks
+    of 128, bfloat16) the kernels' custom calls carry ``mx/ssm/scan`` in
+    their ``op_name``: the forward's, and the backward's two (the states on
+    entry to the chunks, the walk back) through the name the backward
+    enters; the forward of the group the core's backward recomputes is
+    gone, its outputs unused. ``delta`` and the running sums stay in XLA
+    under ``mx/ssm/intra``."""
+    import re
+    import jax.numpy as jnp
+    from mxnet_tpu.kernels import tier
+    from mxnet_tpu.ops import lm_ops
+    h, p, n, t = 4, 64, 128, 512
+
+    def S(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(*a):
+        # two groups of two chunks: the first group's last state is what
+        # keeps the forward's kernel in the gradient's program
+        return jnp.sum(lm_ops.ssd_chunked(*a, num_heads=h, chunk=128,
+                                          group=2).astype(jnp.float32))
+    with tier.force_compiled():
+        text = jax.jit(jax.grad(loss, argnums=range(7))).lower(
+            S((1, t, h * p), jnp.bfloat16), S((1, t, n), jnp.bfloat16),
+            S((1, t, n), jnp.bfloat16), S((1, t, h), jnp.bfloat16),
+            S((h,)), S((h,)), S((h,))).compile().as_text()
+    calls = re.findall(r'^\s*%(ssd_scan_\w+)[.\d]* = .*metadata=\{op_name='
+                       r'"([^"]*)"', text, re.M)
+    assert sorted(k for k, _ in calls) == ["ssd_scan_bwd", "ssd_scan_fwd",
+                                           "ssd_scan_states"]
+    for k, op in calls:
+        assert "mx/ssm/scan" in op, (k, op)
+        assert ("transpose(jvp(mx/ssm/scan))" in op) == (k != "ssd_scan_fwd")
+    assert re.search(r'op_name="[^"]*mx/ssm/intra[^"]*"', text)
