@@ -24,6 +24,7 @@ from __future__ import annotations
 import numpy as _np
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .base import MXNetError
 from .context import Context, current_context
@@ -110,6 +111,27 @@ def _mirror_stages(nodes, entries):
     return out
 
 
+def _read_by_several(stages):
+    """The variables that more than one stage reads, ``("var", name)``."""
+    seen, several = set(), set()
+    for _last, reads, _writes in stages:
+        for k in reads:
+            if k[0] == "var":
+                (several if k in seen else seen).add(k)
+    return several
+
+
+@jax.custom_vjp
+def _together(xs):
+    """``xs``; on the way back their cotangents are handed on together
+    (``lax.optimization_barrier``), so none leaves before all are made."""
+    return xs
+
+
+_together.defvjp(lambda xs: (xs, None),
+                 lambda _, cts: (lax.optimization_barrier(cts),))
+
+
 def _device_scope(node):
     """The name a node's device ops run under: the builder's
     (``mx.AttrScope(device_scope=...)`` names a generic op by the layer it
@@ -131,9 +153,12 @@ def _graph_eval_fn(symbol):
     ``stage/kept_values`` and ``stage/kept_mb`` say what the marked values
     of the program traced last come to, and the gauges its ops declared
     (``registry.PROGRAM_GAUGES``: ``kda/intra_kernel``, ``kda/intra_plain``)
-    what they counted in it. Every op node runs under a
-    ``jax.named_scope`` (:func:`_device_scope`), so that each device op of
-    the compiled program says in its ``op_name`` which node it serves.
+    what they counted in it. A stage that reads a variable other stages
+    read too (the layers of a stack run several times) hands the
+    cotangents of all it reads on together (:func:`_together`). Every op
+    node runs under a ``jax.named_scope`` (:func:`_device_scope`), so that
+    each device op of the compiled program says in its ``op_name`` which
+    node it serves.
     """
     nodes = symbol._topo()
     entries = list(symbol._entries)
@@ -144,6 +169,7 @@ def _graph_eval_fn(symbol):
     kplan, kdeferred = _gfuse.plan(nodes, entries)
     stages = {first: (last, reads, writes) for first, last, reads, writes
               in _mirror_stages(nodes, entries)}
+    shared = _read_by_several(stages.values())
 
     def run(todo, var, is_aux, values, training):
         """Evaluate the op nodes ``todo`` in order into ``values``
@@ -251,6 +277,11 @@ def _graph_eval_fn(symbol):
                         v = values[k[1]] if k[1] in values \
                             else values[(k[1], k[2])]
                         taken.append(v[k[2]] if isinstance(v, tuple) else v)
+                if any(k in shared for k in reads):
+                    # else the compiler may put off this stage's share of
+                    # a shared weight's gradient to the sum at the end,
+                    # and keep what it recomputed for it until then
+                    taken = _together(taken)
                 with stage_marks(kept):
                     outs, upd = jax.checkpoint(
                         stage, policy=_STAGE_POLICY)(taken)

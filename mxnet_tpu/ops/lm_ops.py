@@ -2,8 +2,9 @@
 position embedding, the causal depthwise convolution of linear-attention
 and state-space layers, the KDA recurrence (Kimi Delta Attention,
 arXiv:2510.26692) and Mamba-2's (arXiv:2405.21060) in chunks, in one frame
-of groups of chunks, Mamba-2's gated norm, and the head that gives every
-token's loss without the tokens x vocabulary array.
+of groups of chunks, Mamba-2's gated norm, the head that gives every
+token's loss without the tokens x vocabulary array, and a looped model's
+exit gate and the loss it weights by the passes.
 
 All are pure JAX but the two halves of the KDA core and Mamba-2's chunks:
 KDA's work inside chunks and its scan go each to two Pallas kernels
@@ -16,8 +17,8 @@ its groups of chunks in reverse and marks what a ``mirror_stage`` should
 keep; Mamba-2's core runs in the same frame). Each of the
 layers a device trace should tell apart carries a ``jax.named_scope``
 (``mx/kda`` with ``mx/kda/intra`` and ``mx/kda/scan`` inside it, ``mx/ssm``
-with ``mx/ssm/intra`` and ``mx/ssm/scan``, ``mx/rope``, ``mx/lm_head``;
-docs/observability.md).
+with ``mx/ssm/intra`` and ``mx/ssm/scan``, ``mx/rope``, ``mx/lm_head``,
+``mx/exit``; docs/observability.md).
 """
 from __future__ import annotations
 
@@ -650,14 +651,18 @@ _head_loss.defvjp(_head_fwd, _head_bwd)
 def lm_head_loss(data, weight, label, *, grad_scale=1.0,
                  normalization="null", block=2048):
     """Every token's cross-entropy of ``label`` under ``softmax(data
-    weight^T)``, as a loss head: data (B, T, hidden), weight (vocabulary,
-    hidden), label (B, T) of ids; the output is (B, T) of float32 losses
-    and never the B x T x vocabulary array (the logits exist ``block``
-    tokens at a time, forward and backward). Like ``SoftmaxOutput`` the
-    gradient is the sum's, ``softmax - onehot`` a token, times
-    ``grad_scale``, and with ``normalization="tokens"`` divided by T: with
-    ``Module``'s default ``rescale_grad = 1 / B`` the step then follows
-    the mean over all tokens."""
+    weight^T)``: data (B, T, hidden), weight (vocabulary, hidden), label
+    (B, T) of ids; the output is (B, T) of float32 losses and never the B x
+    T x vocabulary array (the logits exist ``block`` tokens at a time,
+    forward and backward). A token's gradient is ``softmax - onehot`` times
+    the cotangent its row receives, times ``grad_scale``, and with
+    ``normalization="tokens"`` divided by T. As the graph's output the
+    rows receive 1 each (the sum's gradient; with ``Module``'s default
+    ``rescale_grad = 1 / B`` the step then follows the mean over all
+    tokens); as a value inside the graph they receive what the ops that
+    read them give back, such as a looped model's exit weights
+    (:func:`loop_exit_loss`), and several calls on one weight add their
+    gradients."""
     with jax.named_scope("mx/lm_head"):
         b, t, hid = data.shape
         if normalization == "tokens":
@@ -666,6 +671,72 @@ def lm_head_loss(data, weight, label, *, grad_scale=1.0,
                           label.astype(jnp.int32).reshape(b * t),
                           float(grad_scale), int(block))
         return rows.reshape(b, t)
+
+
+# -------------------------------------------------------------- exit
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _grad_times(x, scale):
+    """``x``, its cotangent times ``scale`` on the way back."""
+    return x
+
+
+_grad_times.defvjp(lambda x, scale: (x, None),
+                   lambda scale, _, ct: (ct * scale,))
+
+
+def exit_distribution(logits):
+    """(P, ...) gate logits -> (P, ...) log of the exit distribution:
+    ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` for t < P and ``p_P =
+    prod_{j<P} (1 - lambda_j)``, ``lambda = sigmoid(logits)``; the last
+    pass's gate is not read. Float32."""
+    g = logits.astype(_F32)[:-1]
+    zero = jnp.zeros((1,) + logits.shape[1:], _F32)
+    survive = jnp.concatenate([zero, jnp.cumsum(jax.nn.log_sigmoid(-g), 0)])
+    return survive + jnp.concatenate([jax.nn.log_sigmoid(g), zero])
+
+
+@register("_contrib_ExitGate")
+def exit_gate(data, weight, bias):
+    """A looped model's exit gate: every token's logit ``w . h + b`` of
+    data (B, T, hidden), weight (1, hidden) and bias (1,), in float32
+    whatever the data's dtype (a product and a sum, no matrix unit):
+    (B, T)."""
+    with jax.named_scope("mx/exit"):
+        return jnp.sum(data.astype(_F32) * weight.astype(_F32)[0], -1) \
+            + bias.astype(_F32)[0]
+
+
+@register("_contrib_LoopExitLoss", num_outputs=2)
+def loop_exit_loss(logits, rows, counters, *, beta=0.1):
+    """The loss of a model whose layers run P times, with a head and an
+    exit gate after every pass: ``logits`` (P, B, T) the gates' logits,
+    ``rows`` (P, B, T) every pass's loss a token. Each token's loss is
+    ``sum_t p_t L_t + beta sum_t p_t log p_t`` (the expected loss under
+    the exit distribution :func:`exit_distribution` less ``beta`` times
+    its entropy), in float32: (B, T). As a loss head its gradient is the
+    mean's over the T tokens of a sequence (with ``Module``'s default
+    ``rescale_grad = 1 / B`` the step follows the mean over all tokens);
+    the passes' rows receive ``p_t / T`` a token and hand it to their
+    heads.
+
+    The auxiliary state ``counters`` (4,) is carried on the device: steps
+    seen, then a step's running mean of the passes, of the mean exit
+    step ``E_p[t]`` (from 1) and of the share that leaves after the last
+    pass ``p_P``, each the mean over tokens."""
+    with jax.named_scope("mx/exit"):
+        p_n, _, t = rows.shape
+        log_p = exit_distribution(logits)
+        p = jnp.exp(log_p)
+        loss = _grad_times(
+            jnp.sum(p * (rows.astype(_F32) + float(beta) * log_p), 0), 1.0 / t)
+        p = lax.stop_gradient(p)
+        steps = counters[:1] + 1
+        step_no = jnp.arange(1, p_n + 1, dtype=_F32)[:, None, None]
+        seen = jnp.stack([jnp.asarray(p_n, _F32),
+                          jnp.mean(jnp.sum(p * step_no, 0)),
+                          jnp.mean(p[-1])]).astype(counters.dtype)
+        means = counters[1:] + (seen - counters[1:]) / steps
+        return loss, jnp.concatenate([steps, means])
 
 
 # ------------------------------------------------------------ feed-forward
@@ -714,6 +785,18 @@ set_op_meta("_contrib_KDA", f32_inputs=(5, 6))
 set_op_meta("_contrib_Mamba2", f32_inputs=(4, 5, 6))
 set_op_meta("_contrib_GatedRMSNorm", f32_inputs=(2,))
 set_op_meta("_contrib_LMHeadLoss", index_inputs=(2,))
+set_op_meta("_contrib_ExitGate", f32_inputs=(1, 2))
+set_op_meta("_contrib_LoopExitLoss", aux_inputs=(2,), aux_outputs=(1,),
+            num_visible_outputs=1,
+            counters=((2, (
+                ("loop/passes", "passes a step of a looped model's layers"),
+                ("exit/mean_step",
+                 "the pass a token leaves after, expected under the exit "
+                 "distribution (from 1), mean over tokens"),
+                ("exit/last_share",
+                 "the exit distribution's share on the last pass, mean "
+                 "over tokens"))),),
+            shape_hook=lambda ins, p: list(ins[:2]) + [ins[2] or (4,)])
 set_op_meta("_contrib_MoE", aux_inputs=(6,), aux_outputs=(1,),
             num_visible_outputs=1, f32_inputs=(1, 2),
             counters=((6, (
