@@ -594,6 +594,21 @@ def kernel_cases():
         lambda q, k, v, do: jax.vjp(scaled, q, k, v)[1](do),
         [wide, narrow, narrow, wide],
         lambda q, k, v, do: jax.vjp(dense_scaled, q, k, v)[1](do), tol[bf16])
+    # the backward kernel (``pallas_flash.flash_bwd``) against the plain
+    # blockwise backward it replaces, on the same output, rows'
+    # log-sum-exp and cotangent (drawn, not made by a forward: the
+    # reference runs at the highest precision, which no bfloat16 Pallas
+    # product takes): Ouro-2.6B's core (16 heads of 128, each its own KV
+    # head) and Kimi-Linear's latent attention (32 heads, 192-wide q.k,
+    # 128-wide v), causal, 8,192 tokens, the kernel's own tile
+    for h, d, dv in [(16, 128, 128), (32, 192, 128)]:
+        wide, narrow = ((1, h, 8192, d), bf16, "normal"), \
+            ((1, h, 8192, dv), bf16, "normal")
+        add("pallas_flash[bwd-1x%dx8192x%d/%d-bfloat16-causal1]" % (h, d, dv),
+            lambda *a: pallas_flash.flash_bwd(*a, True, False),
+            [wide, wide, narrow, narrow, ((1, h, 8192), f32, "normal"),
+             narrow],
+            lambda *a: pallas_flash._flash_bwd(*a, True), tol[bf16])
     for b, h, t, d, dt in attn:
         qkv = [((b, h, t, d), dt, "normal")] * 3
         add("flash_attn[%dx%dx%dx%d-%s]" % (b, h, t, d, dt.__name__),

@@ -81,7 +81,8 @@ def fitted(cfg):
         g: telemetry.gauge(g).value()
         for g in ("attn/window_layers", "attn/full_layers",
                   "attn/kv_blocks_visited", "attn/kv_blocks_causal",
-                  "attn/grid_steps", "stage/kept_values")})
+                  "attn/grid_steps", "stage/kept_values", "attn/bwd_kernel",
+                  "attn/bwd_plain")})
     return seen
 
 
@@ -147,7 +148,7 @@ def test_the_gauges_count_the_layers_and_the_key_blocks(cfg, fitted):
     the last query block starts at 256 and sees back to 97), and the grid
     is 3 query blocks by the longest span, 3. The full layer's 300 tokens
     are under one tile: one block, one step. 4 x 6 + 1 and 4 x 9 + 1. A
-    stage keeps each attention's output."""
+    stage keeps each attention's output and rows' log-sum-exp."""
     from mxnet_tpu.ops.pallas_flash import (blocks_visited, grid_steps,
                                             tile_for)
     g = fitted["gauges"]
@@ -161,7 +162,9 @@ def test_the_gauges_count_the_layers_and_the_key_blocks(cfg, fitted):
     assert (g["attn/kv_blocks_visited"], g["attn/kv_blocks_causal"]) \
         == (25, 25)
     assert g["attn/grid_steps"] == 37
-    assert g["stage/kept_values"] == 5
+    # heads of 16: every core's backward is the plain one
+    assert (g["attn/bwd_kernel"], g["attn/bwd_plain"]) == (0, 5)
+    assert g["stage/kept_values"] == 10
     # a window of 40 hides block 0 from the last query block
     assert blocks_visited(t, t, 128, 128, 40) == (5, 6)
     assert grid_steps(t, t, 128, 128, True, 40) == 6

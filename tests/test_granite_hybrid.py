@@ -356,7 +356,8 @@ def fitted(cfg):
         for g in ("ssm/layers", "ssm/chunks", "ssm/state_mb",
                   "attn/window_layers", "attn/full_layers",
                   "stage/kept_values", "stage/kept_mb", "kda/intra_plain",
-                  "kda/scan_plain", "ssm/core_kernel", "ssm/core_plain")})
+                  "kda/scan_plain", "ssm/core_kernel", "ssm/core_plain",
+                  "attn/bwd_kernel", "attn/bwd_plain")})
     return seen
 
 
@@ -503,7 +504,7 @@ def test_the_gauges_count_the_cores_their_chunks_and_their_states(cfg,
     64; a core keeps one float32 state (2 sequences x 4 heads x 32 x 16)
     on entry to each group of 8 chunks, here one group: 2 x 16,384 bytes.
     A stage keeps its core's output and states, or its attention's
-    output."""
+    output and rows' log-sum-exp."""
     g = fitted["gauges"]
     assert (g["ssm/layers"], g["ssm/chunks"]) == (2, 5)
     assert g["ssm/state_mb"] == pytest.approx(2 * 4 * B * 4 * 32 * 16 / 1e6)
@@ -511,11 +512,14 @@ def test_the_gauges_count_the_cores_their_chunks_and_their_states(cfg,
     assert g["kda/intra_plain"] == 0 and g["kda/scan_plain"] == 0
     # heads of 32 over a state of 16 are no tile of the kernels'
     assert (g["ssm/core_kernel"], g["ssm/core_plain"]) == (0, 2)
-    assert g["stage/kept_values"] == 5
+    # heads of 16: the attention core's backward is the plain one
+    assert (g["attn/bwd_kernel"], g["attn/bwd_plain"]) == (0, 1)
+    assert g["stage/kept_values"] == 6
     t, inner = cfg["sequence_length"], 4 * 32
     assert g["stage/kept_mb"] == pytest.approx(
         (2 * 4 * B * 4 * 32 * 16 + 2 * 4 * B * t * inner
-         + 4 * B * t * cfg["hidden_size"]) / 1e6)
+         + 4 * B * t * cfg["hidden_size"]
+         + 4 * B * cfg["num_attention_heads"] * t) / 1e6)
 
 
 def test_every_block_is_a_stage_and_the_scopes_are_the_builders(cfg):

@@ -375,8 +375,9 @@ def test_a_stage_runs_its_core_forward_once_for_the_step(cfg, traced_step,
 
 def test_the_stage_gauges_read_what_the_marked_shapes_say(cfg):
     """``stage/kept_values`` and ``stage/kept_mb`` are set when a training
-    program is traced: the tiny symbol's nine values by their shapes, the
-    same on a second trace, 0 for a graph without stages."""
+    program is traced: the tiny symbol's ten values by their shapes (the
+    attention core keeps its output and the rows' log-sum-exp), the same
+    on a second trace, 0 for a graph without stages."""
     from mxnet_tpu import telemetry
     from mxnet_tpu.models import resnet_symbol
     values, mb = (telemetry.gauge("stage/kept_values"),
@@ -386,10 +387,11 @@ def test_the_stage_gauges_read_what_the_marked_shapes_say(cfg):
     h, d = lin["num_heads"], lin["head_dim"]
     n_groups = LONG_T // (SMALL_CHUNK * 16)
     per_kda = 4 * (B * LONG_T * h * d + n_groups * B * h * d * d)
-    attn = 4 * B * cfg["num_attention_heads"] * LONG_T * cfg["v_head_dim"]
+    attn = 4 * B * cfg["num_attention_heads"] * LONG_T * (
+        cfg["v_head_dim"] + 1)
     for _ in range(2):                     # set, not added
         _traced_gradient(_symbol(cfg, kda_chunk=SMALL_CHUNK))
-        assert values.value() == 2 * len(kda_layers) + 1 == 9
+        assert values.value() == 2 * len(kda_layers) + 2 == 10
         assert mb.value() == pytest.approx(
             (len(kda_layers) * per_kda + attn) / 1e6, rel=1e-9)
     net = resnet_symbol(num_classes=10, num_layers=18, image_shape="3,32,32")
@@ -691,6 +693,9 @@ def test_the_attention_gauges_read_the_tile_the_shapes_give(cfg, t, tile,
         assert tuple(telemetry.gauge("attn/" + g).value() for g in (
             "window_layers", "full_layers", "kv_blocks_visited",
             "kv_blocks_causal", "grid_steps")) == gauges
+        # q.k narrower than 128: the core's backward is the plain one
+        assert (telemetry.gauge("attn/bwd_kernel").value(),
+                telemetry.gauge("attn/bwd_plain").value()) == (0, 1)
 
 
 @pytest.mark.parametrize("tq,tk,causal", [(96, 96, True), (70, 70, True),

@@ -52,6 +52,7 @@ def _tokens(cfg, seed=0):
 def fitted(cfg):
     """One ``fit`` of three steps: every step's losses and Adam's first
     moment after it; the reference's three steps beside them."""
+    from mxnet_tpu import telemetry
     w0 = _weights(cfg)
     data, label = _tokens(cfg)
     seen = {"losses": [], "m": []}
@@ -69,7 +70,9 @@ def fitted(cfg):
     for t in (1, 2, 3):
         rows, _none, p, m, v = step(p, m, v, t)
         steps.append((np.asarray(rows), p, m))
-    seen.update(mod=mod, w0=w0, ref=steps, data=data, label=label)
+    seen.update(mod=mod, w0=w0, ref=steps, data=data, label=label, gauges={
+        g: telemetry.gauge(g).value()
+        for g in ("attn/full_layers", "attn/bwd_kernel", "attn/bwd_plain")})
     return seen
 
 
@@ -224,6 +227,18 @@ def test_the_exit_counters_are_reported(cfg, fitted):
                                                         rel=1e-4)
     assert 1 < counters["exit/mean_step"] < 4
     assert 0 < counters["exit/last_share"] < 1
+
+
+def test_every_layer_pass_is_an_attention_core_whose_backward_is_counted(
+        cfg, fitted):
+    """Two layers run four times are eight attention cores; heads of 16
+    are no shape of the backward kernel's (``bwd_eligible``), so each
+    core's backward is the plain one."""
+    g = fitted["gauges"]
+    cores = cfg["num_hidden_layers"] * cfg["total_ut_steps"]
+    assert cores == 8
+    assert g["attn/full_layers"] == cores
+    assert (g["attn/bwd_kernel"], g["attn/bwd_plain"]) == (0, cores)
 
 
 def test_two_heads_on_one_matrix_take_the_cotangents_the_graph_gives():

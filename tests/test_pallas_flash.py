@@ -138,7 +138,7 @@ _WINDOWS = [(4, 4, 256, 128, 64, 64), (8, 2, 200, 70, 64, 64),
 @pytest.mark.parametrize("h,hk,t,window,bq,bk", _WINDOWS)
 def test_window_and_grouped_heads_match_the_dense_masked_softmax(
         h, hk, t, window, bq, bk):
-    from mxnet_tpu.ops.pallas_flash import _flash_bwd
+    from mxnet_tpu.ops.pallas_flash import _flash_bwd, _flash_fwd
     q, k, v = _grouped(h, hk, t)
     want = _masked_reference(q, k, v, window)
     got = flash_attention(q, k, v, bq, bk, True, None, window)
@@ -150,9 +150,10 @@ def test_window_and_grouped_heads_match_the_dense_masked_softmax(
     # through the custom_vjp (its backward's own blocks hold the sequence)
     # and with the backward cut into the forward's blocks, so that its
     # loops start behind the window and stop before the future
+    lse = _flash_fwd(q, k, v, bq, bk, True, True, window, with_lse=True)[1]
     for gots in (jax.vjp(lambda *a: flash_attention(
                      *a, bq, bk, True, None, window), q, k, v)[1](do),
-                 _flash_bwd(q, k, v, got, do, True, bq, bk, window)):
+                 _flash_bwd(q, k, v, got, lse, do, True, bq, bk, window)):
         for name, a, b in zip("qkv", gots, wants):
             assert a.shape == b.shape
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -173,16 +174,18 @@ def test_a_window_as_long_as_the_sequence_is_causal_attention():
 # in PR 32, whose kernel skips the mask on a block that is wholly visible
 # and whose causal index map stays on a query block's last visible key
 # block (the digests before it, at commit a612b22, were of the kernel as it
-# stood before it knew of a window or of grouped heads)
+# stood before it knew of a window or of grouped heads). The gradients'
+# taken anew when the forward began to hand its rows' log-sum-exp to the
+# backward, whose own pass for it went; the forward's did not move
 _BEFORE = {
     ("float32", "fwd"):
         "074d690d1ebb8044192c61034613b41033ee3aa56894695d64c5835e439d137d",
     ("float32", "grad"):
-        "a9998d5cebb7a6f8e0708a58f59fa542d496a41d479a4814d5c4305fffe08e96",
+        "6d3f5fd584c02c812981ed4f22662b2536c6a3a82e0f753397ba5a7f86e3aadf",
     ("bfloat16", "fwd"):
         "826de351ba126f9d34ed700240c6e96fbcf8e01a0a4ce054720f9e3a27e88c0e",
     ("bfloat16", "grad"):
-        "cf22e63bf366e730dff1f757d63e6b606662ffd1807a5fd7b159d81f17593c1a",
+        "afcbe9dce4094cd9496921d81714dfbca2947480c89859a99cbd5c04f190ef61",
 }
 
 
@@ -289,9 +292,11 @@ def test_an_explicit_tile_is_honoured_as_given():
     with registry.program_counts(chosen):
         b = op(q, k, v, causal=True)
     assert given == {"attn/full_layers": 1, "attn/grid_steps": 9,
-                     "attn/kv_blocks_visited": 6, "attn/kv_blocks_causal": 6}
+                     "attn/kv_blocks_visited": 6, "attn/kv_blocks_causal": 6,
+                     "attn/bwd_plain": 1}
     assert chosen == {"attn/full_layers": 1, "attn/grid_steps": 1,
-                      "attn/kv_blocks_visited": 1, "attn/kv_blocks_causal": 1}
+                      "attn/kv_blocks_visited": 1, "attn/kv_blocks_causal": 1,
+                      "attn/bwd_plain": 1}
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
                                atol=2e-5)
     for blocks, grid in (((64, 64), (8, 3, 3)), ((None, None), (8, 1, 1)),
@@ -381,7 +386,7 @@ def test_a_given_scale_multiplies_the_scores_forward_and_backward(
     """Against the dense masked softmax of ``scale q k^T``: the output,
     and the three gradients through the custom_vjp and through the
     blockwise backward at the forward's blocks."""
-    from mxnet_tpu.ops.pallas_flash import _flash_bwd
+    from mxnet_tpu.ops.pallas_flash import _flash_bwd, _flash_fwd
     q, k, v = _grouped(h, hk, t, d)
     q = q * 4       # scores wide enough that the scale shows
 
@@ -397,7 +402,9 @@ def test_a_given_scale_multiplies_the_scores_forward_and_backward(
     do = jnp.asarray(np.random.RandomState(1).randn(*want.shape)
                      .astype(np.float32))
     wants = pull_dense(do)
-    for gots in (pull(do), _flash_bwd(q, k, v, got, do, True, bq or 512,
+    lse = _flash_fwd(q, k, v, bq, bk, True, True, window, scale,
+                     with_lse=True)[1]
+    for gots in (pull(do), _flash_bwd(q, k, v, got, lse, do, True, bq or 512,
                                       bk or 512, window, scale)):
         for name, a, b in zip("qkv", gots, wants):
             assert a.shape == b.shape
@@ -424,3 +431,183 @@ def test_the_default_scale_is_one_over_the_root_of_the_width(dtype):
     other = run(scale=0.015625)[0]
     assert float(jnp.max(jnp.abs(other.astype(jnp.float32)
                                  - run()[0].astype(jnp.float32)))) > 1e-2
+
+
+# ---- the backward kernel (flash_bwd) and the log-sum-exp it reads
+def _dense_masked(q, k, v, causal=True, window=None, scale=None):
+    """Dense softmax attention in float32 at the highest precision, masked
+    as the kernels mask (grouped heads, the window, the cross-length
+    diagonal); a row that sees no key gives zeros. Also the rows'
+    log-sum-exp of the scaled, masked scores."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    h, hk, tq, tk = q.shape[1], k.shape[1], q.shape[2], k.shape[2]
+    k, v = (jnp.repeat(x, h // hk, axis=1) for x in (k, v))
+    scale = 1 / np.sqrt(q.shape[-1]) if scale is None else scale
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") * scale
+    i = jnp.arange(tq)[:, None] + (tk - tq)
+    j = jnp.arange(tk)[None, :]
+    seen = (j <= i) if causal else jnp.ones((tq, tk), bool)
+    if window is not None:
+        seen = seen & (j > i - window)
+    p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+    p = jnp.where(seen.any(-1, keepdims=True), p, 0.0)
+    lse = jax.nn.logsumexp(jnp.where(seen, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision="highest"), lse
+
+
+# (query heads, KV heads, tq, tk, d, dv, causal, window, scale, query
+# block, key block, dtype): causal full and windowed; groups of 1, 4, 8;
+# 192-wide q.k over 128-wide v (here 96 / 64); a given scale; lengths no
+# multiple of the blocks; the cross-length diagonal both ways; no mask;
+# a window of one key; bfloat16
+_BWD = [
+    (2, 2, 256, 256, 64, 64, True, None, None, 64, 64, "float32"),
+    (4, 1, 200, 200, 32, 32, True, None, None, 64, 64, "float32"),
+    (8, 2, 200, 200, 32, 32, True, 70, None, 64, 32, "float32"),
+    (8, 1, 192, 192, 32, 32, True, 64, None, 64, 64, "float32"),
+    (4, 4, 256, 256, 96, 64, True, None, None, 128, 64, "float32"),
+    (4, 2, 130, 130, 32, 16, True, 33, 0.05, 64, 64, "float32"),
+    (8, 2, 200, 200, 64, 64, True, None, 0.015625, 64, 128, "float32"),
+    (2, 2, 64, 256, 48, 32, True, None, None, 64, 128, "float32"),
+    (2, 2, 256, 100, 32, 32, True, None, None, 64, 64, "float32"),
+    (2, 2, 200, 328, 32, 32, False, None, None, 64, 128, "float32"),
+    (8, 4, 192, 192, 64, 64, True, 1, None, 64, 64, "float32"),
+    (4, 4, 256, 256, 96, 64, True, None, None, 128, 64, "bfloat16"),
+    (8, 2, 256, 256, 64, 64, True, 100, None, 64, 128, "bfloat16"),
+]
+
+
+@pytest.mark.parametrize("h,hk,tq,tk,d,dv,causal,window,scale,bq,bk,dtype",
+                         _BWD)
+def test_the_backward_kernel_matches_the_plain_backward_and_the_dense(
+        h, hk, tq, tk, d, dv, causal, window, scale, bq, bk, dtype):
+    """dQ, dK, dV of ``flash_bwd`` (interpreted) against ``_flash_bwd`` on
+    the same handed-on log-sum-exp, and against the dense form's
+    autodiff; the forward's log-sum-exp against the dense one."""
+    from mxnet_tpu.ops.pallas_flash import _flash_bwd, _flash_fwd, flash_bwd
+    rng = np.random.RandomState(3)
+
+    def mk(*shape):
+        return jnp.asarray(rng.randn(*shape).astype(np.float32) * 2
+                           / np.sqrt(d)).astype(dtype)
+    q, k, v = mk(2, h, tq, d), mk(2, hk, tk, d), mk(2, hk, tk, dv)
+    do = jnp.asarray(rng.randn(2, h, tq, dv).astype(np.float32)).astype(dtype)
+    o, lse = _flash_fwd(q, k, v, bq, bk, causal, True, window, scale,
+                        with_lse=True)
+    want_o, want_lse = _dense_masked(q, k, v, causal, window, scale)
+    seen = np.isfinite(np.asarray(want_lse))
+    np.testing.assert_allclose(np.asarray(lse)[seen],
+                               np.asarray(want_lse)[seen], rtol=1e-5,
+                               atol=1e-5)
+    got = flash_bwd(q, k, v, o, lse, do, causal, True, window, scale, bq, bk)
+    plain = _flash_bwd(q, k, v, o, lse, do, causal, bq, bk, window, scale)
+    wants = jax.vjp(lambda *a: _dense_masked(*a, causal, window, scale)[0],
+                    q, k, v)[1](do.astype(jnp.float32))
+    tol = 2e-4 if dtype == "float32" else 2e-2
+    # relative to the largest gradient (a window of one key makes dQ zero)
+    top = max(float(np.max(np.abs(c))) for c in wants)
+    for name, a, b, c in zip("qkv", got, plain, wants):
+        assert a.shape == b.shape == c.shape and a.dtype == b.dtype
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, atol=tol * top, err_msg=name)
+        np.testing.assert_allclose(a, np.asarray(c), atol=tol * top,
+                                   err_msg=name)
+
+
+def test_the_key_side_spans_visit_the_forwards_pairs():
+    """The backward walks key blocks outermost and, for each, the query
+    blocks ``_query_span`` gives: the same (query block, key block) pairs
+    as the forward's ``_block_span``, over windows, tiles and lengths."""
+    from mxnet_tpu.ops.pallas_flash import _block_span, _query_span
+    for tq, tk in ((256, 256), (200, 328), (328, 200), (64, 256), (8192,
+                                                                  8192)):
+        for bq, bk in ((64, 64), (64, 128), (128, 32), (512, 1024)):
+            for window in (None, 1, 33, 128, 500, 2048):
+                n_q, n_k = -(-tq // bq), -(-tk // bk)
+                first, last = _block_span(np, np.arange(n_q), bq, bk, n_k,
+                                          tk - tq, window)
+                fwd = {(i, j) for i in range(n_q)
+                       for j in range(first[i], last[i] + 1)}
+                qf, ql = _query_span(np, np.arange(n_k), bq, bk, n_q,
+                                     tk - tq, window)
+                bwd = {(i, j) for j in range(n_k)
+                       for i in range(qf[j], ql[j] + 1)}
+                assert fwd == bwd, (tq, tk, bq, bk, window)
+
+
+def test_the_gradients_through_the_op_take_the_kernel_where_eligible():
+    """Through ``flash_attention``'s custom_vjp at shapes the kernel takes
+    (``bwd_eligible``: 256 tokens, heads of 128, each its own KV head or a
+    group under a window, the kernel's own tile) and at shapes it declines
+    (heads of 64; a group that sees every earlier key): all against the
+    dense form, and the registered op counts which backward each core
+    took."""
+    from mxnet_tpu.ops import registry
+    from mxnet_tpu.ops.pallas_flash import bwd_eligible, bwd_tile_for
+    op = registry.get("_contrib_FlashAttention").fn
+    assert bwd_tile_for(256, 256, 128, 128, 4) == (256, 256)
+    for hk, d, window, kernel in ((4, 128, None, True), (2, 128, 100, True),
+                                  (4, 64, None, False),
+                                  (2, 128, None, False)):
+        assert bwd_eligible(256, 256, d, d, 4 // hk, window,
+                            jnp.float32) == kernel
+        q, k, v = _grouped(4, 2, 256, d)
+        k, v = k[:, :hk], v[:, :hk]
+        if hk == 4:
+            k, v = (jnp.concatenate([x, x[:, ::-1] * 0.5], 1)
+                    for x in (k, v))
+        do = jnp.asarray(np.random.RandomState(1).randn(*q.shape)
+                         .astype(np.float32))
+        counts = {}
+        with registry.program_counts(counts):
+            got = jax.vjp(lambda *a: op(*a, causal=True, window=window),
+                          q, k, v)[1](do)
+        path = "attn/bwd_kernel" if kernel else "attn/bwd_plain"
+        assert counts[path] == 1 and len(
+            [c for c in counts if c.startswith("attn/bwd_")]) == 1
+        wants = jax.vjp(lambda *a: _masked_reference(*a, window), q, k,
+                        v)[1](do)
+        for name, a, b in zip("qkv", got, wants):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+def test_the_forward_alone_hands_on_nothing_and_the_residuals_are_kept():
+    """A forward that is not differentiated returns the output alone (the
+    eager op, ``parallel.flash_attention``); under a stage the output and
+    the rows' log-sum-exp are the values kept."""
+    from mxnet_tpu.ops import registry
+    q, k, v = _grouped(4, 2, 192)
+    out = flash_attention(q, k, v, 64, 64, True)
+    assert isinstance(out, jax.Array) and out.shape == q.shape
+    kept = []
+    with registry.stage_marks(kept):
+        jax.vjp(lambda *a: flash_attention(*a, 64, 64, True), q, k, v)
+    assert kept == [q.size * 4, q.size // q.shape[3] * 4]
+
+
+# the attention core of each language-model cell, 8,192 tokens in
+# bfloat16: (heads, KV heads, q.k width, v width, window) -> whether the
+# backward kernel takes it, and the tile it would take (PERF.md §6:
+# the kernel where it was faster on the chip)
+_CELL_CORES = {
+    "ouro": ((16, 16, 128, 128, None), True, (1024, 1024)),
+    "kimi_mla": ((32, 32, 192, 128, None), True, (512, 1024)),
+    "trinity_window": ((32, 4, 128, 128, 2048), True, (1024, 1024)),
+    "trinity_full": ((32, 4, 128, 128, None), False, (1024, 1024)),
+    "granite": ((32, 8, 64, 64, None), False, (1024, 1024)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_CORES))
+def test_bwd_eligible_at_the_cells_cores(cell):
+    from mxnet_tpu.ops.pallas_flash import (VMEM_BUDGET, bwd_eligible,
+                                            bwd_tile_bytes, bwd_tile_for)
+    (h, hk, d, dv, window), kernel, tile = _CELL_CORES[cell]
+    assert bwd_eligible(8192, 8192, d, dv, h // hk, window,
+                        jnp.bfloat16) == kernel
+    assert bwd_tile_for(8192, 8192, d, dv, 2, window) == tile
+    assert bwd_tile_bytes(*tile, d, dv, 2) <= VMEM_BUDGET
+    # float32 operands take the next tile; what fits no tile goes plain
+    assert bwd_tile_for(8192, 8192, d, dv, 4, window) == (512, 1024)
+    assert not bwd_eligible(8192, 8192, d, dv, h // hk, window, jnp.float16)

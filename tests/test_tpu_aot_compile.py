@@ -136,3 +136,50 @@ def test_the_ssd_kernels_run_under_the_scan_scope(one_chip):
         assert "mx/ssm/scan" in op, (k, op)
         assert ("transpose(jvp(mx/ssm/scan))" in op) == (k != "ssd_scan_fwd")
     assert re.search(r'op_name="[^"]*mx/ssm/intra[^"]*"', text)
+
+
+# the attention core of each language-model cell, 8,192 tokens in bfloat16:
+# (heads, KV heads, q.k width, v width, window, scale, the scope its model
+# names, whether the backward kernel takes it)
+_CORES = {
+    "ouro": (16, 16, 128, 128, None, None, "mx/attn/full", True),
+    "kimi_mla": (32, 32, 192, 128, None, None, "mx/mla", True),
+    "trinity_window": (32, 4, 128, 128, 2048, None, "mx/attn/window", True),
+    "trinity_full": (32, 4, 128, 128, None, None, "mx/attn/full", False),
+    "granite": (32, 8, 64, 64, None, 0.015625, "mx/attn/full", False),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CORES))
+def test_the_backward_kernel_lowers_where_the_shapes_take_it(one_chip, cell):
+    """The gradient of ``_contrib_FlashAttention`` at a cell's core,
+    compiled for a described v5e under the scope its model names: the
+    backward is the custom call ``flash_bwd``, under that scope, where
+    ``pallas_flash.bwd_eligible`` takes the shapes, and absent (the plain
+    blockwise backward) where it declines them."""
+    import re
+    import jax.numpy as jnp
+    from mxnet_tpu.kernels import tier
+    from mxnet_tpu.ops import pallas_flash, registry
+    h, hk, d, dv, window, scale, scope, kernel = _CORES[cell]
+    t = 8192
+    assert pallas_flash.bwd_eligible(t, t, d, dv, h // hk, window,
+                                     jnp.bfloat16) == kernel
+    op = registry.get("_contrib_FlashAttention").fn
+
+    def S(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        with jax.named_scope(scope):
+            o = op(q, k, v, causal=True, window=window, scale=scale)
+        return jnp.sum(o.astype(jnp.float32))
+    with tier.force_compiled():
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            S((1, h, t, d)), S((1, hk, t, d)), S((1, hk, t, dv))
+        ).compile().as_text()
+    calls = re.findall(r'^\s*%(flash_bwd)[.\d]* = .*metadata=\{op_name='
+                       r'"([^"]*)"', text, re.M)
+    assert len(calls) == int(kernel)
+    for _, name in calls:
+        assert "transpose(jvp(%s))" % scope in name
